@@ -959,7 +959,7 @@ def run_fleet(replicas: int = 2, n_requests: int = 48, rate: float = 40.0,
     def spawn(replica_id: str) -> SubprocessReplica:
         from repro.core import telemetry
         cmd = worker_command(
-            "--profile", "synthetic", "--replica-id", replica_id,
+            "--replica-id", replica_id,
             "--plane-dir", plane_dir, "--plane-poll-s", "0.2",
             "--cache-dir", cache_dir, "--d", str(d), "--dwell", str(dwell),
             "--slo-ms", str(slo_ms), "--max-wall-s", "120",

@@ -1,9 +1,12 @@
 """Serving example: continuous-batching LM decode with online specialization.
 
     PYTHONPATH=src python examples/serve_adaptive.py
-    PYTHONPATH=src python examples/serve_adaptive.py --arch rwkv6-1.6b
-    PYTHONPATH=src python examples/serve_adaptive.py \
+    PYTHONPATH=src python examples/serve_adaptive.py --reduced --arch rwkv6-1.6b
+    PYTHONPATH=src python examples/serve_adaptive.py --reduced \
         --prefill-chunk 32 --kv-page-size 8 --scheduler sjf
+
+With no arguments it serves the reduced float32 preset (a CPU-sized
+run); pass flags without ``--reduced`` to serve the published config.
 
 Open-loop requests (pseudo-Poisson arrivals, mixed prompt/decode lengths)
 flow through the :mod:`repro.serve` engine: admission queue -> scheduler
@@ -21,5 +24,5 @@ from repro.launch.serve import main
 
 if __name__ == "__main__":
     if len(sys.argv) == 1:
-        sys.argv += ["--steps", "240"]
+        sys.argv += ["--reduced", "--steps", "240"]
     main()

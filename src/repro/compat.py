@@ -1,37 +1,25 @@
-"""jax API compatibility layer — the single absorption point for version drift.
+"""jax API surface used by this repo — the one place ``jax.experimental``
+platform modules are imported.
 
-Policy (see README "Compat policy"): any jax symbol that has moved, been
-renamed, or gained/lost keyword arguments across the jax versions we target
-is imported **only** here, behind a feature probe, and re-exported under one
-stable name.  The rest of the codebase imports from ``repro.compat`` and
-never touches ``jax.experimental`` churn directly.  When the next jax
-release moves something, one file changes.
+The repo targets exactly the jax pinned in ``pyproject.toml`` (0.9.0); there
+are no branches for other versions.  What stays here:
 
-Currently absorbed drift:
-
-* ``shard_map`` — lived at ``jax.experimental.shard_map.shard_map``, is
-  being promoted to ``jax.shard_map``; its replication-check kwarg was
-  renamed ``check_rep`` -> ``check_vma``.  :func:`shard_map` accepts either
-  spelling and forwards whichever the installed jax understands.
-* Pallas platform modules — ``jax.experimental.pallas`` and its ``tpu`` /
-  ``triton`` submodules are optional per build.  They are imported guarded;
-  availability predicates (:func:`has_pallas_tpu`, ...) let callers gate
-  backend-specific code instead of crashing at import time.
-* ``pltpu.TPUCompilerParams`` was renamed ``pltpu.CompilerParams``;
-  :func:`tpu_compiler_params` builds whichever class exists and silently
-  drops fields the installed version does not know.
-* Tree utilities — ``jax.tree_util.tree_*`` vs the newer ``jax.tree.*``
-  namespace; stable names :func:`tree_map` etc. pick whichever exists.
+* ``shard_map`` and the tree utilities, under the names callers use;
+* the Pallas platform modules (``jax.experimental.pallas`` and its ``tpu`` /
+  ``triton`` submodules), imported guarded so a host without one can still
+  import the kernels.  On a TPU backend a Pallas TPU module that failed to
+  import is an error (:func:`has_pallas_tpu` raises), never a silent
+  absence;
+* backend probes and the TPU compiler-params builder, which passes its
+  fields straight through (an unknown field raises).
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Callable, Sequence
+from typing import Any, Sequence
 
 import jax
 
 __all__ = [
-    "jax_version",
     "shard_map",
     "tree_map", "tree_leaves", "tree_flatten", "tree_unflatten",
     "tree_structure",
@@ -43,91 +31,25 @@ __all__ = [
     "abstract_mesh", "cost_analysis",
 ]
 
+shard_map = jax.shard_map
 
-def _version_tuple(v: str) -> tuple[int, ...]:
-    parts = []
-    for p in v.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-#: installed jax version as a comparable tuple, e.g. (0, 4, 37)
-jax_version: tuple[int, ...] = _version_tuple(jax.__version__)
-
-
-# -- shard_map -------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):                       # jax >= 0.6-ish
-    _shard_map = jax.shard_map
-else:                                               # pre-promotion location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_KWARGS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f: Callable, mesh: Any, in_specs: Any, out_specs: Any,
-              **kwargs: Any) -> Callable:
-    """Version-tolerant ``shard_map``.
-
-    Accepts the replication-check flag under either of its historical names
-    (``check_vma`` new, ``check_rep`` old) and forwards whichever spelling
-    the installed jax understands; other unknown kwargs are dropped rather
-    than exploding on older versions.
-    """
-    check = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-    if check is not None:
-        if "check_vma" in _SHARD_MAP_KWARGS:
-            kwargs["check_vma"] = check
-        elif "check_rep" in _SHARD_MAP_KWARGS:
-            kwargs["check_rep"] = check
-    kwargs = {k: v for k, v in kwargs.items() if k in _SHARD_MAP_KWARGS}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
-# -- tree utilities --------------------------------------------------------------
-
-_tree_ns = getattr(jax, "tree", None)
-if _tree_ns is not None and hasattr(_tree_ns, "map"):
-    tree_map = _tree_ns.map
-    tree_leaves = _tree_ns.leaves
-    tree_flatten = _tree_ns.flatten
-    tree_unflatten = _tree_ns.unflatten
-    tree_structure = _tree_ns.structure
-else:                                               # pragma: no cover - old jax
-    tree_map = jax.tree_util.tree_map
-    tree_leaves = jax.tree_util.tree_leaves
-    tree_flatten = jax.tree_util.tree_flatten
-    tree_unflatten = jax.tree_util.tree_unflatten
-    tree_structure = jax.tree_util.tree_structure
+tree_map = jax.tree.map
+tree_leaves = jax.tree.leaves
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
+tree_structure = jax.tree.structure
 
 
 # -- meshes ----------------------------------------------------------------------
 
 def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]) -> Any:
-    """Version-tolerant ``jax.sharding.AbstractMesh``.
-
-    Newer jax takes ``(axis_sizes, axis_names)``; older versions take a
-    single ``((name, size), ...)`` shape tuple.  Probe the new form first.
-    """
-    cls = jax.sharding.AbstractMesh
-    try:
-        return cls(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return cls(tuple(zip(axis_names, axis_sizes)))
+    """``jax.sharding.AbstractMesh`` (its axes default to ``Auto``)."""
+    return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def cost_analysis(compiled: Any) -> dict:
-    """Normalized ``Compiled.cost_analysis()``.
-
-    Older jax returns a one-element list of per-device dicts; newer jax
-    returns the dict directly.  Always returns a (possibly empty) dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    """``Compiled.cost_analysis()`` as a (possibly empty) dict."""
+    return dict(compiled.cost_analysis() or {})
 
 
 # -- pallas platform modules -----------------------------------------------------
@@ -137,10 +59,12 @@ try:
 except Exception:                                   # pragma: no cover
     pallas = None
 
+_PALLAS_TPU_ERROR: Exception | None = None
 try:
     from jax.experimental.pallas import tpu as pallas_tpu
-except Exception:                                   # pragma: no cover
+except Exception as e:                              # pragma: no cover
     pallas_tpu = None
+    _PALLAS_TPU_ERROR = e
 
 try:
     from jax.experimental.pallas import triton as pallas_triton
@@ -155,7 +79,14 @@ def has_pallas() -> bool:
 
 def has_pallas_tpu() -> bool:
     """The Pallas TPU platform module is importable (needed for VMEM scratch
-    and TPU compiler params, including in interpret mode)."""
+    and TPU compiler params, including in interpret mode).
+
+    On a TPU backend a failed import raises: the kernels would otherwise
+    quietly run as their ``xla_ref`` fallback on the chip."""
+    if pallas_tpu is None and on_tpu():
+        raise RuntimeError(
+            "jax.experimental.pallas.tpu failed to import on a TPU backend"
+        ) from _PALLAS_TPU_ERROR
     return pallas_tpu is not None
 
 
@@ -201,23 +132,12 @@ def on_tpu() -> bool:
 # -- TPU compiler params / scratch -----------------------------------------------
 
 def tpu_compiler_params(**kwargs: Any) -> Any:
-    """Build the TPU Pallas compiler-params object for the installed jax.
-
-    Absorbs the ``TPUCompilerParams`` -> ``CompilerParams`` rename and drops
-    fields the installed class does not define.  Returns ``None`` when the
-    TPU platform module is unavailable (``pallas_call`` accepts that).
-    """
+    """``pltpu.CompilerParams(**kwargs)``; an unknown field raises.
+    Returns ``None`` when the TPU platform module is unavailable
+    (``pallas_call`` accepts that)."""
     if pallas_tpu is None:
         return None
-    cls = getattr(pallas_tpu, "CompilerParams", None) \
-        or getattr(pallas_tpu, "TPUCompilerParams", None)
-    if cls is None:                                 # pragma: no cover
-        return None
-    import dataclasses
-    if dataclasses.is_dataclass(cls):
-        known = {f.name for f in dataclasses.fields(cls)}
-        kwargs = {k: v for k, v in kwargs.items() if k in known}
-    return cls(**kwargs)
+    return pallas_tpu.CompilerParams(**kwargs)
 
 
 def vmem(shape: Sequence[int], dtype: Any) -> Any:

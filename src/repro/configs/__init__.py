@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from repro.models import ModelConfig
 
 __all__ = ["ARCHS", "ARCH_IDS", "SHAPES", "Shape", "get_config", "get_reduced",
-           "supported_shapes", "input_specs"]
+           "select", "supported_shapes", "input_specs"]
 
 ARCHS = (
     "kimi_k2_1t_a32b",
@@ -79,6 +79,15 @@ def get_config(name: str) -> ModelConfig:
 def get_reduced(name: str) -> ModelConfig:
     """Reduced same-family config for CPU smoke tests."""
     return _module(name).reduced()
+
+
+def select(name: str, reduced: bool = False) -> ModelConfig:
+    """What a launch driver runs: the published config in its own compute
+    dtype, or — only when asked for with ``reduced`` — the reduced
+    same-family preset in float32 (CPU recipes and tests)."""
+    if reduced:
+        return get_reduced(name).replace(compute_dtype="float32")
+    return get_config(name)
 
 
 def supported_shapes(cfg: ModelConfig) -> list[str]:

@@ -225,6 +225,12 @@ class CompileService:
         return len(cancelled)
 
     # -- waiting ----------------------------------------------------------------
+    def busy(self) -> bool:
+        """Whether any build is pending or running."""
+        with self._lock:
+            return any(r.status in ("pending", "running")
+                       for r in self._inflight.values())
+
     def drain(self, handler: str | None = None,
               timeout: float | None = None) -> bool:
         """Block until every pending/running request (for ``handler``) is

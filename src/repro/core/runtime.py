@@ -667,8 +667,9 @@ class Handler:
         if activate:
             # The policy has moved past any still-queued activation for a
             # different config *in this context*: cancel before a worker
-            # wastes a compile.
-            svc.cancel_pending(self.name, keep_keys={key},
+            # wastes a compile.  The generic variant's backfill stays (see
+            # prefetch).
+            svc.cancel_pending(self.name, keep_keys={key, ctx.generic_key},
                                max_priority=PRIORITY_ACTIVATE,
                                key_filter=lambda k: k[0] == ctx.key)
         if existing is not None:
@@ -747,9 +748,12 @@ class Handler:
                                 speculative=True)
             if not fut.cancelled():      # sync runtimes skip speculation
                 enqueued += 1
+        # The generic variant's AOT backfill is not a candidate the policy
+        # can move past: it is every variant's guard-miss fallback, and a
+        # warm restart loads it from the variant cache only if it was built.
         self.runtime.compile_service.cancel_pending(
-            self.name, keep_keys=keep_keys, speculative_only=True,
-            key_filter=lambda k: k[0] == ctx.key)
+            self.name, keep_keys=keep_keys | {ctx.generic_key},
+            speculative_only=True, key_filter=lambda k: k[0] == ctx.key)
         return enqueued
 
     def despecialize(self, wait: bool = True, context: Any = ...) -> None:

@@ -308,7 +308,9 @@ class SafetyController(Controller):
             self._emit("safety.shadow_verdict", ctl, config=repr(cfg),
                        metric=verdict.get("metric"),
                        in_slo=bool(verdict.get("in_slo")),
-                       pairs=verdict.get("pairs"))
+                       pairs=verdict.get("pairs"),
+                       candidate_s=verdict.get("candidate_s"),
+                       incumbent_s=verdict.get("incumbent_s"))
             if not verdict["in_slo"]:
                 st.shadow_rejected.add(config_key(cfg))
                 self.shadow_rejections += 1

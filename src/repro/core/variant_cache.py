@@ -38,7 +38,7 @@ logger = logging.getLogger("repro.core.variant_cache")
 
 __all__ = ["VariantCache", "spec_fingerprint", "backend_fingerprint"]
 
-_FORMAT_VERSION = 1
+_FORMAT_VERSION = 2
 _SUFFIX = ".var"
 
 
@@ -140,8 +140,12 @@ class VariantCache:
             with open(path, "rb") as f:
                 entry = pickle.load(f)
             blob, in_tree, out_tree = entry["payload"]
+            # Load onto the devices it was compiled for: by default JAX
+            # would spread a one-device executable over every local device.
+            by_id = {d.id: d for d in jax.devices()}
             compiled = serialize_executable.deserialize_and_load(
-                blob, in_tree, out_tree)
+                blob, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in entry["device_ids"]])
             self.stats.hits.bump()
             try:
                 os.utime(path, None)     # refresh last_used for LRU eviction
@@ -171,6 +175,8 @@ class VariantCache:
             entry = {"format": _FORMAT_VERSION,
                      "backend": backend_fingerprint(self.portable),
                      "meta": dict(meta or {}),
+                     "device_ids": [d.id for d in compiled.runtime_executable()
+                                    .local_devices()],
                      "payload": payload}
             blob = pickle.dumps(entry)
         except Exception as e:

@@ -77,7 +77,8 @@ def _attention_pallas_tpu(q, k, v, **kw):
 
 @registry.register("attention", "pallas_interpret", priority=-10,
                    supports_grad=False,
-                   guard=_guard, available=compat.has_pallas_tpu,
+                   guard=_guard, available=lambda: compat.on_cpu()
+                   and compat.has_pallas_tpu(),
                    description="flash kernel under the interpreter")
 def _attention_pallas_interpret(q, k, v, **kw):
     kw.pop("swa_impl", None)

@@ -33,7 +33,7 @@ def _fastpath_kernel(x_ref, k_ref, v_ref, o_ref, hit_ref):
     keys = k_ref[...]                    # (N, K)
     vals = v_ref[...]                    # (N, V)
     match = jnp.all(x[:, None, :] == keys[None, :, :], axis=-1)  # (block_b, N)
-    hit_ref[...] = jnp.any(match, axis=-1).astype(jnp.int32)
+    hit_ref[...] = jnp.any(match, axis=-1, keepdims=True).astype(jnp.int32)
     onehot = match.astype(vals.dtype)
     o_ref[...] = jax.lax.dot(onehot, vals,
                              preferred_element_type=jnp.float32
@@ -63,12 +63,14 @@ def fastpath_lookup_pallas(
         ],
         out_specs=[
             pl.BlockSpec((block_b, v), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            # (block_b, 1) rather than a 1-D (block_b,) block: Mosaic
+            # tiles 1-D int32 blocks differently from XLA and refuses them.
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, v), values.dtype),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         interpret=interpret,
     )(x, keys, values)
-    return out, hit.astype(bool)
+    return out, hit[:, 0].astype(bool)

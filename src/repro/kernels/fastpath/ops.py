@@ -61,7 +61,8 @@ def _lookup_pallas_gpu(x, keys, values, *, block_b=256):
 
 @registry.register("fastpath", "pallas_interpret", priority=-10,
                    supports_grad=False,
-                   guard=_guard, available=compat.has_pallas,
+                   guard=_guard, available=lambda: compat.on_cpu()
+                   and compat.has_pallas(),
                    description="matcher kernel under the interpreter")
 def _lookup_pallas_interpret(x, keys, values, *, block_b=256):
     return _pallas_lookup(x, keys, values, block_b=block_b, interpret=True)

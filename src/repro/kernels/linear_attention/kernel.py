@@ -5,7 +5,7 @@ One grid step processes one (batch*head, chunk) tile; the recurrent state
 dimension (grid-minor, "arbitrary" semantics), so the whole recurrence runs
 without ever spilling state to HBM:
 
-    la   = cumsum(log_w)                       # (c, dk) in-register
+    la   = cumsum(log_w)                       # (c, dk), as tril @ log_w
     out  = (q . exp(la_q)) @ S                 # inter-chunk (MXU)
          + tril((q.exp(la_q)) @ (k.exp(-la))^T [+ diag bonus]) @ v
     S   <- exp(la_c) * S + (k . exp(la_c - la))^T @ v
@@ -42,20 +42,24 @@ def _gla_kernel(q_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
     v = v_ref[0].astype(f32)                  # (c, dv)
     lw = w_ref[0].astype(f32)                 # (c, dk)
 
-    la = jnp.cumsum(lw, axis=0)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # cumsum over the chunk as a lower-triangular matmul (Mosaic has no
+    # cumsum lowering); HIGHEST keeps the running log-decay in fp32.
+    la = jax.lax.dot(jnp.where(rows >= cols, 1.0, 0.0).astype(f32), lw,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=f32)       # (c, dk)
     la_q = la if inclusive else la - lw
-    la_tot = la[-1]                           # (dk,)
+    la_tot = la[chunk - 1]                    # (dk,)
 
     qt = q * jnp.exp(la_q)
     kt = k * jnp.exp(-la)
     scores = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
                                  preferred_element_type=f32)   # (c, c)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     mask = (rows >= cols) if inclusive else (rows > cols)
     scores = jnp.where(mask, scores, 0.0)
     if use_bonus:
-        u = u_ref[0].astype(f32)              # (1, dk) -> (dk,)
+        u = u_ref[0].astype(f32)              # (1, dk), broadcast over c
         diag = jnp.sum(q * u * k, axis=-1)    # (c,)
         scores = scores + diag[:, None] * jnp.where(
             rows == cols, 1.0, 0.0)
@@ -91,6 +95,10 @@ def linear_attention_pallas(
     use_bonus = bonus is not None
     if bonus is None:
         bonus = jnp.zeros((bh, dk), q.dtype)
+    # (BH, 1, dk): a (1, dk) block spans the array's last two dims whole,
+    # which the TPU (8, 128) tiling rule accepts; a (1, dk) block of a
+    # (BH, dk) array does not.
+    bonus = bonus.reshape(bh, 1, dk)
 
     kernel = functools.partial(_gla_kernel, inclusive=inclusive,
                                use_bonus=use_bonus, chunk=chunk)
@@ -102,7 +110,7 @@ def linear_attention_pallas(
             pl.BlockSpec((1, chunk, dk), lambda h, i: (h, i, 0)),
             pl.BlockSpec((1, chunk, dv), lambda h, i: (h, i, 0)),
             pl.BlockSpec((1, chunk, dk), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((1, dk), lambda h, i: (h, 0)),
+            pl.BlockSpec((1, 1, dk), lambda h, i: (h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, dv), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, dv), v.dtype),

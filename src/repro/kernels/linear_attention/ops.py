@@ -61,7 +61,8 @@ def _linatt_pallas_tpu(q, k, v, log_w, *, bonus=None, inclusive=False,
 
 @registry.register("linear_attention", "pallas_interpret", priority=-10,
                    supports_grad=False,
-                   guard=_guard, available=compat.has_pallas_tpu,
+                   guard=_guard, available=lambda: compat.on_cpu()
+                   and compat.has_pallas_tpu(),
                    description="recurrence kernel under the interpreter")
 def _linatt_pallas_interpret(q, k, v, log_w, *, bonus=None, inclusive=False,
                              chunk=64):

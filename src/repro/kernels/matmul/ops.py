@@ -73,7 +73,8 @@ def _matmul_pallas_tpu(x, y, *, bm=128, bn=128, bk=128, out_dtype=None,
 
 @registry.register("matmul", "pallas_interpret", priority=-10,
                    supports_grad=False, guard=_guard,
-                   available=compat.has_pallas_tpu,
+                   available=lambda: compat.on_cpu()
+                   and compat.has_pallas_tpu(),
                    description="Pallas kernel under the interpreter "
                                "(kernel-logic validation on any host)")
 def _matmul_pallas_interpret(x, y, *, bm=128, bn=128, bk=128, out_dtype=None,

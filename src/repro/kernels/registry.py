@@ -24,8 +24,9 @@ Canonical entry names:
                          the fallback target.  (Legacy alias: ``"xla"``.)
 * ``pallas_tpu``       — the Pallas TPU kernel; needs the TPU platform
                          module AND a TPU backend.  (Legacy: ``"pallas"``.)
-* ``pallas_interpret`` — the same Pallas kernel body run by the interpreter
-                         on the host; validates kernel logic anywhere.
+* ``pallas_interpret`` — the same Pallas kernel body run by the interpreter;
+                         available on a CPU backend only, so it is never an
+                         exploration candidate on a chip.
                          (Legacy alias: ``"interpret"``.)
 * ``pallas_gpu``       — Triton-lowered Pallas where a family provides a
                          platform-neutral kernel body; needs a GPU backend.
@@ -95,12 +96,10 @@ class KernelImpl:
     description: str = ""
 
     def is_available(self) -> bool:
-        try:
-            return bool(self.available())
-        except Exception:                     # defensive: probe must not kill
-            logger.exception("availability probe failed for %s/%s",
-                             self.family, self.name)
-            return False
+        # A probe that raises is a fault, not an absence: swallowing it
+        # would turn e.g. a broken Pallas TPU import on the chip into a
+        # silent run on xla_ref.
+        return bool(self.available())
 
 
 class KernelRegistry:
@@ -197,9 +196,13 @@ class KernelRegistry:
         entry = self.get(family, impl)
         if entry.is_available():
             return entry
+        # The named entry has no implementation on this platform (a config
+        # tuned on a TPU replayed on a CPU host): counted, warned once.
         self._count_fallback(family, entry.name)
-        logger.debug("impl %s/%s unavailable on this host; falling back to "
-                     "%s", family, entry.name, FALLBACK_IMPL)
+        if self.fallback_counts[(family, entry.name)] == 1:
+            logger.warning("impl %s/%s is unavailable on this platform; "
+                           "running %s instead", family, entry.name,
+                           FALLBACK_IMPL)
         return self.get(family, FALLBACK_IMPL)
 
     def dispatch(self, family: str, impl: str | None,

@@ -63,7 +63,8 @@ def _rmsnorm_pallas_gpu(x, weight, *, eps=1e-6, block_rows=256):
 
 @registry.register("rmsnorm", "pallas_interpret", priority=-10,
                    supports_grad=False, guard=_guard,
-                   available=compat.has_pallas,
+                   available=lambda: compat.on_cpu()
+                   and compat.has_pallas(),
                    description="Pallas kernel under the interpreter")
 def _rmsnorm_pallas_interpret(x, weight, *, eps=1e-6, block_rows=256):
     return _pallas_rmsnorm(x, weight, eps=eps, block_rows=block_rows,

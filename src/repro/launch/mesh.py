@@ -11,6 +11,13 @@ import jax
 __all__ = ["make_production_mesh", "make_local_mesh"]
 
 
+def _auto(n: int) -> tuple:
+    """``Auto`` axis types: the sharding rules place arrays through
+    ``with_sharding_constraint``, which only refers to ``Auto`` axes
+    (``jax.make_mesh`` defaults to ``Explicit``)."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """The target deployment mesh.
 
@@ -21,11 +28,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(shape)))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over the locally available devices (tests / CPU runs)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))

@@ -2,6 +2,7 @@
 
 Run:
     PYTHONPATH=src python -m repro.launch.serve --steps 300
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve --reduced
 
 The driver is built on the :mod:`repro.serve` engine: requests arrive
 open-loop (deterministic pseudo-Poisson at ``--rate``), pass through a
@@ -23,17 +24,20 @@ points ride the same machinery: the bucket-boundary scheme
 size vs. contiguous-per-request), both searched online against measured
 goodput (in-SLO tokens/s).
 
-**Fleet mode** (``--replicas N`` with N > 1): the process becomes a
-router front instead of an engine.  It spawns N subprocess workers
-(:mod:`repro.serve.fleet.worker` ``--profile lm`` — each the exact
-engine stack above), spreads the open-loop load across them with the
-``--router`` policy (round-robin / join-shortest-queue / deadline-aware
-spill), and reports fleet-merged metrics.  With ``--plane-dir`` the
-replicas share a specialization plane
+The model is served at its published widths in its own compute dtype
+(``configs.get_config``); ``--reduced`` asks for the reduced same-family
+preset in float32, which is what CPU runs and tests use.
+
+**Fleet mode** (``--replicas N`` with N > 1): N engine replicas in this
+process, one per device (``jax.devices()[i]``; a chip belongs to one
+process, so one process drives them all), each served by its own thread
+behind a :class:`~repro.serve.fleet.ReplicaRouter` that spreads the
+open-loop load with the ``--router`` policy (round-robin /
+join-shortest-queue / deadline-aware spill); it reports fleet-merged
+metrics.  With ``--plane-dir`` the replicas share a specialization plane
 (:class:`~repro.serve.fleet.SpecPlane`): each publishes its settled
 per-context winners and seeds remotely-settled ones, so one replica's
-exploration warm-starts the rest — combine with a shared ``--cache-dir
---portable-cache`` and the warm starts are also compile-free.
+exploration warm-starts the rest.
 ``--plane-dir`` also works at ``--replicas 1``: the single engine polls
 the plane before serving and publishes its winners after draining
 (cross-*run* warm start through the plane instead of spec_state.json).
@@ -58,10 +62,12 @@ import json
 import os
 import random
 import sys
+import threading
 import time
 from types import SimpleNamespace
 
 from repro.core import telemetry
+from repro.launch.jax_cache import enable_compile_cache
 from repro.serve import Request, pseudo_poisson_times
 
 KV_PAGE_SIZES = (8, 16, 64)
@@ -84,27 +90,13 @@ def synthetic_workload(n: int, rate: float, seed: int = 0,
             for t in times[:n]]
 
 
-#: (flag, args attribute) for every engine flag — the fleet front
-#: forwards these verbatim to its ``--profile lm`` workers.
-_ENGINE_FLAGS = (
-    ("--arch", "arch"), ("--batch", "batch"), ("--max-len", "max_len"),
-    ("--steps", "steps"), ("--dwell", "dwell"),
-    ("--compile-workers", "compile_workers"), ("--prefetch", "prefetch"),
-    ("--budget", "budget"), ("--cache-dir", "cache_dir"),
-    ("--kv-page-size", "kv_page_size"), ("--prefill-chunk", "prefill_chunk"),
-    ("--requests", "requests"), ("--rate", "rate"), ("--slo-ms", "slo_ms"),
-    ("--queue-depth", "queue_depth"), ("--shed-policy", "shed_policy"),
-    ("--scheduler", "scheduler"), ("--bucket-dwell", "bucket_dwell"),
-    ("--kv-dwell", "kv_dwell"), ("--seed", "seed"),
-    ("--shadow-frac", "shadow_frac"), ("--canary-frac", "canary_frac"),
-    ("--promote-after", "promote_after"),
-)
-
-
 def add_engine_args(ap: argparse.ArgumentParser) -> None:
-    """The single-engine flag set, shared between this driver and the
-    fleet worker (:mod:`repro.serve.fleet.worker` ``--profile lm``)."""
+    """The single-engine flag set (every replica of a fleet shares it)."""
     ap.add_argument("--arch", default="qwen3-0.6b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the reduced same-family preset in float32 "
+                         "(CPU runs and tests) instead of the published "
+                         "config")
     ap.add_argument("--batch", type=int, default=8,
                     help="batch cap = largest batch-shape bucket")
     ap.add_argument("--max-len", type=int, default=256)
@@ -167,11 +159,13 @@ def add_engine_args(ap: argparse.ArgumentParser) -> None:
                          "plain Controller (pre-safety behavior)")
 
 
-def build_engine(args) -> SimpleNamespace:
+def build_engine(args, device=None) -> SimpleNamespace:
     """Build the full single-replica serving stack from parsed engine
-    args; returns the runtime, engine, and every tuned part (the fleet
-    worker runs exactly this stack per replica)."""
+    args; returns the runtime, engine, and every tuned part (a fleet runs
+    exactly this stack per replica).  ``device`` holds the replica's
+    weights and step inputs (``None``: the default device)."""
     import jax
+    from jax.sharding import SingleDeviceSharding
 
     from repro import configs
     from repro.checkpoint import load_safety_state, restore_spec_state
@@ -190,7 +184,8 @@ def build_engine(args) -> SimpleNamespace:
     from repro.serve.kv import KV_LAYOUT_POINT, KV_PAGE_POINT
     from repro.training import make_serve_builder, phase_context_fn
 
-    cfg = configs.get_reduced(args.arch).replace(compute_dtype="float32")
+    cfg = configs.select(args.arch, args.reduced)
+    device = device if device is not None else jax.devices()[0]
     variant_cache = None
     if args.cache_dir:
         variant_cache = VariantCache(
@@ -200,7 +195,7 @@ def build_engine(args) -> SimpleNamespace:
                            max_compile_workers=args.compile_workers,
                            variant_cache=variant_cache)
     handler = rt.register(
-        "serve_step", make_serve_builder(cfg, kernel_impl="xla"),
+        "serve_step", make_serve_builder(cfg),
         context_fn=phase_context_fn,          # (phase, bucket) contexts
         donate_argnums=1)
     batcher = ContinuousBatcher(args.batch)
@@ -230,12 +225,12 @@ def build_engine(args) -> SimpleNamespace:
             initial_plan = (kv_cfg[KV_LAYOUT_POINT],
                             kv_cfg.get(KV_PAGE_POINT, args.kv_page_size))
 
-    params = model.init_params(jax.random.PRNGKey(0), cfg)
-    run_opts = RunOptions(decode_cache_dtype="float32")
+    params = init_serving_params(cfg, SingleDeviceSharding(device))
+    run_opts = RunOptions(decode_cache_dtype=cfg.compute_dtype)
     kv = PagedKV(model.init_cache(cfg, 1, args.max_len, run_opts),
                  model.cache_axes(cfg), max_len=args.max_len,
                  capacity_tokens=args.batch * args.max_len,
-                 page_size=args.kv_page_size)
+                 page_size=args.kv_page_size, device=device)
     executor = PhasedExecutor(handler, params, kv,
                               prefill_chunk=args.prefill_chunk,
                               vocab_size=cfg.vocab_size)
@@ -277,11 +272,11 @@ def build_engine(args) -> SimpleNamespace:
     metrics = ServeMetrics(slo_s=slo_s)
     tuner = BucketTuner(batcher, metric=metrics.interval_goodput,
                         dwell=args.bucket_dwell, plan_handler=plan_handler,
-                        initial_scheme=initial_scheme)
+                        initial_scheme=initial_scheme, device=device)
     kv_tuner = KVTuner(kv, metric=metrics.interval_goodput,
                        dwell=args.kv_dwell, page_sizes=page_sizes,
                        plan_handler=kv_plan_handler,
-                       initial_plan=initial_plan)
+                       initial_plan=initial_plan, device=device)
     engine = ServeEngine(
         handler, controller, batcher, make_scheduler(args.scheduler),
         executor=executor,
@@ -292,7 +287,22 @@ def build_engine(args) -> SimpleNamespace:
         rt=rt, engine=engine, handler=handler, controller=controller,
         batcher=batcher, tuner=tuner, kv_tuner=kv_tuner, kv=kv,
         metrics=metrics, restored=restored, initial_scheme=initial_scheme,
-        initial_plan=initial_plan, shadow=shadow)
+        initial_plan=initial_plan, shadow=shadow, cfg=cfg, params=params,
+        executor=executor, device=device, policy_factory=policy_factory)
+
+
+def init_serving_params(cfg, sharding):
+    """Seeded random weights (``PRNGKey(0)``) in the config's compute
+    dtype, placed by ``sharding``: serving keeps no float32 masters."""
+    import jax
+
+    from repro.models import transformer as model
+
+    def init(key):
+        return jax.tree.map(lambda a: a.astype(cfg.compute_dtype),
+                            model.init_params(key, cfg))
+
+    return jax.jit(init, out_shardings=sharding)(jax.random.PRNGKey(0))
 
 
 def build_tenant_engine(args, tenants) -> SimpleNamespace:
@@ -310,6 +320,7 @@ def build_tenant_engine(args, tenants) -> SimpleNamespace:
     (tenant engines run plain Controllers with a fixed bucket scheme).
     """
     import jax
+    from jax.sharding import SingleDeviceSharding
 
     from repro import configs
     from repro.checkpoint import restore_spec_state
@@ -338,10 +349,10 @@ def build_tenant_engine(args, tenants) -> SimpleNamespace:
 
     stacks = {}
     for spec in tenants:
-        cfg = configs.get_reduced(spec.arch).replace(compute_dtype="float32")
+        cfg = configs.select(spec.arch, args.reduced)
         handler = rt.register(
             f"serve_step[{spec.name}]",
-            make_serve_builder(cfg, kernel_impl="xla"),
+            make_serve_builder(cfg),
             context_fn=make_tenant_context_fn(spec.name, phase_context_fn),
             donate_argnums=1)
         stacks[spec.name] = SimpleNamespace(spec=spec, cfg=cfg,
@@ -360,8 +371,9 @@ def build_tenant_engine(args, tenants) -> SimpleNamespace:
     for spec in tenants:
         st = stacks[spec.name]
         cfg = st.cfg
-        params = model.init_params(jax.random.PRNGKey(0), cfg)
-        run_opts = RunOptions(decode_cache_dtype="float32")
+        params = init_serving_params(
+            cfg, SingleDeviceSharding(jax.devices()[0]))
+        run_opts = RunOptions(decode_cache_dtype=cfg.compute_dtype)
         kv = PagedKV(model.init_cache(cfg, 1, args.max_len, run_opts),
                      model.cache_axes(cfg), max_len=args.max_len,
                      capacity_tokens=args.batch * args.max_len,
@@ -565,55 +577,108 @@ def _export_trace(args) -> None:
           f"{args.trace_out} ({json.dumps(_tb.stats())})")
 
 
+def fleet_router(builts, policy: str = "jsq"):
+    """A :class:`~repro.serve.fleet.ReplicaRouter` over in-process
+    replicas (``builts``: :func:`build_engine` results, each on its own
+    device)."""
+    from repro.serve.fleet import LocalReplica, ReplicaRouter
+
+    devices = [b.device for b in builts]
+    if len(set(devices)) != len(devices):
+        raise ValueError(f"replicas share a device: {devices}")
+    return ReplicaRouter([LocalReplica(b.engine, name=str(i))
+                          for i, b in enumerate(builts)], policy=policy)
+
+
+def serve_fleet(builts, front, schedule, *, plane_dir: str | None = None,
+                plane_poll_s: float = 0.5) -> float:
+    """Serve ``schedule`` open-loop through ``front`` (:func:`fleet_router`
+    over ``builts``), one serving thread per replica; returns the wall
+    seconds.
+
+    Each replica drains its own queue until the schedule is exhausted and
+    it is idle.  With ``plane_dir`` every replica polls the shared
+    :class:`~repro.serve.fleet.SpecPlane` before serving and on
+    ``plane_poll_s`` while serving, publishing its settled winners."""
+    from repro.serve import OpenLoopSource
+    from repro.serve.fleet import SpecPlane
+
+    planes = [SpecPlane(plane_dir, replica=str(i),
+                        quarantine=getattr(b.controller, "quarantine", None))
+              if plane_dir else None for i, b in enumerate(builts)]
+    for plane, b in zip(planes, builts):
+        if plane is not None:
+            plane.poll(b.rt)
+    source = OpenLoopSource(front, schedule)
+    closed = threading.Event()
+    errors: list[BaseException] = []
+
+    def serve(b, plane) -> None:
+        engine = b.engine
+        last_plane = time.perf_counter()
+        try:
+            while not (closed.is_set() and not engine.active
+                       and not len(engine.queue)):
+                if engine.step() == 0 and not engine.active:
+                    time.sleep(0.001)
+                now = time.perf_counter()
+                if plane is not None and now - last_plane >= plane_poll_s:
+                    plane.poll(b.rt)
+                    plane.publish_controller("serve_step", b.controller)
+                    last_plane = now
+            engine.drain(timeout_s=60.0)
+            if plane is not None:
+                plane.publish_controller("serve_step", b.controller)
+        except BaseException as e:            # surfaced by the front below
+            errors.append(e)
+
+    threads = [threading.Thread(target=serve, args=(b, plane),
+                                name=f"replica-{i}", daemon=True)
+               for i, (b, plane) in enumerate(zip(builts, planes))]
+    t0 = time.perf_counter()
+    for t in threads:
+        t.start()
+    while not source.exhausted and not errors:
+        source.pump(time.perf_counter())
+        delay = source.next_due(time.perf_counter())
+        if delay:
+            time.sleep(min(delay, 0.02))
+    closed.set()
+    for t in threads:
+        t.join()
+    if errors:
+        raise errors[0]
+    return time.perf_counter() - t0
+
+
 def _run_fleet(args) -> None:
-    """Router front: N subprocess lm workers behind a routing policy."""
-    from repro.serve import OpenLoopSource, ServeMetrics, substream_seed
-    from repro.serve.fleet import ReplicaRouter
-    from repro.serve.fleet.worker import (SubprocessReplica, worker_command,
-                                          worker_env)
+    """Router front over N in-process replicas, one per device."""
+    import jax
 
-    passthrough: list[str] = []
-    for flag, attr in _ENGINE_FLAGS:
-        v = getattr(args, attr)
-        if v is not None:
-            passthrough += [flag, str(v)]
-    if args.portable_cache:
-        passthrough.append("--portable-cache")
-    if args.no_safety:
-        passthrough.append("--no-safety")
-    if args.trace_out or args.telemetry_snapshot:
-        # Workers run their own flight recorder and forward the stream;
-        # SubprocessReplica absorbs it onto this front's bus per replica.
-        passthrough.append("--telemetry")
-    env = worker_env()
-    replicas = []
-    for i in range(args.replicas):
-        cmd = worker_command("--profile", "lm", "--replica-id", str(i),
-                             *passthrough)
-        if args.plane_dir:
-            cmd += ["--plane-dir", args.plane_dir,
-                    "--plane-poll-s", str(args.plane_poll_s)]
-        replicas.append(SubprocessReplica(cmd, name=str(i), env=env))
-    print(f"fleet: spawned {args.replicas} lm workers "
+    from repro.serve import ServeMetrics, substream_seed
+
+    devices = jax.devices()
+    if args.replicas > len(devices):
+        raise SystemExit(f"--replicas {args.replicas} needs one device per "
+                         f"replica; this host has {len(devices)}")
+    builts = [build_engine(args, device=devices[i])
+              for i in range(args.replicas)]
+    for i, b in enumerate(builts):
+        print(f"replica {i}: device {b.device.id} ({b.device.device_kind})")
+    print(f"fleet: {args.replicas} in-process replicas "
           f"(router={args.router}, plane={args.plane_dir or 'off'})")
-    for r in replicas:
-        if not r.wait_ready(300.0):
-            for other in replicas:
-                other.close()
-            raise RuntimeError(f"replica {r.name} failed to start")
-
     # Per-replica substreams of the root seed: N times the single-replica
     # offered load without N byte-identical arrival processes.
     schedule: list = []
     for i in range(args.replicas):
         schedule += synthetic_workload(args.requests, args.rate,
                                        seed=substream_seed(args.seed, i))
-    router = ReplicaRouter(replicas, policy=args.router)
-    source = OpenLoopSource(router, schedule)
+    front = fleet_router(builts, args.router)
 
     def fleet_provider() -> dict:
-        doc = {"mode": "fleet", "router": router.stats(),
-               "replicas": {r.name: {"depth": r.depth()} for r in replicas}}
+        doc = {"mode": "fleet", "router": front.stats(),
+               "replicas": {r.name: {"depth": r.depth()}
+                            for r in front.replicas}}
         _tb = telemetry.bus()
         if _tb is not None:
             doc["bus"] = _tb.stats()
@@ -622,34 +687,25 @@ def _run_fleet(args) -> None:
     snap = (telemetry.SnapshotWriter(args.telemetry_snapshot, fleet_provider,
                                      interval_s=args.snapshot_interval_s)
             if args.telemetry_snapshot else None)
-    while not source.exhausted:
-        source.pump(time.perf_counter())
-        delay = source.next_due(time.perf_counter())
-        if delay:
-            time.sleep(min(delay, 0.02))
-    for r in replicas:
-        r.close()
-    stats = [r.join(300.0) for r in replicas]
-    alive = [s for s in stats if s is not None]
-    print(f"router: {json.dumps(router.stats())}")
-    if not alive:
-        raise RuntimeError("no replica returned stats")
-    merged = ServeMetrics.merge(*(s["metrics"] for s in alive)).summary()
-    wall = max(s["wall_s"] for s in alive)
+    wall = serve_fleet(builts, front, schedule, plane_dir=args.plane_dir,
+                       plane_poll_s=args.plane_poll_s)
+    print(f"router: {json.dumps(front.stats())}")
+    merged = ServeMetrics.merge(*(b.metrics for b in builts)).summary()
     print(f"fleet served {merged['completed']} requests / "
-          f"{merged['completed_tokens']} tokens across {len(alive)} "
+          f"{merged['completed_tokens']} tokens across {len(builts)} "
           f"replicas in {wall:.2f}s "
           f"({merged['goodput_tokens'] / wall:.1f} goodput tok/s; "
           f"met={merged['slo_met']} missed={merged['slo_missed']})")
     print(f"fleet p50/p95/p99 latency ms: {merged['latency_p50_ms']} / "
           f"{merged['latency_p95_ms']} / {merged['latency_p99_ms']}")
-    for s in alive:
-        print(f"replica {s['replica']}: steps={s['steps']} "
-              f"time_to_settled_s={s['time_to_settled_s']} "
-              f"compile={json.dumps(s['compile'])}")
+    for i, b in enumerate(builts):
+        print(f"replica {i}: steps={b.engine.steps} "
+              f"compile={json.dumps(b.rt.compile_stats())}")
     if snap is not None:
         snap.close()
     _export_trace(args)
+    for b in builts:
+        b.engine.shutdown(state_dir=None)
 
 
 def main() -> None:
@@ -662,8 +718,8 @@ def main() -> None:
                          "weight per tenant); implies single-process mode "
                          "and defaults --scheduler to drr")
     ap.add_argument("--replicas", type=int, default=1,
-                    help="N > 1 turns this process into a router front "
-                         "over N subprocess engine replicas")
+                    help="N > 1 serves N in-process engine replicas, one "
+                         "per device, behind a router")
     ap.add_argument("--router", default="jsq",
                     choices=("round-robin", "jsq", "spill"),
                     help="fleet routing policy")
@@ -683,6 +739,7 @@ def main() -> None:
     ap.add_argument("--snapshot-interval-s", type=float, default=1.0,
                     help="telemetry snapshot period")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trace_out or args.telemetry_snapshot:
         telemetry.enable()
     if args.tenant:

@@ -26,6 +26,7 @@ from repro.checkpoint import CheckpointManager
 from repro.core import (ChangeDetector, Controller, CoordinateDescent,
                         DEFAULT_CONTEXT, IridescentRuntime)
 from repro.data import SyntheticLM
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models import ModelConfig
 from repro.models import transformer as model
 from repro.optim import OptConfig, init_opt_state
@@ -47,7 +48,11 @@ def small_lm(scale: str) -> ModelConfig:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
-                    help="assigned arch id (reduced config); default: small LM")
+                    help="assigned arch id (published config); default: "
+                         "small LM")
+    ap.add_argument("--reduced", action="store_true",
+                    help="with --arch: the reduced same-family preset in "
+                         "float32 (CPU runs)")
     ap.add_argument("--size", default="2m", choices=("2m", "25m", "100m"))
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--batch", type=int, default=8)
@@ -68,7 +73,8 @@ def main() -> None:
                          "exceeds BUDGET x the expected dwell time")
     args = ap.parse_args()
 
-    cfg = (configs.get_reduced(args.arch).replace(compute_dtype="float32")
+    enable_compile_cache()
+    cfg = (configs.select(args.arch, args.reduced)
            if args.arch else small_lm(args.size))
     opt_cfg = OptConfig(lr=args.lr, warmup_steps=10, total_steps=args.steps,
                         compress=args.compress)
@@ -82,7 +88,7 @@ def main() -> None:
                            max_compile_workers=args.compile_workers,
                            variant_cache=mgr.variant_cache() if mgr else None)
     handler = rt.register("train_step",
-                          make_train_builder(cfg, opt_cfg, kernel_impl="xla"),
+                          make_train_builder(cfg, opt_cfg),
                           donate_argnums=0)
 
     params = model.init_params(jax.random.PRNGKey(0), cfg)

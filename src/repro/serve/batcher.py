@@ -329,12 +329,14 @@ class BucketTuner:
                  change_detector=None,
                  initial_scheme: str | None = None,
                  wait_compiles: bool = False,
-                 plan_handler=None):
+                 plan_handler=None,
+                 device=None):
         from repro.core.controller import Controller
         from repro.core.metrics import ChangeDetector
         from repro.core.policy import ExhaustiveSweep
         from repro.core.runtime import DEFAULT_CONTEXT
 
+        import jax
         import jax.numpy as jnp
 
         self.batcher = batcher
@@ -367,7 +369,8 @@ class BucketTuner:
             wait_compiles=wait_compiles,
             prefetch=0,
             initial_configs=initial_configs)
-        self._tick = jnp.int32(0)
+        # the plan handler's dwell-clock input, on the replica's device
+        self._tick = jax.device_put(jnp.int32(0), device)
         batcher.bind_tuner(self)
 
     def active_scheme(self) -> str:

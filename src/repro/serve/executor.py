@@ -81,8 +81,6 @@ class PrefillExecutor:
         self.chunk = int(chunk)
 
     def execute(self, batch: PackedBatch) -> list[int]:
-        import jax.numpy as jnp
-
         o = self.owner
         reqs = batch.requests
         b, c = batch.size, self.chunk
@@ -99,8 +97,7 @@ class PrefillExecutor:
         rids = [req.rid for req in reqs]
         cache, lengths = o.kv.materialize(rids, b)
         logits, new_cache = o.handler(
-            o.params, cache, jnp.asarray(tokens),
-            jnp.asarray(lengths), jnp.asarray(n_new))
+            o.params, cache, o.put(tokens), o.put(lengths), o.put(n_new))
         o.kv.harvest(rids, new_cache, n_new[: len(reqs)])
         logits = np.asarray(logits)
         produced = []
@@ -109,7 +106,7 @@ class PrefillExecutor:
             if req.prefilling:
                 produced.append(0)
             else:
-                o.state[req.rid].out.append(o.sample(logits[i]))
+                o.take(req, logits[i])
                 produced.append(1)
         return produced
 
@@ -123,8 +120,6 @@ class DecodeExecutor:
         self.owner = owner
 
     def execute(self, batch: PackedBatch) -> list[int]:
-        import jax.numpy as jnp
-
         o = self.owner
         reqs = batch.requests
         b = batch.size
@@ -136,12 +131,11 @@ class DecodeExecutor:
         cache, lengths = o.kv.materialize(rids, b)
         ones = np.ones((b,), np.int32)
         logits, new_cache = o.handler(
-            o.params, cache, jnp.asarray(tokens),
-            jnp.asarray(lengths), jnp.asarray(ones))
+            o.params, cache, o.put(tokens), o.put(lengths), o.put(ones))
         o.kv.harvest(rids, new_cache, [1] * len(reqs))
         logits = np.asarray(logits)
         for i, req in enumerate(reqs):
-            o.state[req.rid].out.append(o.sample(logits[i]))
+            o.take(req, logits[i])
         return [1] * len(reqs)
 
 
@@ -158,6 +152,11 @@ class PhasedExecutor:
 
     On retire the request's pages return to the free list and its
     generated token ids are published as ``request.payload`` (a list).
+
+    Step inputs go to the KV manager's device.  For the rids present in
+    ``logits_log`` every logits row a token was sampled from is kept (the
+    row after prefill, then one per decode step), so a caller can check
+    what was served against a reference.
     """
 
     #: tells the engine to pack prefill and decode steps separately
@@ -179,6 +178,7 @@ class PhasedExecutor:
         self.prompt_fn = prompt_fn
         self.sample = sample
         self.state: dict[Any, _RowState] = {}
+        self.logits_log: dict[Any, list[np.ndarray]] = {}
         self.prefill = PrefillExecutor(self, prefill_chunk)
         self.decode = DecodeExecutor(self)
 
@@ -201,6 +201,18 @@ class PhasedExecutor:
             req.payload = row.out
         if req.rid in self.kv.live_requests():
             self.kv.retire(req.rid)
+
+    def put(self, host: np.ndarray):
+        """Upload one step input to the device the cache lives on."""
+        import jax
+        return jax.device_put(host, self.kv.device)
+
+    def take(self, req: Request, logits_row: np.ndarray) -> None:
+        """Sample ``req``'s next token from its logits row."""
+        log = self.logits_log.get(req.rid)
+        if log is not None:
+            log.append(np.array(logits_row, np.float32))
+        self.state[req.rid].out.append(self.sample(logits_row))
 
     # -- execution --------------------------------------------------------------
     def execute(self, batch: PackedBatch) -> list[int]:

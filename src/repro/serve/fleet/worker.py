@@ -1,9 +1,9 @@
 """Fleet replica worker: one ServeEngine behind a line-JSON stdio protocol.
 
-Run as a subprocess by the router front (``launch/serve.py --replicas N``
-or ``benchmarks/serve_bench.py --scenario fleet``)::
+Run as a subprocess by the router front of
+``benchmarks/serve_bench.py --scenario fleet``::
 
-    python -m repro.serve.fleet.worker --profile synthetic --replica-id 1
+    python -m repro.serve.fleet.worker --replica-id 1
 
 Protocol (newline-delimited JSON):
 
@@ -21,10 +21,11 @@ Protocol (newline-delimited JSON):
   snapshot (:meth:`~repro.serve.metrics.ServeMetrics.state` — mergeable
   by the front), compile stats, and time-to-settled.
 
-Two profiles: ``synthetic`` (the benchmark's fused-vs-split matmul
-handler — cheap, CPU-friendly, deterministic winner) and ``lm`` (the
-full LM serving stack of :mod:`repro.launch.serve`: phase-disaggregated
-execution over paged KV, bucket and KV-geometry tuners).
+The worker serves the benchmark's synthetic fused-vs-split matmul
+handler (cheap, CPU-friendly, deterministic winner).  The LM stack runs
+its replicas in one process instead (``launch/serve.py --replicas N``):
+a chip belongs to one process, so subprocess workers could not share
+one host's chips.
 
 With ``--plane-dir`` the worker participates in the shared
 specialization plane: it polls before serving (warm start — remotely
@@ -235,24 +236,13 @@ def _synthetic_stack(args):
     return rt, engine, [("fleet_step", controller)]
 
 
-def _lm_stack(args):
-    """The full LM serving stack, shared with ``launch/serve.py``."""
-    from repro.launch.serve import build_engine
-    built = build_engine(args)
-    return built.rt, built.engine, [("serve_step", built.controller)]
-
-
 def _emit(msg: dict) -> None:
     sys.stdout.write(json.dumps(msg) + "\n")
     sys.stdout.flush()
 
 
 def main(argv=None) -> None:
-    pre = argparse.ArgumentParser(add_help=False)
-    pre.add_argument("--profile", default="synthetic",
-                     choices=("synthetic", "lm"))
-    ns, _ = pre.parse_known_args(argv)
-    ap = argparse.ArgumentParser(description=__doc__, parents=[pre])
+    ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--replica-id", default="0")
     ap.add_argument("--plane-dir", default=None,
                     help="shared SpecPlane directory (publish + subscribe)")
@@ -265,17 +255,11 @@ def main(argv=None) -> None:
     ap.add_argument("--telemetry", action="store_true",
                     help="enable the flight-recorder bus and forward its "
                          "events to the front over stdout")
-    if ns.profile == "lm":
-        # the launch driver's flag set (--arch, --batch, --dwell,
-        # --cache-dir, --slo-ms, ... — shared via add_engine_args)
-        from repro.launch.serve import add_engine_args
-        add_engine_args(ap)
-    else:
-        ap.add_argument("--d", type=int, default=256)
-        ap.add_argument("--max-batch", type=int, default=8)
-        ap.add_argument("--cache-dir", default=None)
-        ap.add_argument("--dwell", type=int, default=6)
-        ap.add_argument("--slo-ms", type=float, default=5000.0)
+    ap.add_argument("--d", type=int, default=256)
+    ap.add_argument("--max-batch", type=int, default=8)
+    ap.add_argument("--cache-dir", default=None)
+    ap.add_argument("--dwell", type=int, default=6)
+    ap.add_argument("--slo-ms", type=float, default=5000.0)
     args = ap.parse_args(argv)
 
     from repro.serve import Request
@@ -303,9 +287,7 @@ def main(argv=None) -> None:
             _emit({"type": "events", "replica": args.replica_id,
                    "events": batch})
 
-    rt, engine, publishable = (_synthetic_stack(args)
-                               if args.profile == "synthetic"
-                               else _lm_stack(args))
+    rt, engine, publishable = _synthetic_stack(args)
     # Share the controller's quarantine registry with the plane so local
     # rollbacks propagate fleet-wide and remote ones are absorbed here.
     quarantine = next((ctl.quarantine for _, ctl in publishable
@@ -393,6 +375,10 @@ def main(argv=None) -> None:
             plane.publish_controller(name, ctl)
 
     flush_events()                        # final batch before stats
+    # Builds still in flight land before the compile stats are read: a
+    # worker that finishes its traffic before its background compiles
+    # would otherwise report them by chance or not at all.
+    rt.compile_service.drain(timeout=60.0)
     stats = engine.stats()
     settled = {name: {str(k): {kk: repr(vv) for kk, vv in cfg.items()}
                       for k, (cfg, _) in ctl.settled_winners().items()}

@@ -158,13 +158,16 @@ class PagedKV:
 
     ``capacity_tokens`` bounds each geometry's pool.  ``geometry`` fixes
     the layout; attach a :class:`KVTuner` to tune it online instead.
+    ``device`` is where each step's dense cache is uploaded (``None``: the
+    default device).
     """
 
     def __init__(self, template: Any, axes: Any, *, max_len: int,
                  capacity_tokens: int, page_size: int = 16,
-                 layout: str = "paged"):
+                 layout: str = "paged", device: Any = None):
         import jax
 
+        self.device = device
         if max_len <= 0:
             raise ValueError(f"max_len must be positive, got {max_len}")
         if capacity_tokens < max_len:
@@ -332,7 +335,7 @@ class PagedKV:
         token count — the executor passes it as the per-row write
         position vector.
         """
-        import jax.numpy as jnp
+        import jax
 
         if len(rids) > batch:
             raise ValueError(f"{len(rids)} requests do not fit in "
@@ -341,7 +344,8 @@ class PagedKV:
         out_leaves = []
         for i, spec in enumerate(self._leaves):
             if spec.kind == _SHARED:
-                out_leaves.append(jnp.asarray(spec.template_value.copy()))
+                out_leaves.append(jax.device_put(spec.template_value.copy(),
+                                                 self.device))
                 continue
             shape = list(spec.shape)
             shape[spec.bat_i] = batch
@@ -361,8 +365,7 @@ class PagedKV:
                         if n <= 0:
                             break
                         view[r, a:a + n] = pool_arr[pid, :n]
-            out_leaves.append(jnp.asarray(staging))
-        import jax
+            out_leaves.append(jax.device_put(staging, self.device))
         cache = jax.tree_util.tree_unflatten(self._treedef, out_leaves)
         lengths = np.array([t.length for t in tables]
                            + [0] * (batch - len(tables)), np.int32)
@@ -502,12 +505,14 @@ class KVTuner:
                  change_detector=None,
                  initial_plan: "tuple[str, int] | None" = None,
                  wait_compiles: bool = False,
-                 plan_handler=None):
+                 plan_handler=None,
+                 device=None):
         from repro.core.controller import Controller
         from repro.core.metrics import ChangeDetector
         from repro.core.policy import ExhaustiveSweep
         from repro.core.runtime import DEFAULT_CONTEXT
 
+        import jax
         import jax.numpy as jnp
 
         self.kv = kv
@@ -552,7 +557,8 @@ class KVTuner:
             wait_compiles=wait_compiles,
             prefetch=0,
             initial_configs=initial_configs)
-        self._tick = jnp.int32(0)
+        # the plan handler's dwell-clock input, on the replica's device
+        self._tick = jax.device_put(jnp.int32(0), device)
         kv.bind_tuner(self)
 
     def active_plan(self) -> tuple[str, int]:

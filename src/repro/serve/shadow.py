@@ -11,10 +11,18 @@ in-SLO judgment is self-calibrating (host speed, batch shape, and data
 distribution cancel out) and the candidate accumulates its K observations
 without ever serving a user request.
 
-Captured arguments are cloned at capture time and again before every
-shadow call: a handler with ``donate_argnums`` (the LM serve step donates
-its KV cache) would otherwise consume the live path's buffers — or have
-its own sample consumed by the first shadow execution.
+The arguments the handler donates (``donate_argnums`` — the LM serve
+step donates its KV cache) are cloned at capture time and again before
+every shadow call: the live path would otherwise consume the sample's
+buffers, or the first shadow execution would.  Every other argument — the
+weights above all — is held by reference: it is never donated or written,
+and a copy per sample would hold another copy of the model in device
+memory.
+
+Pairs are timed on the host clock, so a build tracing or compiling on
+another thread (the GIL, the host's cores) would land in whichever call
+it overlaps: the evaluator measures nothing while the runtime's compile
+service has a build in flight.
 """
 from __future__ import annotations
 
@@ -39,6 +47,11 @@ def _clone(tree):
     alias a buffer another execution still owns."""
     return jax.tree_util.tree_map(
         lambda x: jnp.array(x) if isinstance(x, jax.Array) else x, tree)
+
+
+def _donated_argnums(handler) -> frozenset:
+    nums = handler.jit_kwargs.get("donate_argnums", ())
+    return frozenset((nums,) if isinstance(nums, int) else nums)
 
 
 class _ShadowCtx:
@@ -88,6 +101,7 @@ class ShadowEvaluator:
         self.max_attempts = max(self.k, int(max_attempts))
         self.clock = clock
         self._ctx: dict[Any, _ShadowCtx] = {}
+        self._donated = _donated_argnums(handler)
         self.calls = 0                    # shadow executions (pairs are 2)
         self.dropped_samples = 0
         handler.set_shadow_tap(self._tap)
@@ -111,7 +125,11 @@ class ShadowEvaluator:
         st.tick += 1
         if tick % self.sample_period:
             return
-        st.samples.append((_clone(args), _clone(dict(kwargs))))
+        st.samples.append((self._clone_donated(args), dict(kwargs)))
+
+    def _clone_donated(self, args: tuple) -> tuple:
+        return tuple(_clone(a) if i in self._donated else a
+                     for i, a in enumerate(args))
 
     # -- candidate lifecycle ------------------------------------------------------
     def begin(self, key: Any, candidate: dict, incumbent: dict) -> None:
@@ -148,6 +166,8 @@ class ShadowEvaluator:
     def step(self, budget: int | None = None) -> int:
         """Run up to ``budget`` mirrored call pairs across pending
         contexts (round-robin); returns the number of pairs executed."""
+        if self.handler.runtime.compile_service.busy():
+            return 0
         budget = self.budget_per_tick if budget is None else int(budget)
         executed = 0
         keys = self.pending()
@@ -177,13 +197,15 @@ class ShadowEvaluator:
         st.attempts += 1
         args, kwargs = sample
         try:
+            own = jax.block_until_ready(self._clone_donated(args))
             t0 = self.clock()
-            out = view.shadow_call(st.candidate, _clone(args), _clone(kwargs))
+            out = view.shadow_call(st.candidate, own, kwargs)
             jax.block_until_ready(out)
             st.cand_times.append(self.clock() - t0)
             del out
+            own = jax.block_until_ready(self._clone_donated(args))
             t0 = self.clock()
-            out = view.shadow_call(st.incumbent, _clone(args), _clone(kwargs))
+            out = view.shadow_call(st.incumbent, own, kwargs)
             jax.block_until_ready(out)
             st.inc_times.append(self.clock() - t0)
             del out

@@ -49,6 +49,20 @@ def test_priority_activation_before_speculative():
         svc.shutdown()
 
 
+def test_busy_while_a_build_is_pending_or_running():
+    svc = CompileService(workers=1)
+    b = _Blocker()
+    try:
+        assert not svc.busy()
+        svc.submit("h", "k0", {}, b.build("k0", block=True))
+        assert svc.busy()
+        b.gate.set()
+        assert svc.drain(timeout=30)
+        assert not svc.busy()
+    finally:
+        svc.shutdown()
+
+
 def test_dedup_coalesces_inflight_requests():
     svc = CompileService(workers=1)
     b = _Blocker()

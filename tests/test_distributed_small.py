@@ -26,7 +26,8 @@ from repro.launch import dryrun as dr
 from repro.optim import OptConfig
 from repro.configs import Shape
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 out = {}
 
 # 1) miniature dry-run: one reduced arch per family, train + decode

@@ -38,6 +38,45 @@ def test_cpu_availability_filtering():
     assert registry.get("matmul", "pallas_tpu").is_available() is False
 
 
+def test_interpreter_is_never_a_candidate_off_the_cpu(monkeypatch):
+    # on a chip the interpreter would be explored (and could win) in place
+    # of the kernel it emulates
+    assert "pallas_interpret" in registry.choices("rmsnorm")
+    monkeypatch.setattr(compat, "on_cpu", lambda: False)
+    for family in FAMILIES:
+        assert "pallas_interpret" not in registry.choices(family), family
+
+
+def test_raising_availability_probe_is_an_error():
+    reg = KernelRegistry()
+
+    @reg.register("toy", "xla_ref")
+    def _ref(x):
+        return x
+
+    def probe():
+        raise ImportError("platform module failed to import")
+
+    @reg.register("toy", "fancy", priority=10, available=probe)
+    def _fancy(x):
+        return x
+
+    # not an absence: resolving must not quietly run xla_ref instead
+    with pytest.raises(ImportError):
+        reg.resolve("toy", None)
+    with pytest.raises(ImportError):
+        reg.resolve("toy", "fancy")
+    assert not reg.fallback_counts
+
+
+def test_pallas_tpu_import_failure_raises_only_on_tpu(monkeypatch):
+    monkeypatch.setattr(compat, "pallas_tpu", None)
+    assert compat.has_pallas_tpu() is False         # a CPU host: absent
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="failed to import"):
+        compat.has_pallas_tpu()
+
+
 def test_auto_resolution_prefers_xla_ref_on_cpu():
     # xla_ref (priority 0) outranks pallas_interpret (negative priority)
     for family in FAMILIES:
@@ -247,13 +286,15 @@ def test_require_grad_pins_concrete_grad_safe_impl():
 
 def test_compat_surface():
     # the shim must resolve on this host: shard_map callable, tree utils,
-    # and the TPU compiler-params builder either None or constructible.
+    # and the TPU compiler-params builder passing its fields through — an
+    # unknown field raises instead of being dropped.
     assert callable(compat.shard_map)
     assert compat.tree_map(lambda a: a + 1, {"x": 1}) == {"x": 2}
-    params = compat.tpu_compiler_params(
-        dimension_semantics=("parallel",), not_a_real_field=1)
+    params = compat.tpu_compiler_params(dimension_semantics=("parallel",))
     if compat.has_pallas_tpu():
-        assert params is not None
+        assert params.dimension_semantics == ("parallel",)
+        with pytest.raises(TypeError):
+            compat.tpu_compiler_params(not_a_real_field=1)
     assert compat.backend() == "cpu"
 
 

@@ -97,7 +97,8 @@ def test_shadow_tap_sees_live_calls():
 # --- ShadowEvaluator ------------------------------------------------------------
 
 def _iters_builder(spec):
-    # mode "slow" does 200x the work of "fast": a timing gap no shared CI
+    # mode "slow" does 200x the work of "fast": at 128x128 that is
+    # milliseconds against tens of microseconds, a timing gap no shared CI
     # host can invert, so the in_slo verdicts below are deterministic.
     iters = spec.enum("iters", 1, (1, 200), guarded=False)
 
@@ -114,7 +115,7 @@ def test_shadow_evaluator_passes_faster_candidate():
     rt = make_rt()
     h = rt.register("m", _iters_builder)
     ev = ShadowEvaluator(h, sample_frac=1.0, k=3, tolerance=1.5)
-    x = jnp.eye(32)
+    x = jnp.eye(128)
     h(x)
     view = h.context(DEFAULT_CONTEXT)
     view.specialize({"iters": 200}, wait=True)   # slow incumbent
@@ -137,7 +138,7 @@ def test_shadow_evaluator_rejects_slow_candidate():
     rt = make_rt()
     h = rt.register("m", _iters_builder)
     ev = ShadowEvaluator(h, sample_frac=1.0, k=3, tolerance=1.5)
-    x = jnp.eye(32)
+    x = jnp.eye(128)
     h(x)
     view = h.context(DEFAULT_CONTEXT)
     ev.begin(DEFAULT_CONTEXT, {"iters": 200}, view.active_config())
@@ -149,6 +150,57 @@ def test_shadow_evaluator_rejects_slow_candidate():
     v = ev.verdict(DEFAULT_CONTEXT)
     assert v["measured"] and not v["in_slo"]
     assert v["candidate_s"] > v["incumbent_s"]
+    ev.close()
+    rt.shutdown()
+
+
+def test_shadow_evaluator_holds_pairs_while_busy():
+    # a build in flight would time into whichever call it overlaps
+    rt = make_rt()
+    h = rt.register("m", _mode_builder)
+    busy = [True]
+    rt.compile_service.busy = lambda: busy[0]
+    ev = ShadowEvaluator(h, sample_frac=1.0, k=2)
+    h(jnp.ones(4))
+    view = h.context(DEFAULT_CONTEXT)
+    ev.begin(DEFAULT_CONTEXT, {"mode": "b"}, view.active_config())
+    view.build({"mode": "b"}, wait=True)
+    assert ev.step(budget=4) == 0
+    assert ev._st(DEFAULT_CONTEXT).attempts == 0
+    busy[0] = False
+    assert ev.step(budget=4) == 2
+    assert ev.verdict(DEFAULT_CONTEXT)["measured"]
+    ev.close()
+    rt.shutdown()
+
+
+def _donating_builder(spec):
+    scale = spec.enum("scale", 1.0, (1.0, 2.0), guarded=False)
+
+    def f(w, cache):
+        return cache + scale * w
+
+    return f
+
+
+def test_shadow_evaluator_clones_only_donated_args():
+    # the weights are held by reference (one copy of the model in device
+    # memory), the donated cache is cloned so no execution consumes it
+    rt = make_rt()
+    h = rt.register("m", _donating_builder, donate_argnums=1)
+    ev = ShadowEvaluator(h, sample_frac=1.0, k=2)
+    w = jnp.ones(4)
+    h(w, jnp.zeros(4))
+    (args, _), = ev._st(DEFAULT_CONTEXT).samples
+    assert args[0] is w
+    assert not args[1].is_deleted()          # the live call donated its own
+    view = h.context(DEFAULT_CONTEXT)
+    ev.begin(DEFAULT_CONTEXT, {"scale": 2.0}, view.active_config())
+    view.build({"scale": 2.0}, wait=True)
+    while ev.verdict(DEFAULT_CONTEXT) is None:
+        assert ev.step(budget=4) > 0
+    assert ev.verdict(DEFAULT_CONTEXT)["measured"]
+    assert ev.dropped_samples == 0 and not args[1].is_deleted()
     ev.close()
     rt.shutdown()
 

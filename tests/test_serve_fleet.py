@@ -391,8 +391,8 @@ def test_subprocess_worker_round_trip(tmp_path):
     from repro.serve.fleet.worker import SubprocessReplica, worker_command
 
     rep = SubprocessReplica(
-        worker_command("--profile", "synthetic", "--replica-id", "w",
-                       "--d", "64", "--dwell", "2", "--max-wall-s", "60"),
+        worker_command("--replica-id", "w", "--d", "64", "--dwell", "2",
+                       "--max-wall-s", "60"),
         name="w")
     try:
         assert rep.wait_ready(300.0)

@@ -84,7 +84,7 @@ def test_pool_free_list_reuse_is_lifo():
     assert pool.alloc() == a
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(st.lists(st.tuples(st.integers(0, 5), st.integers(0, 2)),
                 min_size=1, max_size=40))
 def test_no_page_shared_between_live_requests(ops):
