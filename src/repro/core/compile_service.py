@@ -362,48 +362,36 @@ class CompileService:
                                   if p50_compile is not None else None)}
 
     # -- internals ---------------------------------------------------------------
-    def _emit_build(self, req: CompileRequest, span_ts: float) -> None:
-        _tb = telemetry.bus()
-        if _tb is None:
-            return
-        rec = req.record()
-        done_t = req.done_t if req.done_t is not None else time.perf_counter()
-        _tb.emit("compile.build", "span", ts=span_ts,
-                 dur=(done_t - req.started_t) * 1e6,
-                 handler=req.handler, config=repr(req.config),
-                 status=req.status, cache_hit=req.cache_hit,
-                 speculative=req.speculative,
-                 wait_s=round(rec["wait_s"], 6),
-                 compile_s=req.compile_time_s, build_s=req.build_time_s)
-
     def _run(self, req: CompileRequest) -> None:
         req.started_t = time.perf_counter()
         req.status = "running"
-        span_ts = telemetry.perf_to_us(req.started_t)
-        try:
-            result = req.build()
-            req.status = "done"
-        except BaseException as e:
-            req.status = "failed"
+        result = error = None
+        with telemetry.span("compile.build", handler=req.handler,
+                            config=repr(req.config)) as p:
+            try:
+                result = req.build()
+                req.status = "done"
+                # Builds annotate their Variant with timing/cache info.
+                req.build_time_s = getattr(result, "build_time_s", None)
+                req.compile_time_s = getattr(result, "compile_time_s", None)
+                req.cache_hit = bool(getattr(result, "from_cache", False))
+            except BaseException as e:
+                req.status = "failed"
+                error = e
             req.done_t = time.perf_counter()
             with self._cv:
                 self._inflight.pop((req.handler, req.key), None)
                 self._history.append(req.record())
                 self._cv.notify_all()
-            self._emit_build(req, span_ts)
-            req.future.set_exception(e)
-            return
-        req.done_t = time.perf_counter()
-        # Builds annotate their Variant with timing/cache info; fold it in.
-        req.build_time_s = getattr(result, "build_time_s", None)
-        req.compile_time_s = getattr(result, "compile_time_s", None)
-        req.cache_hit = bool(getattr(result, "from_cache", False))
-        with self._cv:
-            self._inflight.pop((req.handler, req.key), None)
-            self._history.append(req.record())
-            self._cv.notify_all()
-        self._emit_build(req, span_ts)
-        req.future.set_result(result)
+            p.update(status=req.status, cache_hit=req.cache_hit,
+                     speculative=req.speculative,
+                     wait_s=round(req.started_t - req.enqueued_t, 6),
+                     compile_s=req.compile_time_s, build_s=req.build_time_s)
+        # resolved once the span has closed, so a waiter finds its event
+        if error is not None:
+            req.future.set_exception(error)
+        else:
+            req.future.set_result(result)
 
     def _worker(self) -> None:
         while True:

@@ -24,6 +24,18 @@ Enabled, the bus is lock-free on emit: a slot index is claimed with an
 atomic under the GIL) and the event dict is stored by reference.  Readers
 take a racy-but-consistent snapshot — fine for a flight recorder.
 
+Spans
+-----
+:func:`span` is the program's one way to open a span.  It always enters a
+``jax.profiler.TraceAnnotation`` named ``iri.<name>``, so the span lands in
+a profiler trace on the same timeline as the device planes (wrap a serve
+run in ``jax.profiler.trace(dir)`` to see both).  It also keeps
+``(name, start_s, end_s, args)`` in a bounded in-process ring on the
+``time.perf_counter`` clock (:func:`recent_spans`, for in-process
+readers), and, when the bus is on, emits the bus's ``kind="span"`` event
+under the same name.
+:meth:`EventBus.span` is the same span bound to one bus.
+
 Event shape
 -----------
 Each event is a plain dict::
@@ -38,19 +50,21 @@ Each event is a plain dict::
 """
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
 import time
-from contextlib import contextmanager
 from typing import Any, Callable, Iterable
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import AtomicCounter
 
 __all__ = [
-    "EventBus", "bus", "install", "enable", "disable",
-    "export_chrome_trace", "SnapshotWriter", "write_atomic_json",
-    "ctx_str", "perf_to_us", "now_us",
+    "EventBus", "bus", "install", "enable", "disable", "span",
+    "recent_spans", "SPAN_RING_SIZE", "export_chrome_trace", "SnapshotWriter",
+    "write_atomic_json", "ctx_str", "perf_to_us", "now_us",
 ]
 
 _EPOCH = time.perf_counter()
@@ -129,19 +143,10 @@ class EventBus:
             n += 1
         return n
 
-    @contextmanager
-    def span(self, name: str, *, track: Any = None, **payload):
-        """Measure a span; emits one ``kind="span"`` event on exit.
-
-        Yields the payload dict — mutate it inside the block to attach
-        results (e.g. ``p["status"] = "done"``)."""
-        t0 = time.perf_counter()
-        ts = _now_us()
-        try:
-            yield payload
-        finally:
-            dur = (time.perf_counter() - t0) * 1e6
-            self.emit(name, "span", track=track, dur=dur, ts=ts, **payload)
+    def span(self, name: str, *, track: Any = None, **payload) -> "_Span":
+        """:func:`span` bound to this bus: emits one ``kind="span"`` event
+        here on exit, whether or not this is the process bus."""
+        return _Span(self, name, track, payload)
 
     # -- read -------------------------------------------------------------
     def emitted(self) -> int:
@@ -219,6 +224,60 @@ def enable(capacity: int = 65536) -> EventBus:
 
 def disable() -> None:
     install(None)
+
+
+# -- spans ----------------------------------------------------------------
+#: how many spans the ring keeps (thousands of serve steps)
+SPAN_RING_SIZE = 1 << 16
+#: the most recent spans, in the order they ended: ``(name, start_s,
+#: end_s, args)`` on the ``time.perf_counter`` clock (``args`` is None
+#: when none were given)
+_SPANS: collections.deque = collections.deque(maxlen=SPAN_RING_SIZE)
+
+
+class _Span:
+    """One span: a profiler annotation, a ring entry, and a bus event."""
+
+    __slots__ = ("bus", "name", "track", "args", "_ann", "_t0")
+
+    def __init__(self, bus_: EventBus | None, name: str, track: Any,
+                 args: dict):
+        self.bus = bus_
+        self.name = name
+        self.track = track
+        self.args = args
+
+    def __enter__(self) -> dict:
+        self._ann = TraceAnnotation("iri." + self.name, **self.args)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self.args
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        _SPANS.append((self.name, self._t0, t1, self.args or None))
+        if self.bus is not None:
+            self.bus.emit(self.name, "span", track=self.track,
+                          ts=perf_to_us(self._t0),
+                          dur=(t1 - self._t0) * 1e6, **self.args)
+
+
+def span(name: str, track: Any = None, **args) -> _Span:
+    """Open a span: ``with telemetry.span("kv.upload", bytes=n): ...``.
+
+    The profiler annotation ``iri.<name>`` carries ``args`` as given at
+    entry.  The block receives ``args`` itself: what it adds there reaches
+    the ring entry and the bus event (e.g. ``p["status"] = "done"``).
+    ``track`` names the bus event's trace track."""
+    return _Span(_bus, name, track, args)
+
+
+def recent_spans() -> list[tuple]:
+    """The retained spans of every thread, in the order they ended, as
+    ``(name, start_s, end_s, args)``; once ``SPAN_RING_SIZE`` are kept,
+    each new span drops the one that ended first."""
+    return list(_SPANS)
 
 
 # -- Chrome-trace exporter ------------------------------------------------

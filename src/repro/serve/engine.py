@@ -162,10 +162,18 @@ class ServeEngine:
                 # pairs run off the hot path under a bounded per-tick
                 # budget, then the controller collects any verdicts (a
                 # shadow-stage context advances without live traffic).
-                self.shadow_pairs += self.shadow.step()
-                if self.controller is not None:
-                    self.controller.step()
+                with telemetry.span("serve.control"):
+                    self.shadow_pairs += self.shadow.step()
+                    if self.controller is not None:
+                        self.controller.step()
             return 0
+        with telemetry.span("serve.step", step=self.steps, phase=batch.phase,
+                            bucket=batch.size, rows=len(batch.requests)):
+            return self._run_batch(batch, now)
+
+    def _run_batch(self, batch: PackedBatch, now: float) -> int:
+        """Execute one packed batch, retire what finished, advance the
+        controllers; returns tokens produced."""
         _tb = telemetry.bus()
         if _tb is not None:
             prev = {id(r) for r in self.active}
@@ -226,12 +234,13 @@ class ServeEngine:
         if batch.tenant is not None:
             self.tenant_steps[batch.tenant] = \
                 self.tenant_steps.get(batch.tenant, 0) + 1
-        if self.controller is not None:
-            self.controller.step()
-        if self.tuner is not None:
-            self.tuner.step()
-        if self.kv_tuner is not None:
-            self.kv_tuner.step()
+        with telemetry.span("serve.control"):
+            if self.controller is not None:
+                self.controller.step()
+            if self.tuner is not None:
+                self.tuner.step()
+            if self.kv_tuner is not None:
+                self.kv_tuner.step()
         return tokens
 
     def _retire(self, req: Request, now: float) -> None:

@@ -29,6 +29,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.serve.batcher import PackedBatch
 from repro.serve.kv import PagedKV
 from repro.serve.request import Request
@@ -96,18 +97,20 @@ class PrefillExecutor:
             n_new[i] = n
         rids = [req.rid for req in reqs]
         cache, lengths = o.kv.materialize(rids, b)
-        logits, new_cache = o.handler(
-            o.params, cache, o.put(tokens), o.put(lengths), o.put(n_new))
+        with telemetry.span("serve.dispatch"):
+            logits, new_cache = o.handler(
+                o.params, cache, o.put(tokens), o.put(lengths), o.put(n_new))
         o.kv.harvest(rids, new_cache, n_new[: len(reqs)])
-        logits = np.asarray(logits)
-        produced = []
-        for i, req in enumerate(reqs):
-            req.prompt_consumed += int(n_new[i])
-            if req.prefilling:
-                produced.append(0)
-            else:
-                o.take(req, logits[i])
-                produced.append(1)
+        with telemetry.span("serve.sample"):
+            logits = np.asarray(logits)
+            produced = []
+            for i, req in enumerate(reqs):
+                req.prompt_consumed += int(n_new[i])
+                if req.prefilling:
+                    produced.append(0)
+                else:
+                    o.take(req, logits[i])
+                    produced.append(1)
         return produced
 
 
@@ -130,12 +133,14 @@ class DecodeExecutor:
         rids = [req.rid for req in reqs]
         cache, lengths = o.kv.materialize(rids, b)
         ones = np.ones((b,), np.int32)
-        logits, new_cache = o.handler(
-            o.params, cache, o.put(tokens), o.put(lengths), o.put(ones))
+        with telemetry.span("serve.dispatch"):
+            logits, new_cache = o.handler(
+                o.params, cache, o.put(tokens), o.put(lengths), o.put(ones))
         o.kv.harvest(rids, new_cache, [1] * len(reqs))
-        logits = np.asarray(logits)
-        for i, req in enumerate(reqs):
-            o.take(req, logits[i])
+        with telemetry.span("serve.sample"):
+            logits = np.asarray(logits)
+            for i, req in enumerate(reqs):
+                o.take(req, logits[i])
         return [1] * len(reqs)
 
 
@@ -216,9 +221,10 @@ class PhasedExecutor:
 
     # -- execution --------------------------------------------------------------
     def execute(self, batch: PackedBatch) -> list[int]:
-        if batch.phase == "prefill":
-            return self.prefill.execute(batch)
-        return self.decode.execute(batch)
+        with telemetry.span("serve.exec." + batch.phase):
+            if batch.phase == "prefill":
+                return self.prefill.execute(batch)
+            return self.decode.execute(batch)
 
     def stats(self) -> dict:
         return self.kv.stats()

@@ -43,6 +43,8 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from repro.core import telemetry
+
 logger = logging.getLogger("repro.serve.kv")
 
 __all__ = ["PageError", "PagePool", "PageTable", "PagedKV",
@@ -344,28 +346,28 @@ class PagedKV:
         out_leaves = []
         for i, spec in enumerate(self._leaves):
             if spec.kind == _SHARED:
-                out_leaves.append(jax.device_put(spec.template_value.copy(),
-                                                 self.device))
+                out_leaves.append(self._upload(spec.template_value.copy()))
                 continue
             shape = list(spec.shape)
             shape[spec.bat_i] = batch
-            staging = np.zeros(tuple(shape), spec.dtype)
-            view = _moved(staging, spec.bat_i, spec.seq_i)
-            if spec.kind == _ROW:
-                view[:] = spec.template_row
-                for r, table in enumerate(tables):
-                    view[r] = table.row_state[self._row_idx.index(i)]
-            else:
-                for r, table in enumerate(tables):
-                    pool_arr = self._geo_pools(table.geometry)[1][i]
-                    ps = table.page_size
-                    for j, pid in enumerate(table.pages):
-                        a = j * ps
-                        n = min(ps, table.length - a)
-                        if n <= 0:
-                            break
-                        view[r, a:a + n] = pool_arr[pid, :n]
-            out_leaves.append(jax.device_put(staging, self.device))
+            with telemetry.span("kv.gather"):
+                staging = np.zeros(tuple(shape), spec.dtype)
+                view = _moved(staging, spec.bat_i, spec.seq_i)
+                if spec.kind == _ROW:
+                    view[:] = spec.template_row
+                    for r, table in enumerate(tables):
+                        view[r] = table.row_state[self._row_idx.index(i)]
+                else:
+                    for r, table in enumerate(tables):
+                        pool_arr = self._geo_pools(table.geometry)[1][i]
+                        ps = table.page_size
+                        for j, pid in enumerate(table.pages):
+                            a = j * ps
+                            n = min(ps, table.length - a)
+                            if n <= 0:
+                                break
+                            view[r, a:a + n] = pool_arr[pid, :n]
+            out_leaves.append(self._upload(staging))
         cache = jax.tree_util.tree_unflatten(self._treedef, out_leaves)
         lengths = np.array([t.length for t in tables]
                            + [0] * (batch - len(tables)), np.int32)
@@ -406,31 +408,41 @@ class PagedKV:
                 raise PageError(
                     f"geometry {geo} needs {need} pages but only "
                     f"{pool.free_pages} free")
-        # host copies of the written spans (device -> host, per row)
-        for r, (table, n) in enumerate(zip(tables, n_new)):
-            n = int(n)
-            # row state is O(1)-sized: refresh it every step regardless
-            for k, i in enumerate(self._row_idx):
-                spec = self._leaves[i]
-                moved = _host_moved(new_leaves[i], spec.bat_i, None)
-                table.row_state[k] = np.asarray(moved[r]).copy()
-            if n == 0:
-                continue
-            pool, pools = self._geo_pools(table.geometry)
-            ps = table.page_size
-            start = table.length
-            while len(table.pages) * ps < start + n:
-                table.pages.append(pool.alloc())
-            for i in self._paged_idx:
-                spec = self._leaves[i]
-                moved = _host_moved(new_leaves[i], spec.bat_i, spec.seq_i)
-                span = np.asarray(moved[r, start:start + n])
-                for off in range(0, n, ps):
-                    slot = start + off
-                    j, a = divmod(slot, ps)
-                    m = min(ps - a, n - off)
-                    pools[i][table.pages[j], a:a + m] = span[off:off + m]
-            table.length = start + n
+        # the step program (and this step's uploads) must finish first
+        with telemetry.span("kv.wait"):
+            jax.block_until_ready(new_cache)
+        # whole leaves to the host: row state always, paged leaves when a
+        # row wrote tokens
+        wanted = self._row_idx + (self._paged_idx
+                                  if any(int(n) for n in n_new) else [])
+        host: dict[int, np.ndarray] = {}
+        for i in wanted:
+            spec = self._leaves[i]
+            with telemetry.span("kv.download", bytes=new_leaves[i].nbytes):
+                arr = np.asarray(new_leaves[i])
+            host[i] = _moved(arr, spec.bat_i, spec.seq_i)
+        # copy each row's written slots and row state into its pages
+        with telemetry.span("kv.scatter"):
+            for r, (table, n) in enumerate(zip(tables, n_new)):
+                n = int(n)
+                # row state is O(1)-sized: refresh it every step regardless
+                for k, i in enumerate(self._row_idx):
+                    table.row_state[k] = host[i][r].copy()
+                if n == 0:
+                    continue
+                pool, pools = self._geo_pools(table.geometry)
+                ps = table.page_size
+                start = table.length
+                while len(table.pages) * ps < start + n:
+                    table.pages.append(pool.alloc())
+                for i in self._paged_idx:
+                    span = host[i][r, start:start + n]
+                    for off in range(0, n, ps):
+                        slot = start + off
+                        j, a = divmod(slot, ps)
+                        m = min(ps - a, n - off)
+                        pools[i][table.pages[j], a:a + m] = span[off:off + m]
+                table.length = start + n
 
     # -- reporting --------------------------------------------------------------
     def stats(self) -> dict:
@@ -450,10 +462,15 @@ class PagedKV:
             "pools": geos,
         }
 
+    def _upload(self, host: np.ndarray):
+        """One leaf to the step's device.  ``device_put`` returns before
+        the copy lands: the rest of it (a layout transpose on the
+        runtime's worker threads, then the transfer) runs beside the host
+        work that follows, and ``harvest``'s wait covers what is left."""
+        import jax
 
-def _host_moved(leaf, bat_i: int, seq_i: int | None):
-    """Moved-layout view of a (possibly device) leaf, on host."""
-    return _moved(np.asarray(leaf), bat_i, seq_i)
+        with telemetry.span("kv.upload", bytes=host.nbytes):
+            return jax.device_put(host, self.device)
 
 
 # -- geometry as a specialization point -----------------------------------------
