@@ -11,7 +11,7 @@ scheduler (``--scheduler fcfs|sjf|deadline``), and are packed each
 iteration into bucketed batch shapes by the continuous batcher.
 
 Execution is **phase-disaggregated** over a **paged per-request KV
-runtime**: every request's decode state lives in block-paged host pools
+runtime**: every request's decode state lives in block-paged device pools
 (:class:`~repro.serve.kv.PagedKV` — fixed-size pages, per-request page
 tables, free-list reuse on retire), and each engine step runs either a
 chunked-prefill or a decode batch through one registered serve handler

@@ -4,12 +4,19 @@ The continuous-batching engine joins and retires requests mid-stream, but
 model decode caches are dense ``(batch, ..., seq, ...)`` arrays compiled
 for a bucket shape.  :class:`PagedKV` bridges the two, vLLM-style: every
 request owns an isolated logical KV sequence stored as fixed-size **pages**
-in host-side pools, mapped through a per-request :class:`PageTable`.  Each
-engine step the executor *materializes* the batch's rows into a dense
-device cache (padded to the bucket), runs the compiled step, then
-*harvests* the newly written slots back into pages.  Retiring a request
-returns its pages to a free list, so memory is reused across the stream
-and no page is ever shared between two live requests.
+of device-resident pools, mapped through a per-request :class:`PageTable`.
+Each engine step the executor *materializes* the batch's rows: one jitted
+gather on the device builds the dense cache (padded to the bucket) from
+the pools, through a per-token index table of a few kilobytes.  The
+compiled step runs on that copy, then *harvest* scatters only the newly
+written slots, and each row's recurrent state, back into the pools, also
+on the device.  Nothing but the index tables crosses the host link.
+Retiring a request returns its pages to a free list, so memory is reused
+across the stream and no page is ever shared between two live requests.
+
+The host keeps the allocator's bookkeeping: the free lists
+(:class:`PagePool`), each request's pages and length, and the page-demand
+pre-check that lets a capacity failure raise before anything changes.
 
 **Page geometry is a specialization point.**  The layout — ``paged`` with
 a tunable page size, or ``contig`` (one max-length page per request, the
@@ -20,28 +27,40 @@ tiny registered ``kv_plan`` handler (:func:`kv_plan_builder`), and
 exactly the machinery that tunes kernel implementations and bucket
 schemes, persisting through ``spec_state.json`` like any other tuned
 config.  The tradeoff being searched: small pages waste no capacity on
-short requests (more concurrent requests fit) but fragment the host
-copies; big pages copy in long runs but strand capacity.  A geometry
+short requests (more concurrent requests fit); big pages strand capacity.
+Each geometry has its own pools, but every pool holds the same number of
+token rows when the page sizes divide the capacity, so the gather and
+scatter programs are shared and a re-tune compiles nothing.  A geometry
 re-tune only affects *future* joins — in-flight requests keep the
-geometry they were admitted under, so no live state is ever migrated.
+geometry they were admitted under, so no live state is ever migrated; a
+geometry's pools are released once no live request is in it and it is no
+longer the active one.
 
 Cache pytree leaves are classified by the model's logical axes
 (``model.cache_axes(cfg)``), so the manager is generic across mixers:
 
-* ``seq_kv`` in axes      -> **paged** (attention/MLA KV rings),
-* ``batch`` without seq   -> **row state** (SSM/RWKV recurrent state,
-  copied whole per request per step — it is O(1) in sequence length),
-* neither                 -> **shared** (e.g. ``slot_pos``), passed
-  through from the template.
+* ``seq_kv`` in axes      -> **paged**: a pool of token rows, the leaf's
+  axes with the batch axis dropped and the sequence axis holding tokens
+  (attention/MLA KV),
+* ``batch`` without seq   -> **row state**: a pool of row slots, one per
+  request that fits the capacity plus the template row, the leaf's axes
+  with slots on the batch axis (SSM/RWKV recurrent state, O(1) in
+  sequence length),
+* neither                 -> **shared** (e.g. ``slot_pos``), uploaded
+  from the template every step.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import math
 from typing import Any, Callable, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import telemetry
 
@@ -53,6 +72,9 @@ __all__ = ["PageError", "PagePool", "PageTable", "PagedKV",
 #: Spec-point labels for the KV plan handler.
 KV_LAYOUT_POINT = "kv_layout"
 KV_PAGE_POINT = "kv_page_size"
+
+#: An index past every pool: a gather reads zeros there, a scatter drops it.
+_NOWHERE = np.iinfo(np.int32).max
 
 
 class PageError(RuntimeError):
@@ -118,11 +140,17 @@ class PageTable:
     geometry: tuple[str, int]            # (layout, page_size)
     pages: list[int] = dataclasses.field(default_factory=list)
     length: int = 0                      # tokens written so far
-    row_state: list = dataclasses.field(default_factory=list)
+    slot: int | None = None              # row-state slot, from 1st harvest
 
     @property
     def page_size(self) -> int:
         return self.geometry[1]
+
+    def pool_rows(self, start: int, n: int) -> np.ndarray:
+        """The pool's token rows behind slots ``[start, start + n)``."""
+        ps = self.page_size
+        slots = np.arange(start, start + n)
+        return np.asarray(self.pages, np.int32)[slots // ps] * ps + slots % ps
 
 
 # -- leaf classification --------------------------------------------------------
@@ -137,16 +165,103 @@ class _LeafSpec:
     seq_i: int | None       # seq_kv axis index in the original layout
     shape: tuple            # original template shape (batch dim == 1)
     dtype: Any
-    token_shape: tuple      # moved-layout trailing dims (paged leaves)
-    template_row: "np.ndarray | None"   # one row's initial state
-    template_value: Any = None          # shared leaves: passed through
+    template: "np.ndarray | None" = None   # shared leaves: uploaded as is
+
+    def token_shape(self) -> tuple:
+        """One token's slice of a paged leaf: its axes but batch and seq."""
+        return tuple(n for d, n in enumerate(self.shape)
+                     if d not in (self.bat_i, self.seq_i))
 
 
-def _moved(arr, bat_i: int, seq_i: int | None):
-    """View with batch first (and seq second, for paged leaves)."""
-    if seq_i is None:
-        return np.moveaxis(arr, bat_i, 0)
-    return np.moveaxis(arr, (bat_i, seq_i), (0, 1))
+# -- the device programs ----------------------------------------------------------
+#
+# A paged pool is token-major, ``(tokens + 1, *token_shape)``: the TPU's
+# gather and scatter index the major axis in place, where a pool in the
+# leaf's own axis order is first copied whole into that layout (both
+# ways, every step).  Its last row stays zero: slots past a row's length
+# and padding rows read it.  A row-state pool keeps the leaf's axis order
+# with slots on the batch axis: the request slots, then the template row,
+# then a sink that padding rows write (a row is written with an in-place
+# dynamic update, which cannot drop a write).
+
+def _to_leaf(x, bat_i: int, seq_i: int):
+    """``(B, S, *token_shape)`` -> the leaf's own axis order."""
+    rest = [d for d in range(x.ndim) if d not in (bat_i, seq_i)]
+    return jnp.transpose(x, [0 if d == bat_i else 1 if d == seq_i
+                             else 2 + rest.index(d) for d in range(x.ndim)])
+
+
+def _from_leaf(x, bat_i: int, seq_i: int):
+    """The leaf's own axis order -> ``(B, S, *token_shape)``."""
+    return jnp.transpose(x, [bat_i, seq_i] + [
+        d for d in range(x.ndim) if d not in (bat_i, seq_i)])
+
+
+@functools.partial(jax.jit, static_argnames=("paged", "row"))
+def _gather(pools, row_pools, table, *, paged, row):
+    """The dense paged and row-state leaves of one step.
+
+    ``table (B, S + 2)``: each row's pool token rows (``_NOWHERE`` past
+    its length), then the index of its geometry in ``pools`` (-1 for
+    padding), then its row-state slot.  ``pools[k][i]`` is geometry k's
+    pool for the i-th paged leaf, whose (batch, seq) axes are
+    ``paged[i]``; ``row`` holds the batch axes of ``row_pools``."""
+    tokens, geo, slot = table[:, :-2], table[:, -2], table[:, -1]
+    b, s = tokens.shape
+    out = []
+    for i, (bat_i, seq_i) in enumerate(paged):
+        leaf = None
+        for k, geo_pools in enumerate(pools):
+            pool = geo_pools[i]
+            zero = pool.shape[0] - 1
+            mine = (geo == k)[:, None]
+            rows = jnp.where(mine, jnp.minimum(tokens, zero), zero)
+            part = jnp.take(pool, rows.reshape(-1), axis=0, mode="clip")
+            part = part.reshape((b, s) + pool.shape[1:])
+            leaf = part if leaf is None else jnp.where(
+                mine.reshape((b, 1) + (1,) * (part.ndim - 2)), part, leaf)
+        out.append(_to_leaf(leaf, bat_i, seq_i))
+    for pool, bat_i in zip(row_pools, row):
+        out.append(jnp.take(pool, slot, axis=bat_i, mode="clip"))
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("paged", "row"),
+                   donate_argnums=(0, 1))
+def _scatter(pools, row_pools, new_paged, new_row, table, *, paged, row):
+    """Write one step's new slots and row state into the (donated) pools.
+
+    ``table (B, W + 3)``: the pool token rows of the ``W`` slots from
+    each row's window start (``_NOWHERE`` for a slot it did not write),
+    the window start, the index of its geometry in ``pools``, and its
+    row-state slot (the sink for padding rows)."""
+    w = table.shape[1] - 3
+    dest, start, geo, slot = (table[:, :w], table[:, w], table[:, w + 1],
+                              table[:, w + 2])
+    b = table.shape[0]
+    out_pools = [[] for _ in pools]
+    for leaf, (bat_i, seq_i), *geo_pools in zip(new_paged, paged, *pools):
+        size = list(leaf.shape)
+        size[bat_i], size[seq_i] = 1, w
+        window = []
+        for r in range(b):
+            at = [0] * leaf.ndim
+            at[bat_i], at[seq_i] = r, start[r]
+            window.append(lax.dynamic_slice(leaf, at, size))
+        window = _from_leaf(jnp.concatenate(window, axis=bat_i), bat_i, seq_i)
+        window = window.reshape((b * w,) + window.shape[2:])
+        for k, pool in enumerate(geo_pools):
+            rows = jnp.where((geo == k)[:, None], dest, _NOWHERE)
+            out_pools[k].append(pool.at[rows.reshape(-1)].set(
+                window.astype(pool.dtype), mode="drop"))
+    out_rows = []
+    for pool, leaf, bat_i in zip(row_pools, new_row, row):
+        for r in range(b):
+            pool = lax.dynamic_update_slice_in_dim(
+                pool, lax.slice_in_dim(leaf, r, r + 1, axis=bat_i).astype(
+                    pool.dtype), slot[r], axis=bat_i)
+        out_rows.append(pool)
+    return out_pools, out_rows
 
 
 class PagedKV:
@@ -155,20 +270,20 @@ class PagedKV:
     ``template`` is a cache built for ``batch=1`` at full ``max_len``
     (``model.init_cache(cfg, 1, max_len, opts)``); ``axes`` is the
     matching logical-axes pytree (``model.cache_axes(cfg)``).  The
-    manager owns host (numpy) page pools per *geometry*; device arrays
-    exist only for the duration of a step (materialize -> run -> harvest).
+    manager owns device pools per *geometry* for the paged leaves and one
+    row-slot pool per row-state leaf, all on ``device`` (``None``: the
+    default device); each step's dense cache is a fresh gathered copy the
+    step program may donate.
 
-    ``capacity_tokens`` bounds each geometry's pool.  ``geometry`` fixes
-    the layout; attach a :class:`KVTuner` to tune it online instead.
-    ``device`` is where each step's dense cache is uploaded (``None``: the
-    default device).
+    ``capacity_tokens`` bounds each geometry's pool, and
+    ``capacity_tokens // max_len`` requests hold row state at once.
+    ``geometry`` fixes the layout; attach a :class:`KVTuner` to tune it
+    online instead.
     """
 
     def __init__(self, template: Any, axes: Any, *, max_len: int,
                  capacity_tokens: int, page_size: int = 16,
                  layout: str = "paged", device: Any = None):
-        import jax
-
         self.device = device
         if max_len <= 0:
             raise ValueError(f"max_len must be positive, got {max_len}")
@@ -185,6 +300,8 @@ class PagedKV:
                 f"template has {len(t_leaves)} leaves but axes has "
                 f"{len(a_leaves)}; the pytrees must match")
         self._leaves: list[_LeafSpec] = []
+        self._rows: list = []                # row-state pools, in leaf order
+        slots = self.capacity_tokens // self.max_len
         for leaf, ax in zip(t_leaves, a_leaves):
             ax = tuple(ax)
             if len(ax) != np.ndim(leaf):
@@ -195,39 +312,45 @@ class PagedKV:
             if seq_i is not None and bat_i is None:
                 raise ValueError(f"leaf with axes {ax} has seq_kv but no "
                                  f"batch axis; cannot page it per request")
-            host = np.asarray(leaf)
+            shape, dtype = tuple(np.shape(leaf)), np.dtype(leaf.dtype)
             if seq_i is not None:
-                moved = _moved(host, bat_i, seq_i)
-                if moved.shape[1] != self.max_len:
+                if shape[seq_i] != self.max_len:
                     raise ValueError(
-                        f"paged leaf seq capacity {moved.shape[1]} != "
+                        f"paged leaf seq capacity {shape[seq_i]} != "
                         f"max_len {self.max_len}; windowed (SWA) caches "
                         f"are not pageable per request")
-                self._leaves.append(_LeafSpec(
-                    _PAGED, bat_i, seq_i, host.shape, host.dtype,
-                    moved.shape[2:], None))
+                self._leaves.append(_LeafSpec(_PAGED, bat_i, seq_i, shape,
+                                              dtype))
             elif bat_i is not None:
-                moved = _moved(host, bat_i, None)
-                self._leaves.append(_LeafSpec(
-                    _ROW, bat_i, None, host.shape, host.dtype,
-                    moved.shape[1:], moved[0].copy()))
+                self._leaves.append(_LeafSpec(_ROW, bat_i, None, shape,
+                                              dtype))
+                # the request slots, the template row (what padding rows
+                # and fresh requests read) and the sink
+                row = jax.device_put(np.asarray(leaf), device)
+                self._rows.append(jnp.repeat(row, slots + 2, axis=bat_i))
             else:
                 # Shared leaves are kept on host and re-uploaded each
                 # materialize: handlers may donate the cache argument, so
                 # a device buffer handed out once cannot be reused.
                 self._leaves.append(_LeafSpec(
-                    _SHARED, None, None, host.shape, host.dtype,
-                    (), None, template_value=host.copy()))
+                    _SHARED, None, None, shape, dtype,
+                    template=np.asarray(leaf).copy()))
         self._paged_idx = [i for i, l in enumerate(self._leaves)
                            if l.kind == _PAGED]
         self._row_idx = [i for i, l in enumerate(self._leaves)
                          if l.kind == _ROW]
-        # geometry -> (PagePool, {leaf index -> pool array})
-        self._pools: dict[tuple[str, int],
-                          tuple[PagePool, dict[int, np.ndarray]]] = {}
+        self._paged_axes = tuple((self._leaves[i].bat_i,
+                                  self._leaves[i].seq_i)
+                                 for i in self._paged_idx)
+        self._row_axes = tuple(self._leaves[i].bat_i for i in self._row_idx)
+        self._slots = PagePool(slots, 1) if self._row_idx else None
+        self._template_slot, self._sink_slot = slots, slots + 1
+        # geometry -> (PagePool, [device pool per paged leaf])
+        self._pools: dict[tuple[str, int], tuple[PagePool, list]] = {}
         self._tables: dict[str, PageTable] = {}
         self._tuner: "KVTuner | None" = None
         self._fixed = self._normalize(layout, page_size)
+        self._wide = 1                       # widest multi-token scatter
 
     # -- geometry ---------------------------------------------------------------
     def _normalize(self, layout: str, page_size: int | None) -> tuple[str, int]:
@@ -259,32 +382,39 @@ class PagedKV:
                                "using fixed geometry", layout, page)
         return self._fixed
 
-    def _geo_pools(self, geo: tuple[str, int]) \
-            -> tuple[PagePool, dict[int, np.ndarray]]:
+    def _geo_pools(self, geo: tuple[str, int]) -> tuple[PagePool, list]:
         entry = self._pools.get(geo)
         if entry is None:
             _, page_size = geo
             num_pages = max(1, math.ceil(self.capacity_tokens / page_size))
-            pools = {
-                i: np.zeros((num_pages, page_size)
-                            + self._leaves[i].token_shape,
-                            self._leaves[i].dtype)
-                for i in self._paged_idx}
+            rows = num_pages * page_size + 1        # the last stays zero
+            pools = [jnp.zeros((rows,) + self._leaves[i].token_shape(),
+                               self._leaves[i].dtype, device=self.device)
+                     for i in self._paged_idx]
             entry = (PagePool(num_pages, page_size), pools)
             self._pools[geo] = entry
         return entry
 
+    def _release_idle(self) -> None:
+        """Drop the pools of geometries no live request is in, but for the
+        active one (a geometry sweep would otherwise keep one per
+        candidate)."""
+        keep = {t.geometry for t in self._tables.values()}
+        keep.add(self.active_geometry())
+        for geo in [g for g in self._pools if g not in keep]:
+            del self._pools[geo]
+
     # -- request lifecycle ------------------------------------------------------
     def join(self, rid: str) -> PageTable:
         """Admit a request under the active geometry; pages are allocated
-        lazily as tokens are written."""
+        lazily as tokens are written, and a row-state slot at its first
+        harvest (until then it reads as the template row)."""
         if rid in self._tables:
             raise PageError(f"request {rid!r} already live")
         geo = self.active_geometry()
+        self._release_idle()
         self._geo_pools(geo)           # materialize the pool up front
-        table = PageTable(rid=rid, geometry=geo,
-                          row_state=[self._leaves[i].template_row.copy()
-                                     for i in self._row_idx])
+        table = PageTable(rid=rid, geometry=geo)
         self._tables[rid] = table
         return table
 
@@ -294,9 +424,12 @@ class PagedKV:
         table = self._tables.pop(rid, None)
         if table is None:
             raise PageError(f"request {rid!r} is not live")
-        pool, _ = self._geo_pools(table.geometry)
+        pool, _ = self._pools[table.geometry]
         for pid in table.pages:
             pool.free(pid)
+        if table.slot is not None:
+            self._slots.free(table.slot)
+        self._release_idle()
         return len(table.pages)
 
     def length(self, rid: str) -> int:
@@ -332,42 +465,39 @@ class PagedKV:
         """Assemble a dense device cache for one step.
 
         Rows ``0..len(rids)`` hold those requests' paged tokens and row
-        state; rows beyond are padding (template-initial).  Returns
-        ``(cache pytree, lengths)`` where ``lengths[i]`` is request i's
-        token count — the executor passes it as the per-row write
-        position vector.
+        state; rows beyond are padding (template-initial), and slots at or
+        past a row's length read zero.  Returns ``(cache pytree,
+        lengths)`` where ``lengths[i]`` is request i's token count — the
+        executor passes it as the per-row write position vector.  The
+        cache is a copy: the pools are never handed to the step.
         """
-        import jax
-
         if len(rids) > batch:
             raise ValueError(f"{len(rids)} requests do not fit in "
                              f"batch {batch}")
         tables = [self._tables[r] for r in rids]
-        out_leaves = []
-        for i, spec in enumerate(self._leaves):
-            if spec.kind == _SHARED:
-                out_leaves.append(self._upload(spec.template_value.copy()))
-                continue
-            shape = list(spec.shape)
-            shape[spec.bat_i] = batch
-            with telemetry.span("kv.gather"):
-                staging = np.zeros(tuple(shape), spec.dtype)
-                view = _moved(staging, spec.bat_i, spec.seq_i)
-                if spec.kind == _ROW:
-                    view[:] = spec.template_row
-                    for r, table in enumerate(tables):
-                        view[r] = table.row_state[self._row_idx.index(i)]
-                else:
-                    for r, table in enumerate(tables):
-                        pool_arr = self._geo_pools(table.geometry)[1][i]
-                        ps = table.page_size
-                        for j, pid in enumerate(table.pages):
-                            a = j * ps
-                            n = min(ps, table.length - a)
-                            if n <= 0:
-                                break
-                            view[r, a:a + n] = pool_arr[pid, :n]
-            out_leaves.append(self._upload(staging))
+        with telemetry.span("kv.gather"):
+            geos = list(dict.fromkeys(t.geometry for t in tables)) \
+                or [self.active_geometry()]
+            s = self.max_len if self._paged_idx else 0
+            index = np.full((batch, s + 2), _NOWHERE, np.int32)
+            index[:, s] = -1
+            index[:, s + 1] = self._template_slot
+            for r, t in enumerate(tables):
+                if s:
+                    index[r, :t.length] = t.pool_rows(0, t.length)
+                index[r, s] = geos.index(t.geometry)
+                if t.slot is not None:
+                    index[r, s + 1] = t.slot
+        index = self._upload(index)
+        out_leaves = [self._upload(spec.template)
+                      if spec.kind == _SHARED else None
+                      for spec in self._leaves]
+        with telemetry.span("kv.gather"):
+            built = _gather(
+                [self._geo_pools(g)[1] for g in geos], self._rows, index,
+                paged=self._paged_axes, row=self._row_axes)
+        for i, leaf in zip(self._paged_idx + self._row_idx, built):
+            out_leaves[i] = leaf
         cache = jax.tree_util.tree_unflatten(self._treedef, out_leaves)
         lengths = np.array([t.length for t in tables]
                            + [0] * (batch - len(tables)), np.int32)
@@ -375,24 +505,24 @@ class PagedKV:
 
     def harvest(self, rids: Sequence[str], new_cache: Any,
                 n_new: Sequence[int]) -> None:
-        """Copy each request's newly written slots back into its pages.
+        """Write each request's newly written slots back into its pages.
 
         Request i wrote ``n_new[i]`` tokens at slots
-        ``[length, length + n_new[i])`` of row i.  Pages are allocated on
-        demand; the whole-batch page demand is checked *before* any
-        mutation, so a capacity failure raises :class:`PageError` without
-        corrupting any request's state.
+        ``[length, length + n_new[i])`` of row i; every real row's state
+        goes to its row-state slot.  Pages and slots are allocated on
+        demand; the whole-batch demand is checked *before* any mutation,
+        so a capacity failure raises :class:`PageError` without
+        corrupting any request's state.  Leaves are cast to the pools'
+        dtypes; nothing leaves the device.
         """
-        import jax
-
         new_leaves, _ = jax.tree_util.tree_flatten(new_cache)
         if len(new_leaves) != len(self._leaves):
             raise ValueError("new_cache structure does not match template")
         tables = [self._tables[r] for r in rids]
-        # pre-check page demand per geometry pool
+        n_new = [int(n) for n in n_new]
+        # pre-check page demand per geometry pool, and row-state slots
         demand: dict[tuple[str, int], int] = {}
         for table, n in zip(tables, n_new):
-            n = int(n)
             if n == 0:
                 continue
             end = table.length + n
@@ -408,46 +538,72 @@ class PagedKV:
                 raise PageError(
                     f"geometry {geo} needs {need} pages but only "
                     f"{pool.free_pages} free")
-        # the step program (and this step's uploads) must finish first
+        if self._slots is not None:
+            fresh = sum(t.slot is None for t in tables)
+            if fresh > self._slots.free_pages:
+                raise PageError(f"{fresh} requests need row-state slots but "
+                                f"only {self._slots.free_pages} free")
+        # the step program must finish first
         with telemetry.span("kv.wait"):
             jax.block_until_ready(new_cache)
-        # whole leaves to the host: row state always, paged leaves when a
-        # row wrote tokens
-        wanted = self._row_idx + (self._paged_idx
-                                  if any(int(n) for n in n_new) else [])
-        host: dict[int, np.ndarray] = {}
-        for i in wanted:
-            spec = self._leaves[i]
-            with telemetry.span("kv.download", bytes=new_leaves[i].nbytes):
-                arr = np.asarray(new_leaves[i])
-            host[i] = _moved(arr, spec.bat_i, spec.seq_i)
-        # copy each row's written slots and row state into its pages
+        writes = [(t, n) for t, n in zip(tables, n_new) if n]
+        if not self._row_idx and not (self._paged_idx and writes):
+            return
         with telemetry.span("kv.scatter"):
+            geos = list(dict.fromkeys(t.geometry for t, _ in writes)) \
+                if self._paged_idx else []
+            w = self._width(max(n_new, default=0)) if geos else 0
+            first = (self._paged_idx + self._row_idx)[0]
+            batch = new_leaves[first].shape[self._leaves[first].bat_i]
+            index = np.full((batch, w + 3), _NOWHERE, np.int32)
+            index[:, w] = 0
+            index[:, w + 1] = -1
+            index[:, w + 2] = self._sink_slot
             for r, (table, n) in enumerate(zip(tables, n_new)):
-                n = int(n)
-                # row state is O(1)-sized: refresh it every step regardless
-                for k, i in enumerate(self._row_idx):
-                    table.row_state[k] = host[i][r].copy()
+                if self._slots is not None:
+                    if table.slot is None:
+                        table.slot = self._slots.alloc()
+                    index[r, w + 2] = table.slot
                 if n == 0:
                     continue
-                pool, pools = self._geo_pools(table.geometry)
-                ps = table.page_size
-                start = table.length
-                while len(table.pages) * ps < start + n:
+                pool, _ = self._pools[table.geometry]
+                while len(table.pages) * table.page_size < table.length + n:
                     table.pages.append(pool.alloc())
-                for i in self._paged_idx:
-                    span = host[i][r, start:start + n]
-                    for off in range(0, n, ps):
-                        slot = start + off
-                        j, a = divmod(slot, ps)
-                        m = min(ps - a, n - off)
-                        pools[i][table.pages[j], a:a + m] = span[off:off + m]
-                table.length = start + n
+                if geos:
+                    # a window of w slots that holds the written ones and
+                    # ends inside the row
+                    lo = min(table.length, self.max_len - w)
+                    off = table.length - lo
+                    index[r, off:off + n] = table.pool_rows(table.length, n)
+                    index[r, w] = lo
+                    index[r, w + 1] = geos.index(table.geometry)
+        index = self._upload(index)
+        with telemetry.span("kv.scatter"):
+            pools, self._rows = _scatter(
+                [self._pools[g][1] for g in geos], self._rows,
+                [new_leaves[i] for i in self._paged_idx] if geos else [],
+                [new_leaves[i] for i in self._row_idx], index,
+                paged=self._paged_axes, row=self._row_axes)
+            for g, geo_pools in zip(geos, pools):
+                self._pools[g][1][:] = geo_pools
+        for table, n in zip(tables, n_new):
+            table.length += n
+
+    def _width(self, most: int) -> int:
+        """Slots a row to scatter: 1 for a decode step; for a step that
+        wrote several tokens a row, the widest power of two seen so far,
+        so a chunked prefill's short last chunks reuse the full chunk's
+        program instead of compiling their own."""
+        if most <= 1:
+            return 1
+        self._wide = max(self._wide,
+                         min(1 << (most - 1).bit_length(), self.max_len))
+        return self._wide
 
     # -- reporting --------------------------------------------------------------
     def stats(self) -> dict:
         geos = {}
-        for geo, (pool, _) in self._pools.items():
+        for geo, (pool, arrays) in self._pools.items():
             geos[f"{geo[0]}@{geo[1]}"] = {
                 "num_pages": pool.num_pages,
                 "live_pages": pool.live_pages,
@@ -455,20 +611,19 @@ class PagedKV:
                 "allocs": pool.allocs,
                 "frees": pool.frees,
                 "high_water": pool.high_water,
+                "pool_bytes": sum(a.nbytes for a in arrays),
             }
         return {
             "live_requests": len(self._tables),
             "active_geometry": list(self.active_geometry()),
             "pools": geos,
+            "row_state_bytes": sum(a.nbytes for a in self._rows),
         }
 
     def _upload(self, host: np.ndarray):
-        """One leaf to the step's device.  ``device_put`` returns before
-        the copy lands: the rest of it (a layout transpose on the
-        runtime's worker threads, then the transfer) runs beside the host
-        work that follows, and ``harvest``'s wait covers what is left."""
-        import jax
-
+        """One host array (an index table or a shared leaf) to the step's
+        device.  ``device_put`` returns before the copy lands; the programs
+        that read it wait for it on the device."""
         with telemetry.span("kv.upload", bytes=host.nbytes):
             return jax.device_put(host, self.device)
 

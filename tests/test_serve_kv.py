@@ -170,6 +170,227 @@ def test_join_live_and_overflow_raise():
         kv.harvest(["b"], cache, [1])
 
 
+# -- device pools against a host reference ---------------------------------------
+
+LAYERS = 2
+
+
+def _layered_template():
+    """Leaves laid out as the models lay theirs: a stacked-layer bf16 KV
+    leaf, one with its sequence axis ahead of its batch axis, row state
+    whose template is not zero, and a shared leaf."""
+    return {"kv": jnp.zeros((LAYERS, 1, 2, MAX_LEN, 4), jnp.bfloat16),
+            "sk": jnp.zeros((MAX_LEN, 3, 1), jnp.float32),
+            "state": jnp.full((LAYERS, 1, 5), 7.0, jnp.float32),
+            "tick": jnp.arange(3, dtype=jnp.int32)}
+
+
+LAYERED_AXES = {"kv": ("layers", "batch", "kv_heads", "seq_kv", "head_dim"),
+                "sk": ("seq_kv", "model", "batch"),
+                "state": ("layers", "batch", "model"),
+                "tick": (None,)}
+
+
+class _HostReference:
+    """The dense cache as the host staging built it: zeros, the template
+    row in every row-state row, then each request's written slots and its
+    last harvested state copied in; the shared leaf as the template."""
+
+    def __init__(self, template):
+        self.template = {k: np.asarray(v) for k, v in template.items()}
+        self.seqs: dict = {}                 # rid -> {"kv", "sk", "state"}
+
+    def join(self, rid):
+        t = self.template
+        self.seqs[rid] = {"kv": np.zeros_like(t["kv"][:, 0]),
+                          "sk": np.zeros_like(t["sk"][:, :, 0]),
+                          "state": t["state"][:, 0].copy()}
+
+    def dense(self, rids, batch, lengths):
+        t = self.template
+        kv = np.zeros(t["kv"].shape[:1] + (batch,) + t["kv"].shape[2:],
+                      t["kv"].dtype)
+        sk = np.zeros(t["sk"].shape[:2] + (batch,), t["sk"].dtype)
+        state = np.repeat(t["state"], batch, axis=1)
+        for r, rid in enumerate(rids):
+            n, seq = lengths[rid], self.seqs[rid]
+            kv[:, r, :, :n] = seq["kv"][:, :, :n]
+            sk[:n, :, r] = seq["sk"][:n]
+            state[:, r] = seq["state"]
+        return {"kv": kv, "sk": sk, "state": state, "tick": t["tick"]}
+
+    def harvest(self, rids, new, starts, n_new):
+        for r, (rid, a, n) in enumerate(zip(rids, starts, n_new)):
+            seq = self.seqs[rid]
+            seq["kv"][:, :, a:a + n] = new["kv"][:, r, :, a:a + n]
+            seq["sk"][a:a + n] = new["sk"][a:a + n, :, r]
+            seq["state"] = new["state"][:, r].copy()
+
+
+def _assert_same_bits(got, want):
+    for name in want:
+        g, w = np.asarray(got[name]), want[name]
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(g.view(np.uint8), w.view(np.uint8),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("layout,page", [("paged", 4), ("paged", 8),
+                                         ("contig", MAX_LEN)])
+def test_device_pools_match_host_staging_bit_for_bit(layout, page):
+    """Random join/harvest/retire interleavings: every dense cache equals
+    the host reference bit for bit — padding rows, slots past each row's
+    length after LIFO page reuse, rows in any order — and harvest takes
+    only the written slots (the step fills every other slot with noise)."""
+    rng = np.random.default_rng(page)
+    template = _layered_template()
+    kv = PagedKV(template, LAYERED_AXES, max_len=MAX_LEN,
+                 capacity_tokens=4 * MAX_LEN, page_size=page, layout=layout)
+    ref = _HostReference(template)
+    live: dict[str, int] = {}
+    for step in range(60):
+        action = rng.integers(4)
+        if action == 0 and len(live) < 4:
+            rid = f"r{step}"
+            kv.join(rid)
+            ref.join(rid)
+            live[rid] = 0
+        elif action == 1 and live:
+            rid = str(rng.choice(sorted(live)))
+            kv.retire(rid)
+            del live[rid]
+        elif live:
+            rids = [str(r) for r in rng.permutation(sorted(live))]
+            rids = rids[:rng.integers(1, len(rids) + 1)]
+            batch = len(rids) + int(rng.integers(0, 3))
+            cache, lengths = kv.materialize(rids, batch)
+            assert list(lengths[:len(rids)]) == [live[r] for r in rids]
+            _assert_same_bits(cache, ref.dense(rids, batch, live))
+            n_new = [int(rng.integers(0, min(5, MAX_LEN - live[r]) + 1))
+                     for r in rids]
+            noise = {name: jnp.asarray(rng.normal(size=np.shape(leaf)) * 9,
+                                       leaf.dtype)
+                     for name, leaf in cache.items() if name != "tick"}
+            noise["tick"] = cache["tick"]
+            kv.harvest(rids, noise, n_new)
+            ref.harvest(rids, {k: np.asarray(v) for k, v in noise.items()},
+                        [live[r] for r in rids], n_new)
+            for r, n in zip(rids, n_new):
+                live[r] += n
+
+
+def _pool_arrays(kv):
+    return ({geo: [np.asarray(a) for a in arrays]
+             for geo, (_, arrays) in kv._pools.items()},
+            [np.asarray(a) for a in kv._rows])
+
+
+def test_harvest_changes_only_the_written_slots_and_real_rows():
+    kv = PagedKV(_layered_template(), LAYERED_AXES, max_len=MAX_LEN,
+                 capacity_tokens=4 * MAX_LEN, page_size=4)
+    for rid in ("a", "b", "c"):
+        kv.join(rid)
+    cache, _ = kv.materialize(["a", "b", "c"], 4)
+    kv.harvest(["a", "b", "c"], cache, [6, 3, 0])
+    before_pools, before_rows = _pool_arrays(kv)
+    cache, _ = kv.materialize(["c", "a"], 4)
+    step = {k: (v + 1 if k != "tick" else v) for k, v in cache.items()}
+    kv.harvest(["c", "a"], step, [2, 1])
+    after_pools, after_rows = _pool_arrays(kv)
+    (geo,) = after_pools
+    # token rows of the written slots: c's 0-1 (its first page) and a's 6
+    pages = {r: kv.table(r).pages for r in ("a", "c")}
+    written = {pages["c"][0] * 4, pages["c"][0] * 4 + 1, pages["a"][1] * 4 + 2}
+    for before, after in zip(before_pools[geo], after_pools[geo]):
+        changed = {int(t) for t in np.nonzero(
+            (before != after).reshape(len(before), -1).any(axis=1))[0]}
+        assert changed == written
+    # row state: the slots of c and a, and the sink the padding rows wrote
+    slots = {kv.table("c").slot, kv.table("a").slot, kv._sink_slot}
+    for before, after in zip(before_rows, after_rows):
+        changed = {int(s) for s in np.nonzero(
+            (before != after).swapaxes(0, 1).reshape(before.shape[1], -1)
+            .any(axis=1))[0]}
+        assert changed == slots
+    assert kv.table("b").slot not in slots
+    assert kv._template_slot not in slots
+
+
+def test_page_error_leaves_the_device_pools_untouched():
+    kv = PagedKV(_layered_template(), LAYERED_AXES, max_len=MAX_LEN,
+                 capacity_tokens=MAX_LEN, page_size=4)   # one row slot
+    kv.join("a")
+    cache, _ = kv.materialize(["a"], 1)
+    kv.harvest(["a"], cache, [10])                       # 3 of 4 pages
+    kv.join("b")
+    before_pools, before_rows = _pool_arrays(kv)
+    cache, _ = kv.materialize(["a", "b"], 2)
+    for n_new in ([6, 1], [2, 0], [MAX_LEN, 0]):
+        # out of pages; out of row-state slots; past max_len
+        with pytest.raises(PageError):
+            kv.harvest(["a", "b"], cache, n_new)
+    assert kv.length("a") == 10 and kv.length("b") == 0
+    assert kv.table("b").slot is None and not kv.table("b").pages
+    after_pools, after_rows = _pool_arrays(kv)
+    for geo in before_pools:
+        for before, after in zip(before_pools[geo], after_pools[geo]):
+            np.testing.assert_array_equal(before, after)
+    for before, after in zip(before_rows, after_rows):
+        np.testing.assert_array_equal(before, after)
+
+
+def test_idle_inactive_geometry_pool_is_released():
+    kv = make_kv(page_size=4)
+    kv.join("a")
+    cache, _ = kv.materialize(["a"], 1)
+    kv.harvest(["a"], cache, [5])
+    kv.set_geometry("paged", 8)
+    kv.join("b")                                   # a keeps paged@4
+    pools = kv.stats()["pools"]
+    assert set(pools) == {"paged@4", "paged@8"}
+    # 128 token rows and the zero row, 3 float32 lanes each
+    assert pools["paged@4"]["pool_bytes"] == (8 * MAX_LEN + 1) * 3 * 4
+    kv.retire("a")                                 # paged@4 idle, inactive
+    assert set(kv.stats()["pools"]) == {"paged@8"}
+    kv.retire("b")                                 # the active one stays
+    assert set(kv.stats()["pools"]) == {"paged@8"}
+    assert kv.stats()["row_state_bytes"] == (8 + 2) * 3 * 4
+
+
+def test_decode_step_uploads_index_tables_and_shared_leaves_only():
+    import time
+
+    from repro.core import telemetry
+
+    rt = IridescentRuntime(async_compile=False)
+    handler = rt.register("hist", _history_builder,
+                          context_fn=phase_context_fn)
+    kv = make_kv(page_size=4)
+    executor = PhasedExecutor(handler, None, kv, prefill_chunk=2,
+                              prompt_fn=_prompt_fn)
+    engine = ServeEngine(handler, None,
+                         ContinuousBatcher(2, scheme="single"), FCFS(),
+                         executor=executor, queue=AdmissionQueue(),
+                         metrics=ServeMetrics())
+    try:
+        for _ in range(2):
+            assert engine.submit(Request(prompt_tokens=3, max_new_tokens=4))
+        while not engine.active or any(r.prefilling for r in engine.active):
+            engine.step()
+        t0 = time.perf_counter()
+        engine.step()                              # one decode step
+    finally:
+        rt.shutdown()
+    spans = [sp for sp in telemetry.recent_spans() if sp[1] >= t0]
+    assert [n for n, *_ in spans].count("serve.exec.decode") == 1
+    assert not any(n == "kv.download" for n, *_ in spans)
+    up = sum(a["bytes"] for n, _, _, a in spans if n == "kv.upload")
+    # int32 tables of the bucket's 2 rows: materialize's 16 slots, its
+    # geometry and row-state slot; harvest's 1 slot, window start,
+    # geometry and row-state slot; and the shared tick
+    assert 0 < up <= 2 * (MAX_LEN + 2) * 4 + 2 * (1 + 3) * 4 + 4
+
+
 # -- phased executor determinism ------------------------------------------------
 
 def _history_builder(spec):

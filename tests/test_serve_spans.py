@@ -1,5 +1,6 @@
 """The program's spans: one API on the profiler's clock, the tree a serve
-step opens, and the bytes the paged KV manager's transfer spans carry.
+step opens, and the bytes the paged KV manager's upload spans carry (its
+index tables and shared leaves; nothing comes down).
 
 A small engine runs one prefill and one decode step under
 ``jax.profiler`` on the CPU; the trace's ``iri.`` annotations and the
@@ -94,8 +95,8 @@ def test_serve_step_span_tree_on_the_profiler_trace(tmp_path):
     names = {n for n, _, _ in spans}
     assert names >= {"serve.step", "serve.exec.decode", "serve.exec.prefill",
                      "kv.gather", "kv.upload", "serve.dispatch", "kv.wait",
-                     "kv.download", "kv.scatter", "serve.sample",
-                     "serve.control"}
+                     "kv.scatter", "serve.sample", "serve.control"}
+    assert "kv.download" not in names
     steps = [s for s in spans if s[0] == "serve.step"]
     assert len(steps) == 4
     execs = [s for s in spans if s[0].startswith("serve.exec.")]
@@ -103,7 +104,7 @@ def test_serve_step_span_tree_on_the_profiler_trace(tmp_path):
     assert all(any(_inside(ex, st) for st in steps) for ex in execs)
     for ex in (s for s in execs if s[0] == "serve.exec.decode"):
         for name in ("kv.gather", "kv.upload", "serve.dispatch", "kv.wait",
-                     "kv.download", "kv.scatter", "serve.sample"):
+                     "kv.scatter", "serve.sample"):
             assert any(_inside(s, ex) for s in spans if s[0] == name), name
     for c in (s for s in spans if s[0] == "serve.control"):
         assert any(_inside(c, st) for st in steps)
@@ -124,16 +125,21 @@ def test_decode_step_spans_in_the_order_of_the_work():
         rt.shutdown()
     # the ring is appended as spans end: children before their parents
     names = [n for n, *_ in _since(t0) if n != "compile.build"]      # the decode program's compile
-    leaves = ["kv.gather", "kv.upload", "kv.gather", "kv.upload",
-              "kv.upload", "serve.dispatch", "kv.wait", "kv.download",
-              "kv.download", "kv.scatter", "serve.sample"]
+    # materialize: the index table, its upload and the shared leaf's, the
+    # gather; harvest: the wait, the scatter's table, its upload, the
+    # scatter
+    leaves = ["kv.gather", "kv.upload", "kv.upload", "kv.gather",
+              "serve.dispatch", "kv.wait", "kv.scatter", "kv.upload",
+              "kv.scatter", "serve.sample"]
     assert names[:len(leaves)] == leaves
     assert names[len(leaves):] == ["serve.exec.decode", "serve.control",
                                    "serve.step"]
 
 
 def test_paged_kv_byte_counters_match_the_leaves():
-    # the byte counts ride on the KV transfer spans as their ``bytes`` arg
+    # the byte counts ride on the KV transfer spans as their ``bytes`` arg:
+    # the pools stay on the device, so only the index tables and the
+    # shared leaf go up, and nothing comes down
     kv = PagedKV(_template(), AXES, max_len=MAX_LEN,
                  capacity_tokens=4 * MAX_LEN, page_size=4)
     kv.join("a")
@@ -147,21 +153,24 @@ def test_paged_kv_byte_counters_match_the_leaves():
     t0 = time.perf_counter()
     cache, _ = kv.materialize(["a", "b"], 4)
     leaves = jax.tree_util.tree_leaves(cache)
-    staged = sum(int(np.asarray(x).nbytes) for x in leaves)
+    dense = sum(int(np.asarray(x).nbytes) for x in leaves)
     # k (4, 16, 3) + state (4, 2) in float32, tick int32
-    assert staged == 4 * 16 * 3 * 4 + 4 * 2 * 4 + 4
-    assert moved(t0) == {"kv.upload": staged, "kv.download": 0}
-    # the shared leaf is uploaded but not staged
+    assert dense == 4 * 16 * 3 * 4 + 4 * 2 * 4 + 4
+    # an int32 table of each row's 16 slots, geometry and row-state slot,
+    # and the shared tick
+    assert moved(t0) == {"kv.upload": 4 * (16 + 2) * 4 + 4, "kv.download": 0}
+    # the table's build and the gather's dispatch
     assert [n for n, *_ in _since(t0)].count("kv.gather") == 2
     t0 = time.perf_counter()
     kv.harvest(["a", "b"], cache, [1, 1])
-    # the paged and row-state leaves come back; the shared one does not
-    assert moved(t0) == {"kv.upload": 0, "kv.download": staged - 4}
-    # a harvest with no token written brings back the row state alone
+    # the scatter's table: each row's written slot, length, geometry and
+    # row-state slot
+    assert moved(t0) == {"kv.upload": 4 * (1 + 3) * 4, "kv.download": 0}
+    # a harvest with no token written writes the row state alone
     cache, _ = kv.materialize(["a"], 1)
     t0 = time.perf_counter()
     kv.harvest(["a"], cache, [0])
-    assert moved(t0)["kv.download"] == 1 * 2 * 4
+    assert moved(t0) == {"kv.upload": 1 * 3 * 4, "kv.download": 0}
 
 
 def test_bus_span_is_the_span_bound_to_that_bus(tmp_path):

@@ -129,3 +129,43 @@ def test_serve_step_compiles_at_published_width(one_chip, monkeypatch, phase):
         params, cache, tokens, rows, rows).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert registry.default_registry.fallback_counts == before
+
+
+@pytest.mark.parametrize("program", ["gather", "scatter"])
+def test_paged_kv_programs_compile_in_place(one_chip, program):
+    """The paged KV gather and scatter at published widths: qwen3-0.6b's
+    k and v at batch 8, max_len 1024 over 8192 pool tokens, rwkv6-1.6b's
+    row state at batch 16.  The scatter updates the donated pools in
+    place, and the gather keeps no copy of a pool on the side (a pool in
+    the leaf's own axis order is copied whole into the layout the TPU
+    indexes, every step)."""
+    from repro.serve import kv
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, max_len, tokens = 8, 1024, 8192
+    dense = on_chip((28, b, 8, max_len, 128), BF16)
+    pools = [[on_chip((tokens + 1, 28, 8, 128), BF16)] * 2]
+    rows = [on_chip((24, 16 + 2, 32, 64, 64), F32),
+            on_chip((24, 16 + 2, 2048), BF16),
+            on_chip((24, 16 + 2, 2048), BF16)]
+    state = [on_chip((24, 16, 32, 64, 64), F32),
+             on_chip((24, 16, 2048), BF16), on_chip((24, 16, 2048), BF16)]
+    paged, row = ((1, 3), (1, 3)), (1, 1, 1)
+    mib = 1 << 20
+    if program == "gather":
+        compiled = kv._gather.lower(pools, rows, on_chip((b, max_len + 2), I32),
+                                    paged=paged, row=row).compile()
+        leaf = 28 * b * 8 * max_len * 128 * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < leaf + 16 * mib
+    else:
+        for width in (1, 16):
+            table = on_chip((b, width + 3), I32)
+            compiled = kv._scatter.lower(pools, [], [dense] * 2, [], table,
+                                         paged=paged, row=()).compile()
+            assert compiled.memory_analysis().temp_size_in_bytes < 16 * mib
+        compiled = kv._scatter.lower([], rows, [], state,
+                                     on_chip((16, 3), I32),
+                                     paged=(), row=row).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 * mib
