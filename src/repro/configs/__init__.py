@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned configs + input-shape sets.
+"""Architecture registry: the 10 assigned configs, one chip's cut of
+deepseek-v2-236b under expert parallelism, and the input-shape sets.
 
 Every (arch x shape) cell is well-defined here; ``input_specs`` produces the
 ShapeDtypeStruct stand-ins the dry-run lowers (no allocation).  ``long_500k``
@@ -21,6 +22,7 @@ __all__ = ["ARCHS", "ARCH_IDS", "SHAPES", "Shape", "get_config", "get_reduced",
 ARCHS = (
     "kimi_k2_1t_a32b",
     "deepseek_v2_236b",
+    "deepseek_v2_236b_ep16",
     "internvl2_2b",
     "yi_6b",
     "deepseek_7b",
@@ -35,6 +37,7 @@ ARCHS = (
 _ALIAS = {
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
     "deepseek-v2-236b": "deepseek_v2_236b",
+    "deepseek-v2-236b-ep16": "deepseek_v2_236b_ep16",
     "internvl2-2b": "internvl2_2b",
     "yi-6b": "yi_6b",
     "deepseek-7b": "deepseek_7b",

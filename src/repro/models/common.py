@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -11,7 +12,7 @@ from repro.distributed.sharding import constrain
 from repro.kernels import rmsnorm as rmsnorm_kernel
 
 __all__ = ["KernelOptions", "rms_norm", "rope", "apply_rope", "swiglu",
-           "dense_init", "embed_init"]
+           "dense_init", "embed_init", "yarn_mscale"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,13 +58,54 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6,
                                   impl=opts.impl_for("rmsnorm"))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context stretched ``factor``
+    times (hf ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_freqs(dim: int, theta: float, cfg) -> tuple[jnp.ndarray, float]:
+    """YaRN's inverse frequencies and cos/sin scale (hf
+    ``DeepseekV2YarnRotaryEmbedding``): dimensions that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    those that turn fewer than ``beta_slow`` times are divided by the
+    factor, and a linear ramp blends the ones between."""
+    half = dim // 2
+    extra = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    inter = extra / cfg.yarn_factor
+
+    def at(rotations):
+        return dim * math.log(cfg.yarn_original_max_len
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(at(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(at(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    keep = 1.0 - jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                          / (high - low), 0.0, 1.0)
+    scale = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return inter * (1.0 - keep) + extra * keep, scale
+
+
 def rope(positions: jnp.ndarray, dim: int, theta: float = 1e4,
-         dtype=jnp.float32) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Rotary embedding tables. positions (...,) -> cos/sin (..., dim/2)."""
+         dtype=jnp.float32, cfg=None) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Rotary embedding tables. positions (...,) -> cos/sin (..., dim/2).
+    A ``cfg`` (ModelConfig) with ``yarn_factor`` set gives YaRN's."""
     assert dim % 2 == 0, dim
-    freqs = theta ** (-jnp.arange(0, dim // 2, dtype=jnp.float32) / (dim // 2))
+    if cfg is not None and cfg.yarn_factor:
+        freqs, scale = _yarn_freqs(dim, theta, cfg)
+    else:
+        freqs = theta ** (-jnp.arange(0, dim // 2, dtype=jnp.float32)
+                          / (dim // 2))
+        scale = 1.0
     angles = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
+    if scale == 1.0:
+        return jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
+    return ((jnp.cos(angles) * scale).astype(dtype),
+            (jnp.sin(angles) * scale).astype(dtype))
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray,

@@ -24,6 +24,14 @@ class ModelConfig:
     qk_norm: bool = False
     window: int | None = None       # sliding-window attention (hybrid long ctx)
     rope_theta: float = 1e4
+    rope_interleave: bool = False   # rotate (even, odd) pairs, not halves
+    # YaRN context extension (hf DeepseekV2YarnRotaryEmbedding); 0 = off
+    yarn_factor: float = 0.0
+    yarn_original_max_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # MLA (deepseek-v2)
     q_lora_rank: int = 0
@@ -37,6 +45,17 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     n_dense_layers: int = 0         # dense prefix before MoE layers
+    # gate: softmax -> best ``topk_group`` of ``n_group`` expert groups
+    # (by their best score) -> top_k inside them -> renormalize, or scale
+    # by ``routed_scaling_factor`` when ``norm_topk_prob`` is off
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # expert parallelism: this chip holds experts [first_expert,
+    # first_expert + n_experts_held) of the n_experts the router scores
+    n_experts_held: int = 0         # 0 -> all of them
+    first_expert: int = 0
 
     # token mixer
     mixer: str = "attn"             # attn | rwkv6 | hymba
@@ -60,6 +79,16 @@ class ModelConfig:
             object.__setattr__(self, "mixer", "hymba")
         if self.ssm_state and not self.ssm_heads:
             object.__setattr__(self, "ssm_heads", self.n_heads)
+        if self.n_experts:
+            if self.n_experts % self.n_group or self.topk_group > self.n_group:
+                raise ValueError(f"{self.n_experts} experts cannot form "
+                                 f"{self.n_group} groups of which "
+                                 f"{self.topk_group} are picked")
+            if not 0 <= self.first_expert < self.first_expert + \
+                    self.held_experts <= self.n_experts:
+                raise ValueError(f"held experts [{self.first_expert}, "
+                                 f"+{self.held_experts}) lie outside the "
+                                 f"{self.n_experts} the router scores")
 
     # -- derived ----------------------------------------------------------------
     @property
@@ -74,6 +103,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.n_experts_held or self.n_experts
 
     @property
     def rwkv_heads(self) -> int:
@@ -133,7 +167,7 @@ def _count_params(cfg: ModelConfig, active_only: bool) -> int:
     total += dense_layers * _ffn_params(d, cfg.d_ff)
     if cfg.is_moe:
         router = d * cfg.n_experts
-        experts = cfg.n_experts * _ffn_params(d, cfg.moe_d_ff)
+        experts = cfg.held_experts * _ffn_params(d, cfg.moe_d_ff)
         shared = cfg.n_shared_experts * _ffn_params(d, cfg.moe_d_ff)
         if active_only:
             experts = cfg.top_k * _ffn_params(d, cfg.moe_d_ff)

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from repro.distributed.sharding import constrain
 from repro.kernels.attention import attention as attn_op
 from repro.kernels.attention.ref import NEG_INF
-from repro.models.common import KernelOptions, apply_rope, dense_init, rope, rms_norm
+from repro.models.common import (KernelOptions, apply_rope, dense_init, rope,
+                                 rms_norm, yarn_mscale)
 from repro.models.config import ModelConfig
 
 __all__ = ["init_mla", "mla_axes", "apply_mla", "init_mla_cache",
@@ -69,16 +70,39 @@ def _latents(p: dict, x: jnp.ndarray, cfg: ModelConfig, opts: KernelOptions,
     q_rope = q[..., cfg.nope_head_dim:]
     ckv = rms_norm(x @ p["w_dkv"].astype(cdt), p["kv_norm"], cfg.rms_eps, opts)
     k_rope = (x @ p["w_kr"].astype(cdt))[:, None]       # (B,1,S,rd)
-    cos, sin = rope(positions, cfg.rope_head_dim, cfg.rope_theta)
+    cos, sin = rope(positions, cfg.rope_head_dim, cfg.rope_theta, cfg=cfg)
+    if cfg.rope_interleave:
+        # rotate (even, odd) column pairs: gather them into halves first
+        # (hf deepseek's apply_rotary_pos_emb); q and k move alike, so the
+        # scores are those of the pairs
+        q_rope, k_rope = _deinterleave(q_rope), _deinterleave(k_rope)
     q_rope = apply_rope(q_rope, cos, sin)
     k_rope = apply_rope(k_rope, cos, sin)
     return q_nope, q_rope, ckv, k_rope
+
+
+def _deinterleave(x: jnp.ndarray) -> jnp.ndarray:
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1)
+
+
+def _score_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: ``(nope + rope) ** -0.5``, times YaRN's mscale
+    squared when the context is stretched (hf ``softmax_scale``)."""
+    scale = (cfg.nope_head_dim + cfg.rope_head_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
 
 
 def apply_mla(p: dict, x: jnp.ndarray, cfg: ModelConfig,
               opts: KernelOptions, *, window: int | None = None,
               positions: jnp.ndarray | None = None) -> jnp.ndarray:
     """Materialized train/prefill path. x (B,S,d) -> (B,S,d)."""
+    with jax.named_scope("iri.mla"):
+        return _apply_mla(p, x, cfg, opts, window, positions)
+
+
+def _apply_mla(p, x, cfg, opts, window, positions):
     b, s, d = x.shape
     h, nd, rd, dh = cfg.n_heads, cfg.nope_head_dim, cfg.rope_head_dim, cfg.d_head
     if positions is None:
@@ -93,7 +117,7 @@ def apply_mla(p: dict, x: jnp.ndarray, cfg: ModelConfig,
     k = constrain(k, ("batch", "heads", "seq", "head_dim"))
     v = constrain(v, ("batch", "heads", "seq", "head_dim"))
     out = attn_op(q, k, v, causal=True, window=window,
-                  scale=(nd + rd) ** -0.5,
+                  scale=_score_scale(cfg),
                   block_q=opts.block_q, block_kv=opts.block_kv,
                   impl=opts.impl_for("attention"))     # (B,H,S,dh)
     y = jnp.einsum("bhsk,hkd->bsd", out, p["wo"].astype(cdt))
@@ -129,10 +153,17 @@ def decode_mla(p: dict, cache: dict, x: jnp.ndarray, pos: jnp.ndarray,
     lockstep).  ``pos`` vector (B,): per-row contiguous slots for paged
     per-request caches — mirrors :func:`repro.models.attention.decode_gqa`.
     """
-    if jnp.ndim(pos) == 1:
-        return _decode_mla_rows(p, cache, x, pos, cfg, opts, window=window)
-    b = x.shape[0]
-    h, nd, rd, dh = cfg.n_heads, cfg.nope_head_dim, cfg.rope_head_dim, cfg.d_head
+    with jax.named_scope("iri.mla"):
+        if jnp.ndim(pos) == 1:
+            return _decode_mla_rows(p, cache, x, pos, cfg, opts,
+                                    window=window)
+        return _decode_mla_ring(p, cache, x, pos, cfg, opts, window=window)
+
+
+def _decode_mla_ring(p: dict, cache: dict, x: jnp.ndarray, pos: jnp.ndarray,
+                     cfg: ModelConfig, opts: KernelOptions, *,
+                     window: int | None = None) -> tuple[jnp.ndarray, dict]:
+    """Scalar-pos absorbed decode: one shared ring slot for all rows."""
     cdt = x.dtype
     q_nope, q_rope, ckv, k_rope = _latents(p, x, cfg, opts, pos[None])
     # Absorb W_uk into the query: q_eff (B,H,kv_lora).
@@ -150,7 +181,7 @@ def decode_mla(p: dict, cache: dict, x: jnp.ndarray, pos: jnp.ndarray,
     f32 = jnp.float32
     scores = (jnp.einsum("bhr,bwr->bhw", q_eff.astype(f32), cckv.astype(f32))
               + jnp.einsum("bhsk,bwk->bhw", q_rope.astype(f32),
-                           ckr.astype(f32))) * ((nd + rd) ** -0.5)
+                           ckr.astype(f32))) * _score_scale(cfg)
     valid = (spos >= 0) & (spos <= pos)
     if window is not None:
         valid &= spos > pos - window
@@ -167,7 +198,6 @@ def _decode_mla_rows(p: dict, cache: dict, x: jnp.ndarray, pos: jnp.ndarray,
                      cfg: ModelConfig, opts: KernelOptions, *,
                      window: int | None = None) -> tuple[jnp.ndarray, dict]:
     """Vector-pos absorbed decode: row b at position pos[b]."""
-    h, nd, rd = cfg.n_heads, cfg.nope_head_dim, cfg.rope_head_dim
     cdt = x.dtype
     q_nope, q_rope, ckv, k_rope = _latents(p, x, cfg, opts, pos[:, None, None])
     q_eff = jnp.einsum("bhsk,rhk->bhr", q_nope, p["w_uk"].astype(cdt))
@@ -184,7 +214,7 @@ def _decode_mla_rows(p: dict, cache: dict, x: jnp.ndarray, pos: jnp.ndarray,
     f32 = jnp.float32
     scores = (jnp.einsum("bhr,bwr->bhw", q_eff.astype(f32), cckv.astype(f32))
               + jnp.einsum("bhsk,bwk->bhw", q_rope.astype(f32),
-                           ckr.astype(f32))) * ((nd + rd) ** -0.5)
+                           ckr.astype(f32))) * _score_scale(cfg)
     valid = slots[None, :] <= pos[:, None]              # contiguous prefix
     if window is not None:
         valid &= slots[None, :] > pos[:, None] - window

@@ -13,9 +13,12 @@ Four dispatch implementations — einsum/gather/dense share
   No dispatch matmul FLOPs — HLO compute approaches the 6*N_active*D model
   FLOPs.  This is the specialized implementation the online policy should
   discover (§Perf hillclimb #3).
-* ``"dense"``   — every expert computes every token, gated mask combine.
-  Only sane for tiny smoke configs; doubles as the correctness oracle
-  (equals the others when capacity is unbounded).
+* ``"dense"``   — every held expert computes every token, gated mask
+  combine: no capacity, so no token is ever dropped and a token's output
+  does not depend on its batch-mates.  The serving path (at decode the
+  step's tokens are few, and each held expert's weights are read once
+  whatever the rows); doubles as the correctness oracle (equals the others
+  when capacity is unbounded).
 * ``"shard"``   — explicit expert parallelism via ``shard_map``: tokens are
   data-sharded and therefore *replicated across the model axis*, so each
   model shard locally selects + computes the entries routed to its own
@@ -29,6 +32,13 @@ Four dispatch implementations — einsum/gather/dense share
 Capacity factor and group size are further spec points; expert weights are
 sharded over the ``model`` axis (EP) and tokens over ``data``, so dispatch
 lowers to all-to-all style collectives under GSPMD.
+
+Every path routes through the gate of :func:`route`: softmax scores over
+all ``n_experts``, group-limited top-k, then renormalized or scaled.  A
+config may hold only some of the experts (``first_expert``,
+``n_experts_held``: one chip's share under expert parallelism): the router
+still scores all of them, and the layer returns the part of the result
+its held experts give, picks of the others counting as dropped.
 """
 from __future__ import annotations
 
@@ -45,7 +55,7 @@ from repro.distributed.sharding import constrain, current_mesh
 from repro.models.common import dense_init
 from repro.models.config import ModelConfig
 
-__all__ = ["init_moe", "moe_axes", "apply_moe", "assign_experts",
+__all__ = ["init_moe", "moe_axes", "apply_moe", "assign_experts", "route",
            "MoEOptions"]
 
 import dataclasses
@@ -63,10 +73,10 @@ class MoEOptions:
 
 
 def init_moe(key, cfg: ModelConfig) -> dict:
-    d, e, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    d, e, f = cfg.d_model, cfg.held_experts, cfg.moe_d_ff
     ks = jax.random.split(key, 5)
     p = {
-        "router": dense_init(ks[0], (d, e)),
+        "router": dense_init(ks[0], (d, cfg.n_experts)),
         "wg": dense_init(ks[1], (e, d, f), in_axis=1),
         "wu": dense_init(ks[2], (e, d, f), in_axis=1),
         "wd": dense_init(ks[3], (e, f, d), in_axis=1),
@@ -121,32 +131,86 @@ def _rank_positions(flat_e: jnp.ndarray, e: int, ranking: str) -> jnp.ndarray:
     return jnp.take_along_axis(pos_incl, flat_e[..., None], -1)[..., 0] - 1
 
 
+def route(logits: jnp.ndarray, top_k: int, *, n_group: int = 1,
+          topk_group: int = 1, norm_topk_prob: bool = True,
+          scale: float = 1.0):
+    """The gate (hf ``DeepseekV2MoEGate``, softmax scoring): softmax over
+    all experts; with ``topk_group < n_group`` only the ``topk_group``
+    groups whose best score is highest stay eligible
+    (``group_limited_greedy``); top-k of the eligible; the k weights are
+    renormalized to sum to one, or with ``norm_topk_prob`` off multiplied
+    by ``scale`` (``routed_scaling_factor``).
+
+    logits (T, E).  Returns ``(probs (T, E), w (T, k), idx (T, k))``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    scores = probs
+    if topk_group < n_group:
+        t, e = probs.shape
+        best = probs.reshape(t, n_group, e // n_group).max(-1)
+        _, groups = jax.lax.top_k(best, topk_group)
+        eligible = jax.nn.one_hot(groups, n_group, dtype=jnp.int32).sum(1)
+        scores = jnp.where(jnp.repeat(eligible, e // n_group, axis=1) > 0,
+                           probs, 0.0)
+    w, idx = jax.lax.top_k(scores, top_k)                 # (T, k)
+    if norm_topk_prob:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    else:
+        w = w * scale
+    return probs, w, idx
+
+
+def _gate(logits: jnp.ndarray, cfg: ModelConfig):
+    return route(logits, cfg.top_k, n_group=cfg.n_group,
+                 topk_group=cfg.topk_group,
+                 norm_topk_prob=cfg.norm_topk_prob,
+                 scale=cfg.routed_scaling_factor)
+
+
+def _held(idx: jnp.ndarray, first: int, held: int, n_experts: int):
+    """Picks as indices into the held experts, and which picks are held.
+    A pick of an expert held elsewhere gets the sentinel index ``held``;
+    with every expert held, ``idx`` itself and None."""
+    if held == n_experts:
+        return idx, None
+    mine = (idx >= first) & (idx < first + held)
+    return jnp.where(mine, idx - first, held), mine
+
+
 def assign_experts(logits: jnp.ndarray, top_k: int, n_experts: int,
                    capacity: int, group_size: int = 0,
-                   ranking: str = "cumsum"):
+                   ranking: str = "cumsum", cfg: ModelConfig | None = None):
     """Top-k routing with capacity-based dropping, shared by all impls.
 
-    logits (T, E) fp32.  Returns dict with (T, k) expert ids / combine
-    weights / position-in-expert / keep mask, plus aux-loss terms.
-    Positions are assigned in token-major order within each group.
+    logits (T, E) fp32.  Returns dict with (T, k) expert ids (into the
+    held experts) / combine weights / position-in-expert / keep mask,
+    plus aux-loss terms.  Positions are assigned in token-major order
+    within each group.  ``cfg`` gives the gate and the held experts;
+    without it, softmax top-k renormalized over all ``n_experts``.
     """
     t, e = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, idx = jax.lax.top_k(probs, top_k)                  # (T, k)
-    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)   # renormalize
+    if cfg is None:
+        probs, w, idx = route(logits, top_k)
+        first, held = 0, n_experts
+    else:
+        probs, w, idx = _gate(logits, cfg)
+        first, held = cfg.first_expert, cfg.held_experts
+    local, mine = _held(idx, first, held, e)
 
     g = group_size if group_size > 0 else t
     assert t % g == 0, (t, g)
     n_groups = t // g
-    flat_e = idx.reshape(n_groups, g * top_k)             # token-major slots
-    pos = _rank_positions(flat_e, e, ranking).reshape(t, top_k)
+    flat_e = local.reshape(n_groups, g * top_k)           # token-major slots
+    pos = _rank_positions(flat_e, e if mine is None else held + 1,
+                          ranking).reshape(t, top_k)
     keep = pos < capacity
+    if mine is not None:
+        keep &= mine
 
     # Switch-style load-balance aux loss terms.
     me = probs.mean(0)                                    # (E,)
     ce = jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32).mean(0)
     aux = e * jnp.sum(me * ce)
-    return {"idx": idx, "w": w.astype(jnp.float32), "pos": pos,
+    return {"idx": local, "w": w.astype(jnp.float32), "pos": pos,
             "keep": keep, "aux": aux}
 
 
@@ -185,9 +249,7 @@ def _shard_moe(p: dict, xf: jnp.ndarray, cfg: ModelConfig,
         t_loc = xl.shape[0]
         cap = _capacity(t_loc, k, e, opts.capacity_factor)
         logits = (xl @ router).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        w, idx = jax.lax.top_k(probs, k)
-        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+        probs, w, idx = _gate(logits, cfg)
 
         my = jax.lax.axis_index("model")
         base = my * e_loc
@@ -237,62 +299,88 @@ def _shard_moe(p: dict, xf: jnp.ndarray, cfg: ModelConfig,
     return fn(xf, router, *args)
 
 
+def _shared(p: dict, xf: jnp.ndarray, out: jnp.ndarray) -> jnp.ndarray:
+    """Add the shared experts' SwiGLU (every token, every chip)."""
+    if "shared" not in p:
+        return out
+    cdt = xf.dtype
+    with jax.named_scope("iri.moe.shared"):
+        sh = p["shared"]
+        hs = jax.nn.silu(xf @ sh["wg"].astype(cdt)) \
+            * (xf @ sh["wu"].astype(cdt))
+        hs = constrain(hs, ("batch", "ffn"))
+        return out + hs @ sh["wd"].astype(cdt)
+
+
 def apply_moe(p: dict, x: jnp.ndarray, cfg: ModelConfig,
-              opts: MoEOptions) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """x (B,S,d) -> (out (B,S,d), aux_loss scalar)."""
+              opts: MoEOptions, *, count_rows: jnp.ndarray | None = None):
+    """x (B,S,d) -> (out (B,S,d), aux_loss scalar).
+
+    ``count_rows`` (the ``dense`` path only), a bool mask over the B*S
+    tokens, adds an int32 pair: the held experts those tokens picked, and
+    the rows the held experts computed (every token's)."""
     b, s, d = x.shape
-    e, k = cfg.n_experts, cfg.top_k
+    e, k, eh = cfg.n_experts, cfg.top_k, cfg.held_experts
     cdt = x.dtype
     xf = x.reshape(b * s, d)
     t = b * s
+    if count_rows is not None and opts.impl != "dense":
+        raise ValueError(f"row counts are kept on the dense path, not "
+                         f"{opts.impl!r}")
 
     impl = opts.impl
     if impl == "shard":
         mesh = current_mesh()
         if (mesh is None or "model" not in mesh.shape
-                or e % mesh.shape["model"] != 0):
+                or e % mesh.shape["model"] != 0 or eh != e):
             impl = "gather"       # guarded degrade to the generic path
         else:
             out, aux = _shard_moe(p, xf, cfg, opts, mesh)
-            if "shared" in p:
-                sh = p["shared"]
-                hs = jax.nn.silu(xf @ sh["wg"].astype(cdt)) \
-                    * (xf @ sh["wu"].astype(cdt))
-                hs = constrain(hs, ("batch", "ffn"))
-                out = out + hs @ sh["wd"].astype(cdt)
+            out = _shared(p, xf, out)
             return out.reshape(b, s, d), aux * opts.aux_coef
     opts = dataclasses.replace(opts, impl=impl)
 
-    logits = (xf @ p["router"].astype(cdt)).astype(jnp.float32)
+    with jax.named_scope("iri.moe.route"):
+        logits = (xf @ p["router"].astype(cdt)).astype(jnp.float32)
 
     if opts.impl == "dense":
-        probs = jax.nn.softmax(logits, -1)
-        w, idx = jax.lax.top_k(probs, k)
-        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
-        full = jnp.zeros((t, e), jnp.float32).at[
-            jnp.arange(t)[:, None], idx].set(w)           # (T, E) gates
-        buf = jnp.broadcast_to(xf[None], (e, t, d))       # every expert, all T
-        h = _expert_ffn(buf, p, cdt)                      # (E, T, d)
-        out = jnp.einsum("te,etd->td", full.astype(cdt), h)
+        with jax.named_scope("iri.moe.route"):
+            probs, w, idx = _gate(logits, cfg)
+            local, mine = _held(idx, cfg.first_expert, eh, e)
+            width = eh if mine is None else eh + 1        # + sentinel
+            full = jnp.zeros((t, width), jnp.float32).at[
+                jnp.arange(t)[:, None], local].set(w)[:, :eh]   # (T, Eh)
+        with jax.named_scope("iri.moe.experts"):
+            buf = jnp.broadcast_to(xf[None], (eh, t, d))  # every held expert
+            h = _expert_ffn(buf, p, cdt)                  # (Eh, T, d)
+            out = jnp.einsum("te,etd->td", full.astype(cdt), h)
         me = probs.mean(0)
         ce = jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32).mean(0)
         aux = e * jnp.sum(me * ce)
+        if count_rows is not None:
+            picks = jnp.broadcast_to(count_rows.reshape(t, 1), idx.shape)
+            if mine is not None:
+                picks &= mine
+            counts = jnp.stack([jnp.sum(picks, dtype=jnp.int32),
+                                jnp.int32(t * eh)])
     else:
         g = opts.group_size if opts.group_size > 0 else t
         cap_t = g if opts.group_size > 0 else t
         cap = _capacity(cap_t, k, e, opts.capacity_factor)
-        a = assign_experts(logits, k, e, cap, opts.group_size, opts.ranking)
+        with jax.named_scope("iri.moe.route"):
+            a = assign_experts(logits, k, e, cap, opts.group_size,
+                               opts.ranking, cfg)
         aux = a["aux"]
         if opts.impl == "einsum":
             n_groups = t // g
-            oh_e = jax.nn.one_hot(a["idx"], e, dtype=cdt)       # (T,k,E)
+            oh_e = jax.nn.one_hot(a["idx"], eh, dtype=cdt)      # (T,k,Eh)
             oh_c = jax.nn.one_hot(a["pos"], cap, dtype=cdt)     # (T,k,C)
             keep = a["keep"].astype(cdt)[..., None, None]
             disp = (oh_e[..., :, None] * oh_c[..., None, :] * keep)  # (T,k,E,C)
-            disp = disp.sum(1).reshape(n_groups, g, e, cap)     # (G,g,E,C)
+            disp = disp.sum(1).reshape(n_groups, g, eh, cap)    # (G,g,E,C)
             comb = (oh_e[..., :, None] * oh_c[..., None, :] * keep
                     * a["w"].astype(cdt)[..., None, None]).sum(1)
-            comb = comb.reshape(n_groups, g, e, cap)
+            comb = comb.reshape(n_groups, g, eh, cap)
             xg = xf.reshape(n_groups, g, d)
             buf = jnp.einsum("gtec,gtd->gecd", disp, xg)
             # grouped: shard groups over data; global: shard capacity.
@@ -300,7 +388,8 @@ def apply_moe(p: dict, x: jnp.ndarray, cfg: ModelConfig,
                         if n_groups > 1
                         else (None, "experts", "expert_cap", None))
             buf = constrain(buf, cap_axes)
-            hbuf = _expert_ffn(buf, p, cdt)
+            with jax.named_scope("iri.moe.experts"):
+                hbuf = _expert_ffn(buf, p, cdt)
             hbuf = constrain(hbuf, cap_axes)
             out = jnp.einsum("gtec,gecd->gtd", comb, hbuf).reshape(t, d)
         elif opts.impl == "gather":
@@ -312,22 +401,23 @@ def apply_moe(p: dict, x: jnp.ndarray, cfg: ModelConfig,
             if opts.group_size > 0:
                 # group-local capacity -> global buffer offset per group
                 grp = flat_t // g
-                dest = (grp * e + flat_e) * cap + flat_pos
-                rows = (t // g) * e * cap
+                dest = (grp * eh + flat_e) * cap + flat_pos
+                rows = (t // g) * eh * cap
             else:
                 dest = flat_e * cap + flat_pos
-                rows = e * cap
+                rows = eh * cap
             dest = jnp.where(flat_keep, dest, rows)             # OOB -> drop
             buf = jnp.zeros((rows, d), cdt).at[dest].set(
                 xf[flat_t], mode="drop")
             if opts.group_size > 0:
-                buf = buf.reshape(t // g, e, cap, d)
+                buf = buf.reshape(t // g, eh, cap, d)
                 cap_axes = ("moe_groups", "experts", None, None)
             else:
-                buf = buf.reshape(e, cap, d)
+                buf = buf.reshape(eh, cap, d)
                 cap_axes = ("experts", "expert_cap", None)
             buf = constrain(buf, cap_axes)
-            hbuf = _expert_ffn(constrain(buf, cap_axes), p, cdt)
+            with jax.named_scope("iri.moe.experts"):
+                hbuf = _expert_ffn(constrain(buf, cap_axes), p, cdt)
             hbuf = constrain(hbuf, cap_axes).reshape(rows, d)
             gathered = jnp.take(hbuf, jnp.where(flat_keep, dest, 0), axis=0)
             gathered = gathered * (flat_w.astype(cdt)
@@ -336,10 +426,7 @@ def apply_moe(p: dict, x: jnp.ndarray, cfg: ModelConfig,
         else:
             raise ValueError(f"unknown moe impl {opts.impl!r}")
 
-    if "shared" in p:
-        sh = p["shared"]
-        hs = jax.nn.silu(xf @ sh["wg"].astype(cdt)) * (xf @ sh["wu"].astype(cdt))
-        hs = constrain(hs, ("batch", "ffn"))
-        out = out + hs @ sh["wd"].astype(cdt)
-
-    return out.reshape(b, s, d), aux * opts.aux_coef
+    out = _shared(p, xf, out).reshape(b, s, d)
+    if count_rows is not None:
+        return out, aux * opts.aux_coef, counts
+    return out, aux * opts.aux_coef

@@ -309,8 +309,13 @@ def cache_axes(cfg: ModelConfig) -> dict:
 
 
 def _layer_decode(lp: dict, lc: dict, x: jnp.ndarray, pos: jnp.ndarray,
-                  cfg: ModelConfig, opts: RunOptions, moe: bool):
+                  cfg: ModelConfig, opts: RunOptions, moe: bool,
+                  count_rows: jnp.ndarray | None = None):
+    """One layer's decode step -> (x, cache, counts): ``counts`` is the MoE
+    layer's int32 (held experts the ``count_rows`` picked, rows they
+    computed) when ``count_rows`` is given, else zeros."""
     ko = opts.kernels
+    counts = jnp.zeros((2,), jnp.int32)
     xin = rms_norm(x, lp["norm1"], cfg.rms_eps, ko)
     if cfg.mixer == "rwkv6":
         h, lc = rwkv_mod.decode_rwkv6(lp["mixer"], lc, xin, pos, cfg, ko)
@@ -336,19 +341,26 @@ def _layer_decode(lp: dict, lc: dict, x: jnp.ndarray, pos: jnp.ndarray,
         f = rwkv_mod.apply_rwkv6_channel_mix(lp["mixer"], xin2, cfg,
                                              x_prev=x_prev)
         lc = dict(lc, x_cm=xin2[:, 0].astype(lc["x_cm"].dtype))
+    elif moe and count_rows is not None:
+        f, _, counts = moe_mod.apply_moe(lp["moe"], xin2, cfg, opts.moe,
+                                         count_rows=count_rows)
     elif moe:
         f, _ = moe_mod.apply_moe(lp["moe"], xin2, cfg, opts.moe)
     else:
         ff = lp["ffn"]
         f = swiglu(xin2, ff["wg"].astype(xin2.dtype),
                    ff["wu"].astype(xin2.dtype), ff["wd"].astype(xin2.dtype))
-    return x + f, lc
+    return x + f, lc, counts
 
 
 def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
                 pos: jnp.ndarray, cfg: ModelConfig,
-                opts: RunOptions) -> tuple[jnp.ndarray, dict]:
-    """One decode step. tokens (B,) int32, pos scalar -> (logits (B,V), cache)."""
+                opts: RunOptions, count_rows: jnp.ndarray | None = None):
+    """One decode step. tokens (B,) int32, pos scalar -> (logits (B,V), cache).
+
+    With ``count_rows``, a (B,) bool mask (MoE on the ``dense`` path), a
+    third output, int32 ``(2,)`` summed over the MoE layers: the held
+    experts those rows picked, and the rows the held experts computed."""
     cdt = jnp.dtype(cfg.compute_dtype)
     x = params["embed"].astype(cdt)[tokens][:, None]      # (B,1,d)
     x = constrain(x, ("batch", None, None))
@@ -364,31 +376,42 @@ def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
 
     dense_cache, moe_cache = split_cache(cache)
     new_caches = []
+    counts = jnp.zeros((2,), jnp.int32)
 
     def run(stacked, lcache, moe):
-        def scan_fn(xx, pc):
+        # the carry holds the running counts only where they are asked
+        # for and counted: every other step keeps its program
+        count = count_rows is not None and moe
+
+        def scan_fn(carry, pc):
             lp, lcc = pc
-            xx, lcc = _layer_decode(lp, lcc, xx, pos, cfg, opts, moe)
-            return xx, lcc
+            xx, cc = carry if count else (carry, None)
+            xx, lcc, c = _layer_decode(lp, lcc, xx, pos, cfg, opts, moe,
+                                       count_rows if count else None)
+            return ((xx, cc + c) if count else xx), lcc
+        carry = (x_cur, counts) if count else x_cur
         if opts.scan_layers:
-            return jax.lax.scan(scan_fn, x_cur, (stacked, lcache))
-        xx = x_cur
-        outs = []
-        n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
-        for i in range(n):
-            lp = jax.tree_util.tree_map(lambda a: a[i], stacked)
-            lcc = jax.tree_util.tree_map(lambda a: a[i], lcache)
-            xx, lcc = _layer_decode(lp, lcc, xx, pos, cfg, opts, moe)
-            outs.append(lcc)
-        stacked_out = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *outs)
-        return xx, stacked_out
+            carry, stacked_out = jax.lax.scan(scan_fn, carry,
+                                              (stacked, lcache))
+        else:
+            outs = []
+            n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+            for i in range(n):
+                lp = jax.tree_util.tree_map(lambda a: a[i], stacked)
+                lcc = jax.tree_util.tree_map(lambda a: a[i], lcache)
+                carry, lcc = scan_fn(carry, (lp, lcc))
+                outs.append(lcc)
+            stacked_out = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls),
+                                                 *outs)
+        return (carry if count else (carry, counts)), stacked_out
 
     x_cur = x
     if n_dense:
-        x_cur, dc = run(params["dense_layers"], dense_cache, moe=False)
+        (x_cur, counts), dc = run(params["dense_layers"], dense_cache,
+                                  moe=False)
         new_caches.append(dc)
     if n_moe:
-        x_cur, mc = run(params["moe_layers"], moe_cache, moe=True)
+        (x_cur, counts), mc = run(params["moe_layers"], moe_cache, moe=True)
         new_caches.append(mc)
     if len(new_caches) == 2:
         new_cache = jax.tree_util.tree_map(
@@ -399,6 +422,8 @@ def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
     xf = rms_norm(x_cur, params["final_norm"], cfg.rms_eps, opts.kernels)
     head = lm_head_weight(params, cfg)
     logits = (xf[:, 0] @ head).astype(jnp.float32)
+    if count_rows is not None:
+        return logits[:, : cfg.vocab_size], new_cache, counts
     return logits[:, : cfg.vocab_size], new_cache
 
 
@@ -426,14 +451,15 @@ def _select_rows(cfg: ModelConfig, active: jnp.ndarray, new_cache: dict,
 
 def prefill_chunk(params: dict, cache: dict, tokens: jnp.ndarray,
                   pos: jnp.ndarray, n_new: jnp.ndarray, cfg: ModelConfig,
-                  opts: RunOptions) -> tuple[jnp.ndarray, dict]:
+                  opts: RunOptions, counters: bool = False):
     """Chunked prefill: consume up to C prompt tokens per row.
 
     ``tokens (B,C)`` int32 (pad with any valid id), ``pos (B,)`` per-row
     start positions, ``n_new (B,)`` valid token counts (<= C; rows may
     differ — a short row goes inactive once its tokens are consumed).
     Returns ``(logits (B,V) at each row's last consumed token, cache)``;
-    rows with ``n_new == 0`` get zero logits.
+    rows with ``n_new == 0`` get zero logits.  ``counters``: as
+    :func:`decode_step`'s, over the chunk's consumed tokens.
 
     Implemented as a ``lax.scan`` of single-token vector-pos decode
     steps with per-row masking — one compiled program per (bucket, C),
@@ -444,15 +470,20 @@ def prefill_chunk(params: dict, cache: dict, tokens: jnp.ndarray,
     b, c = tokens.shape
 
     def step(carry, xs):
-        cache_c, logits_c = carry
+        cache_c, logits_c, *counts_c = carry
         tok_t, t = xs
-        lg, stepped = decode_step(params, cache_c, tok_t, pos + t, cfg, opts)
+        lg, stepped, *counts = decode_step(
+            params, cache_c, tok_t, pos + t, cfg, opts,
+            (t < n_new) if counters else None)
         cache_c = _select_rows(cfg, t < n_new, stepped, cache_c)
         logits_c = jnp.where((t == n_new - 1)[:, None], lg, logits_c)
-        return (cache_c, logits_c), None
+        return (cache_c, logits_c,
+                *[a + b for a, b in zip(counts_c, counts)]), None
 
     logits0 = jnp.zeros((b, cfg.vocab_size), jnp.float32)
-    (cache, logits), _ = jax.lax.scan(
-        step, (cache, logits0),
+    counts0 = [jnp.zeros((2,), jnp.int32)] if counters else []
+    out, _ = jax.lax.scan(
+        step, (cache, logits0, *counts0),
         (tokens.T, jnp.arange(c, dtype=jnp.int32)))
-    return logits, cache
+    cache, logits, *counts = out
+    return (logits, cache, *counts)

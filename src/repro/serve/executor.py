@@ -66,6 +66,20 @@ def _argmax_sample(logits_row: np.ndarray) -> int:
     return int(np.argmax(logits_row))
 
 
+def _fetch(logits, counts: list, args: dict) -> np.ndarray:
+    """The step's logits on the host.  A step that also returned counters
+    (MoE: ``expert_rows_routed``, ``expert_rows_computed``) has them
+    fetched in the same transfer and recorded in ``args``, the
+    ``serve.sample`` span's."""
+    if not counts:
+        return np.asarray(logits)
+    import jax
+
+    logits, got = jax.device_get((logits, counts[0]))
+    args.update({name: int(v) for name, v in got.items()})
+    return np.asarray(logits)
+
+
 class PrefillExecutor:
     """Chunked-prefill steps: ``tokens (B, C)`` through the serve handler.
 
@@ -98,11 +112,11 @@ class PrefillExecutor:
         rids = [req.rid for req in reqs]
         cache, lengths = o.kv.materialize(rids, b)
         with telemetry.span("serve.dispatch"):
-            logits, new_cache = o.handler(
+            logits, new_cache, *counts = o.handler(
                 o.params, cache, o.put(tokens), o.put(lengths), o.put(n_new))
         o.kv.harvest(rids, new_cache, n_new[: len(reqs)])
-        with telemetry.span("serve.sample"):
-            logits = np.asarray(logits)
+        with telemetry.span("serve.sample") as args:
+            logits = _fetch(logits, counts, args)
             produced = []
             for i, req in enumerate(reqs):
                 req.prompt_consumed += int(n_new[i])
@@ -132,13 +146,13 @@ class DecodeExecutor:
             tokens[i] = row.out[-1] if row.out else row.prompt[-1]
         rids = [req.rid for req in reqs]
         cache, lengths = o.kv.materialize(rids, b)
-        ones = np.ones((b,), np.int32)
+        real = (np.arange(b) < len(reqs)).astype(np.int32)
         with telemetry.span("serve.dispatch"):
-            logits, new_cache = o.handler(
-                o.params, cache, o.put(tokens), o.put(lengths), o.put(ones))
+            logits, new_cache, *counts = o.handler(
+                o.params, cache, o.put(tokens), o.put(lengths), o.put(real))
         o.kv.harvest(rids, new_cache, [1] * len(reqs))
-        with telemetry.span("serve.sample"):
-            logits = np.asarray(logits)
+        with telemetry.span("serve.sample") as args:
+            logits = _fetch(logits, counts, args)
             for i, req in enumerate(reqs):
                 o.take(req, logits[i])
         return [1] * len(reqs)

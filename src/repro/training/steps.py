@@ -89,8 +89,13 @@ def run_options_from_spec(spec: SpecCtx, cfg: ModelConfig, *,
                           scan_layers: bool = True,
                           window: int | None = None,
                           for_decode: bool = False,
-                          differentiable: bool = False) -> RunOptions:
-    """Declare the model-level spec points and bundle the chosen constants."""
+                          differentiable: bool = False,
+                          dropless: bool = False) -> RunOptions:
+    """Declare the model-level spec points and bundle the chosen constants.
+
+    ``dropless``: MoE takes the ``dense`` path, which drops no token, and
+    declares no MoE point (capacity and grouping change which tokens an
+    expert sees, so they change results)."""
     # Implementation choice per kernel family the step exercises: the
     # candidate set is the registry entries *available on this host*, so the
     # policy only ever explores implementations that can run here; a choice
@@ -126,7 +131,9 @@ def run_options_from_spec(spec: SpecCtx, cfg: ModelConfig, *,
                             guarded=False)
                   if (cfg.window or window) else "full"),
     )
-    if cfg.is_moe:
+    if cfg.is_moe and dropless:
+        moe = MoEOptions(impl="dense")
+    elif cfg.is_moe:
         moe = MoEOptions(
             impl=spec.enum("moe_impl", "einsum",
                            ("einsum", "gather", "shard"), guarded=False),
@@ -393,14 +400,20 @@ def make_serve_builder(
 
     ``pos (B,)`` is each row's write position (contiguous per-request
     cache semantics — the paged KV manager's materialized lengths);
-    ``n_new (B,)`` the valid token count per row (prefill only; the
-    decode trace ignores it).  Returns ``(logits (B, V), new cache)``.
+    ``n_new (B,)`` the valid token count per row (at decode 1 for a real
+    row and 0 for padding, read only by the MoE counters).  Returns
+    ``(logits (B, V), new cache)``, and for MoE configs a third output:
+    ``{"expert_rows_routed", "expert_rows_computed"}``, int32 scalars
+    summed over the MoE layers (and a prefill chunk's tokens): the held
+    experts the valid tokens picked, and the rows the held experts
+    computed, padding included.  MoE runs dropless: no spec point changes
+    what is served.
     """
 
     def builder(spec: SpecCtx) -> Callable:
         opts = run_options_from_spec(spec, cfg, kernel_impl=kernel_impl,
                                      scan_layers=scan_layers, window=window,
-                                     for_decode=True)
+                                     for_decode=True, dropless=True)
         opts = RunOptions(**{**opts.__dict__, "decode_cache_dtype": spec.enum(
             "cache_dtype", "bfloat16", ("bfloat16", "float32"),
             guarded=False)})
@@ -415,10 +428,17 @@ def make_serve_builder(
                 params = _constrain_tree(params, model.param_axes(cfg))
                 cache = _constrain_tree(cache, model.cache_axes(cfg))
                 if tokens.ndim == 2:
-                    return model.prefill_chunk(params, cache, tokens, pos,
-                                               n_new, cfg, opts)
-                return model.decode_step(params, cache, tokens, pos, cfg,
-                                         opts)
+                    out = model.prefill_chunk(params, cache, tokens, pos,
+                                              n_new, cfg, opts, cfg.is_moe)
+                else:
+                    out = model.decode_step(
+                        params, cache, tokens, pos, cfg, opts,
+                        (n_new > 0) if cfg.is_moe else None)
+                if not cfg.is_moe:
+                    return out
+                logits, new_cache, counts = out
+                return logits, new_cache, {"expert_rows_routed": counts[0],
+                                           "expert_rows_computed": counts[1]}
 
         return serve_step
 

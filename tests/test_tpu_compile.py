@@ -10,7 +10,9 @@ described the tests skip.
 Widths: qwen3-0.6b attention (16 q heads / 8 kv heads, head dim 128) at
 decode and prefill, the d_model-1024 rmsnorm, 128-tiles for the matmul,
 rwkv6-1.6b's head size 64 for the linear-attention recurrence; and the
-whole qwen3-0.6b serve step at published width, decode and prefill.
+whole serve step at published width, decode and prefill: qwen3-0.6b, and
+deepseek-v2-236b-ep16 (one chip's share of DeepSeek-V2 under expert
+parallelism) at the batch and cache length its benchmark cell serves.
 """
 import functools
 
@@ -129,6 +131,49 @@ def test_serve_step_compiles_at_published_width(one_chip, monkeypatch, phase):
         params, cache, tokens, rows, rows).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert registry.default_registry.fallback_counts == before
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_deepseek_v2_serve_step_fits_one_chip(one_chip, monkeypatch, phase):
+    """The deepseek-v2-236b-ep16 serve step (the dense layer and 5 MoE
+    layers with 10 of 160 experts each, 128-head MLA, bf16, vocab 102,400)
+    at batch 64 and max_len 1024: it compiles for one v5e, its weights,
+    cache and temporaries fit the chip's 16 GB, and the step carries the
+    ``iri.`` scopes a device trace can attribute its time by."""
+    from repro import compat, configs
+    from repro.core.specializer import specialize_builder
+    from repro.models import transformer as model
+    from repro.models.transformer import RunOptions
+    from repro.training import make_serve_builder
+
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(compat, "on_cpu", lambda: False)
+    cfg = configs.get_config("deepseek-v2-236b-ep16")
+    b, max_len = 64, 1024
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: jax.tree.map(lambda a: a.astype(cfg.compute_dtype),
+                               model.init_params(k, cfg)),
+        jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(
+        cfg, b, max_len, RunOptions(decode_cache_dtype="bfloat16"))))
+    tokens = on_chip(jax.ShapeDtypeStruct(
+        (b, 16) if phase == "prefill" else (b,), I32))
+    rows = on_chip(jax.ShapeDtypeStruct((b,), I32))
+    fn = specialize_builder(make_serve_builder(cfg),
+                            {"rmsnorm_impl": "pallas_tpu"}).fn
+    lowered = jax.jit(fn, donate_argnums=1).lower(params, cache, tokens,
+                                                  rows, rows)
+    text = lowered.as_text(debug_info=True)
+    for scope in ("iri.mla", "iri.moe.route", "iri.moe.experts",
+                  "iri.moe.shared"):
+        assert scope in text, scope
+    mem = lowered.compile().memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
 @pytest.mark.parametrize("program", ["gather", "scatter"])
