@@ -12,11 +12,12 @@ import jax.numpy as jnp
 from repro.distributed.sharding import constrain
 from repro.kernels.attention import attention as attn_op
 from repro.kernels.attention.ref import NEG_INF
-from repro.models.common import KernelOptions, apply_rope, dense_init, rope, rms_norm
+from repro.models.common import (KernelOptions, apply_rope, chunk_slots,
+                                 chunk_visible, dense_init, rope, rms_norm)
 from repro.models.config import ModelConfig
 
 __all__ = ["init_gqa", "gqa_axes", "apply_gqa", "init_gqa_cache",
-           "gqa_cache_axes", "decode_gqa"]
+           "gqa_cache_axes", "decode_gqa", "prefill_gqa"]
 
 
 def init_gqa(key, cfg: ModelConfig) -> dict:
@@ -177,5 +178,40 @@ def _decode_gqa_rows(p: dict, cache: dict, x: jnp.ndarray, pos: jnp.ndarray,
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgw,bhwk->bhgk", probs, cv.astype(jnp.float32))
     out = out.reshape(b, h, 1, dh).astype(x.dtype)
+    y = jnp.einsum("bhsk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    return y, {"k": ck, "v": cv, "slot_pos": cache["slot_pos"]}
+
+
+def prefill_gqa(p: dict, cache: dict, x: jnp.ndarray, pos: jnp.ndarray,
+                n_new: jnp.ndarray, cfg: ModelConfig, opts: KernelOptions, *,
+                window: int | None = None) -> tuple[jnp.ndarray, dict]:
+    """A prompt chunk against per-row contiguous caches: x (B,C,d), row b's
+    token t at position ``pos[b] + t`` -> ((B,C,d), cache).
+
+    The chunk's keys and values land in slots ``pos[b] .. pos[b] +
+    n_new[b] - 1`` in one scatter per leaf (nothing past the cache, nothing
+    for a row with ``n_new == 0``); each query then scores every slot up to
+    its own position, in float32, as :func:`_decode_gqa_rows` scores one."""
+    b, c, _ = x.shape
+    h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    g = h // hk
+    positions = pos[:, None] + jnp.arange(c, dtype=jnp.int32)   # (B,C)
+    q, k, v = _project_qkv(p, x, cfg, opts, positions[:, None])
+    w = cache["k"].shape[2]
+    slots = chunk_slots(positions, n_new, w)
+    rows = jnp.arange(b)[:, None]
+    ck = cache["k"].at[rows, :, slots].set(
+        k.swapaxes(1, 2).astype(cache["k"].dtype), mode="drop")
+    cv = cache["v"].at[rows, :, slots].set(
+        v.swapaxes(1, 2).astype(cache["v"].dtype), mode="drop")
+
+    qg = q.reshape(b, hk, g, c, dh)
+    scores = jnp.einsum("bhgck,bhwk->bhgcw", qg.astype(jnp.float32),
+                        ck.astype(jnp.float32)) * (dh ** -0.5)
+    valid = chunk_visible(positions, w, window)                 # (B,C,w)
+    scores = jnp.where(valid[:, None, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhgcw,bhwk->bhgck", probs, cv.astype(jnp.float32))
+    out = out.reshape(b, h, c, dh).astype(x.dtype)
     y = jnp.einsum("bhsk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, {"k": ck, "v": cv, "slot_pos": cache["slot_pos"]}

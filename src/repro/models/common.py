@@ -12,7 +12,8 @@ from repro.distributed.sharding import constrain
 from repro.kernels import rmsnorm as rmsnorm_kernel
 
 __all__ = ["KernelOptions", "rms_norm", "rope", "apply_rope", "swiglu",
-           "dense_init", "embed_init", "yarn_mscale"]
+           "dense_init", "embed_init", "yarn_mscale", "chunk_slots",
+           "chunk_visible"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,6 +116,26 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray,
     cos = cos.astype(x1.dtype)
     sin = sin.astype(x1.dtype)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def chunk_slots(positions: jnp.ndarray, n_new: jnp.ndarray,
+                w: int) -> jnp.ndarray:
+    """Cache slots a prompt chunk writes: ``positions (B,C)`` where row b's
+    token t is among its first ``n_new[b]`` and lies inside the ``w``-slot
+    cache, else ``w``, which a ``mode="drop"`` scatter leaves unwritten."""
+    live = jnp.arange(positions.shape[1])[None] < n_new[:, None]
+    return jnp.where(live & (positions < w), positions, w)
+
+
+def chunk_visible(positions: jnp.ndarray, w: int,
+                  window: int | None) -> jnp.ndarray:
+    """(B,C,w) bool: the slots the query at ``positions[b, t]`` sees, every
+    one up to its own position (within ``window`` of it, if set)."""
+    at = jnp.arange(w, dtype=jnp.int32)[None, None, :]
+    valid = at <= positions[:, :, None]
+    if window is not None:
+        valid &= at > positions[:, :, None] - window
+    return valid
 
 
 def swiglu(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,

@@ -9,7 +9,8 @@ query, W_uv applied after attention), so the KV cache is ~1/``n_heads`` the
 size of a GQA cache.  This is the arch-level analogue of the paper's
 specialization story: the decode handler is a *structurally different,
 specialized implementation* of the same math, selected when the workload is
-autoregressive decode.
+autoregressive decode.  A served prompt chunk (:func:`prefill_mla`) takes
+the absorbed form too, against the same latent cache.
 """
 from __future__ import annotations
 
@@ -19,12 +20,13 @@ import jax.numpy as jnp
 from repro.distributed.sharding import constrain
 from repro.kernels.attention import attention as attn_op
 from repro.kernels.attention.ref import NEG_INF
-from repro.models.common import (KernelOptions, apply_rope, dense_init, rope,
-                                 rms_norm, yarn_mscale)
+from repro.models.common import (KernelOptions, apply_rope, chunk_slots,
+                                 chunk_visible, dense_init, rope, rms_norm,
+                                 yarn_mscale)
 from repro.models.config import ModelConfig
 
 __all__ = ["init_mla", "mla_axes", "apply_mla", "init_mla_cache",
-           "mla_cache_axes", "decode_mla"]
+           "mla_cache_axes", "decode_mla", "prefill_mla"]
 
 
 def init_mla(key, cfg: ModelConfig) -> dict:
@@ -224,4 +226,46 @@ def _decode_mla_rows(p: dict, cache: dict, x: jnp.ndarray, pos: jnp.ndarray,
     out = jnp.einsum("bhr,rhk->bhk", o_latent.astype(cdt),
                      p["w_uv"].astype(cdt))
     y = jnp.einsum("bhk,hkd->bd", out, p["wo"].astype(cdt))[:, None]
+    return y, {"ckv": cckv, "k_rope": ckr, "slot_pos": cache["slot_pos"]}
+
+
+def prefill_mla(p: dict, cache: dict, x: jnp.ndarray, pos: jnp.ndarray,
+                n_new: jnp.ndarray, cfg: ModelConfig, opts: KernelOptions, *,
+                window: int | None = None) -> tuple[jnp.ndarray, dict]:
+    """A prompt chunk in the absorbed form: x (B,C,d), row b's token t at
+    position ``pos[b] + t`` -> ((B,C,d), cache).  The chunk's latents land
+    in slots ``pos[b] .. pos[b] + n_new[b] - 1`` (as
+    :func:`repro.models.attention.prefill_gqa` writes keys), and each query
+    scores every slot up to its own position, as :func:`_decode_mla_rows`
+    scores one."""
+    with jax.named_scope("iri.mla"):
+        return _prefill_mla(p, cache, x, pos, n_new, cfg, opts, window)
+
+
+def _prefill_mla(p, cache, x, pos, n_new, cfg, opts, window):
+    cdt = x.dtype
+    b, c, _ = x.shape
+    positions = pos[:, None] + jnp.arange(c, dtype=jnp.int32)   # (B,C)
+    q_nope, q_rope, ckv, k_rope = _latents(p, x, cfg, opts, positions[:, None])
+    q_eff = jnp.einsum("bhck,rhk->bhcr", q_nope, p["w_uk"].astype(cdt))
+
+    w = cache["ckv"].shape[1]
+    slots = chunk_slots(positions, n_new, w)
+    rows = jnp.arange(b)[:, None]
+    cckv = cache["ckv"].at[rows, slots].set(
+        ckv.astype(cache["ckv"].dtype), mode="drop")
+    ckr = cache["k_rope"].at[rows, slots].set(
+        k_rope[:, 0].astype(cache["k_rope"].dtype), mode="drop")
+
+    f32 = jnp.float32
+    scores = (jnp.einsum("bhcr,bwr->bhcw", q_eff.astype(f32), cckv.astype(f32))
+              + jnp.einsum("bhck,bwk->bhcw", q_rope.astype(f32),
+                           ckr.astype(f32))) * _score_scale(cfg)
+    valid = chunk_visible(positions, w, window)                 # (B,C,w)
+    scores = jnp.where(valid[:, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    o_latent = jnp.einsum("bhcw,bwr->bhcr", probs, cckv.astype(f32))
+    out = jnp.einsum("bhcr,rhk->bhck", o_latent.astype(cdt),
+                     p["w_uv"].astype(cdt))
+    y = jnp.einsum("bhck,hkd->bcd", out, p["wo"].astype(cdt))
     return y, {"ckv": cckv, "k_rope": ckr, "slot_pos": cache["slot_pos"]}

@@ -11,7 +11,8 @@ evaluation (chunk <= 64).
 
 Train path uses the chunked linear-attention engine (``chunk_scan``) —
 sub-quadratic, loop-free; decode advances the (H, hs, hs) state directly, so
-``long_500k`` decode is O(1) in sequence length.
+``long_500k`` decode is O(1) in sequence length; a served prompt chunk
+(:func:`prefill_rwkv6`) runs the chunked form from the cached state.
 """
 from __future__ import annotations
 
@@ -20,12 +21,14 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
 from repro.kernels.linear_attention import linear_attention
-from repro.models.chunk_scan import step_linear_attention
+from repro.models.chunk_scan import (chunked_linear_attention,
+                                     step_linear_attention)
 from repro.models.common import KernelOptions, dense_init, rms_norm
 from repro.models.config import ModelConfig
 
 __all__ = ["init_rwkv6", "rwkv6_axes", "apply_rwkv6", "init_rwkv6_cache",
-           "rwkv6_cache_axes", "decode_rwkv6", "LOG_W_MIN"]
+           "rwkv6_cache_axes", "decode_rwkv6", "prefill_rwkv6",
+           "prefill_rwkv6_channel_mix", "LOG_W_MIN"]
 
 LOG_W_MIN = -1.0        # per-step log-decay clamp (chunk-safety, see module doc)
 _DECAY_LORA = 64
@@ -186,3 +189,67 @@ def decode_rwkv6(p: dict, cache: dict, x: jnp.ndarray, pos, cfg: ModelConfig,
     y = (o @ p["wo"].astype(x.dtype))[:, None]
     return y, {"state": new_state, "x_tm": xt.astype(cache["x_tm"].dtype),
                "x_cm": cache["x_cm"]}
+
+
+def _shifted(last: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """Token shift of a chunk x (B,C,d) after the cached input ``last``
+    (B,d): each token's predecessor, rounded through the cache's dtype as
+    the one-token step reads it back."""
+    prev = x[:, :-1].astype(last.dtype)
+    return jnp.concatenate([last[:, None], prev], 1).astype(x.dtype)
+
+
+def _last(x: jnp.ndarray, n_new: jnp.ndarray,
+          old: jnp.ndarray) -> jnp.ndarray:
+    """Row b's input at token ``n_new[b] - 1``, in ``old``'s dtype; ``old``
+    where the row took no token."""
+    at = jnp.clip(n_new - 1, 0)[:, None, None]
+    last = jnp.take_along_axis(x, at, axis=1)[:, 0].astype(old.dtype)
+    return jnp.where((n_new > 0)[:, None], last, old)
+
+
+def _chunk_len(c: int, most: int) -> int:
+    """The largest divisor of the chunk length ``c`` that is at most
+    ``most`` (the linear-attention chunk, bounded for float32)."""
+    return max(n for n in range(1, min(c, most) + 1) if c % n == 0)
+
+
+def prefill_rwkv6(p: dict, cache: dict, x: jnp.ndarray, n_new: jnp.ndarray,
+                  cfg: ModelConfig, opts: KernelOptions
+                  ) -> tuple[jnp.ndarray, dict]:
+    """Time-mix over a prompt chunk x (B,C,d) from the cached state ->
+    ((B,C,d), cache).  The wkv recurrence runs in its chunked form from
+    the cached state; the state and ``x_tm`` advance through row b's first
+    ``n_new[b]`` tokens only (later tokens add no key and decay nothing)."""
+    b, c, d = x.shape
+    h, hs = cfg.rwkv_heads, cfg.rwkv_head_size
+    r, k, v, g, lw = _time_mix_inputs(p, x, _shifted(cache["x_tm"], x), cfg)
+    live = (jnp.arange(c)[None] < n_new[:, None])[..., None]   # (B,C,1)
+    k = jnp.where(live, k, 0)
+    lw = jnp.where(live, lw, 0.0)
+    chunk = _chunk_len(c, opts.chunk_len)
+
+    def one(q_, k_, v_, w_, s_, u_):
+        return chunked_linear_attention(q_, k_, v_, w_, bonus=u_,
+                                        chunk=chunk, init_state=s_,
+                                        return_state=True)
+
+    fn = jax.vmap(jax.vmap(one, in_axes=(1, 1, 1, 1, 0, 0)),
+                  in_axes=(0, 0, 0, 0, 0, None))
+    o, state = fn(_heads(r, h, hs), _heads(k, h, hs), _heads(v, h, hs),
+                  _heads(lw, h, hs), cache["state"], p["u"])
+    o = o.transpose(0, 2, 1, 3)                        # (B,C,H,hs)
+    o = rms_norm(o, jnp.ones((hs,), jnp.float32), cfg.rms_eps, opts)
+    o = o.reshape(b, c, d) * p["ln_x"].astype(x.dtype) * g
+    y = o @ p["wo"].astype(x.dtype)
+    return y, {"state": state, "x_tm": _last(x, n_new, cache["x_tm"]),
+               "x_cm": cache["x_cm"]}
+
+
+def prefill_rwkv6_channel_mix(p: dict, cache: dict, x: jnp.ndarray,
+                              n_new: jnp.ndarray, cfg: ModelConfig
+                              ) -> tuple[jnp.ndarray, dict]:
+    """Channel mix over a prompt chunk x (B,C,d) after the cached input;
+    ``x_cm`` advances to row b's token ``n_new[b] - 1``."""
+    f = apply_rwkv6_channel_mix(p, x, cfg, x_prev=_shifted(cache["x_cm"], x))
+    return f, dict(cache, x_cm=_last(x, n_new, cache["x_cm"]))

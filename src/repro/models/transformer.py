@@ -341,29 +341,35 @@ def _layer_decode(lp: dict, lc: dict, x: jnp.ndarray, pos: jnp.ndarray,
         f = rwkv_mod.apply_rwkv6_channel_mix(lp["mixer"], xin2, cfg,
                                              x_prev=x_prev)
         lc = dict(lc, x_cm=xin2[:, 0].astype(lc["x_cm"].dtype))
-    elif moe and count_rows is not None:
-        f, _, counts = moe_mod.apply_moe(lp["moe"], xin2, cfg, opts.moe,
-                                         count_rows=count_rows)
-    elif moe:
-        f, _ = moe_mod.apply_moe(lp["moe"], xin2, cfg, opts.moe)
     else:
-        ff = lp["ffn"]
-        f = swiglu(xin2, ff["wg"].astype(xin2.dtype),
-                   ff["wu"].astype(xin2.dtype), ff["wd"].astype(xin2.dtype))
+        f, counts = _cached_ffn(lp, xin2, cfg, opts, moe, count_rows, counts)
     return x + f, lc, counts
 
 
-def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
-                pos: jnp.ndarray, cfg: ModelConfig,
-                opts: RunOptions, count_rows: jnp.ndarray | None = None):
-    """One decode step. tokens (B,) int32, pos scalar -> (logits (B,V), cache).
+def _cached_ffn(lp: dict, x: jnp.ndarray, cfg: ModelConfig, opts: RunOptions,
+                moe: bool, count_rows: jnp.ndarray | None,
+                counts: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The FFN of a layer that serves from a cache (RWKV6's channel mix
+    aside) -> (out, counts): the MoE layer's when ``count_rows`` is given,
+    else ``counts`` as it came."""
+    if moe and count_rows is not None:
+        f, _, counts = moe_mod.apply_moe(lp["moe"], x, cfg, opts.moe,
+                                         count_rows=count_rows)
+    elif moe:
+        f, _ = moe_mod.apply_moe(lp["moe"], x, cfg, opts.moe)
+    else:
+        ff = lp["ffn"]
+        f = swiglu(x, ff["wg"].astype(x.dtype), ff["wu"].astype(x.dtype),
+                   ff["wd"].astype(x.dtype))
+    return f, counts
 
-    With ``count_rows``, a (B,) bool mask (MoE on the ``dense`` path), a
-    third output, int32 ``(2,)`` summed over the MoE layers: the held
-    experts those rows picked, and the rows the held experts computed."""
-    cdt = jnp.dtype(cfg.compute_dtype)
-    x = params["embed"].astype(cdt)[tokens][:, None]      # (B,1,d)
-    x = constrain(x, ("batch", None, None))
+
+def _run_layers(params: dict, cache: dict, x: jnp.ndarray, cfg: ModelConfig,
+                opts: RunOptions, layer: Callable, counting: bool):
+    """x through the dense, then the MoE, layer stack beside its stacked
+    cache -> (x, new cache, counts).  ``layer(lp, lc, x, moe, count)`` runs
+    one layer -> (x, lc, int32 (2,) counts); ``count`` is set on the MoE
+    layers when ``counting``, and their counts are summed."""
     n_moe = cfg.n_moe_layers
     n_dense = cfg.n_layers - n_moe
 
@@ -381,13 +387,12 @@ def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
     def run(stacked, lcache, moe):
         # the carry holds the running counts only where they are asked
         # for and counted: every other step keeps its program
-        count = count_rows is not None and moe
+        count = counting and moe
 
         def scan_fn(carry, pc):
             lp, lcc = pc
             xx, cc = carry if count else (carry, None)
-            xx, lcc, c = _layer_decode(lp, lcc, xx, pos, cfg, opts, moe,
-                                       count_rows if count else None)
+            xx, lcc, c = layer(lp, lcc, xx, moe, count)
             return ((xx, cc + c) if count else xx), lcc
         carry = (x_cur, counts) if count else x_cur
         if opts.scan_layers:
@@ -418,6 +423,26 @@ def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
             lambda a, b: jnp.concatenate([a, b], 0), *new_caches)
     else:
         new_cache = new_caches[0]
+    return x_cur, new_cache, counts
+
+
+def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
+                pos: jnp.ndarray, cfg: ModelConfig,
+                opts: RunOptions, count_rows: jnp.ndarray | None = None):
+    """One decode step. tokens (B,) int32, pos scalar -> (logits (B,V), cache).
+
+    With ``count_rows``, a (B,) bool mask (MoE on the ``dense`` path), a
+    third output, int32 ``(2,)`` summed over the MoE layers: the held
+    experts those rows picked, and the rows the held experts computed."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    x = params["embed"].astype(cdt)[tokens][:, None]      # (B,1,d)
+    x = constrain(x, ("batch", None, None))
+
+    def layer(lp, lc, xx, moe, count):
+        return _layer_decode(lp, lc, xx, pos, cfg, opts, moe,
+                             count_rows if count else None)
+    x_cur, new_cache, counts = _run_layers(params, cache, x, cfg, opts,
+                                           layer, count_rows is not None)
 
     xf = rms_norm(x_cur, params["final_norm"], cfg.rms_eps, opts.kernels)
     head = lm_head_weight(params, cfg)
@@ -449,24 +474,77 @@ def _select_rows(cfg: ModelConfig, active: jnp.ndarray, new_cache: dict,
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def prefill_chunk(params: dict, cache: dict, tokens: jnp.ndarray,
+def _layer_major(cfg: ModelConfig, opts: RunOptions) -> bool:
+    """Whether :func:`prefill_chunk` can push a chunk through each layer
+    at once: GQA, MLA and RWKV6 mixers have a chunk form, Hymba's SSM
+    heads do not; MoE only on the dropless ``dense`` path, since capacity
+    would make a chunk's drops differ from a token's."""
+    if cfg.mixer == "hymba":
+        return False
+    return not cfg.is_moe or opts.moe.impl == "dense"
+
+
+def _layer_chunk(lp: dict, lc: dict, x: jnp.ndarray, pos: jnp.ndarray,
+                 n_new: jnp.ndarray, cfg: ModelConfig, opts: RunOptions,
+                 moe: bool, count_rows: jnp.ndarray | None = None):
+    """One layer over a prompt chunk x (B,C,d) -> (x, cache, counts): the
+    chunk forms of :func:`_layer_decode`'s mixers, every other part over
+    the B*C rows at once."""
+    ko = opts.kernels
+    counts = jnp.zeros((2,), jnp.int32)
+    xin = rms_norm(x, lp["norm1"], cfg.rms_eps, ko)
+    if cfg.mixer == "rwkv6":
+        h, lc = rwkv_mod.prefill_rwkv6(lp["mixer"], lc, xin, n_new, cfg, ko)
+    elif cfg.attn_kind == "mla":
+        h, lc = mla_mod.prefill_mla(lp["mixer"], lc, xin, pos, n_new, cfg,
+                                    ko, window=opts.window)
+    else:
+        h, lc = attn_mod.prefill_gqa(lp["mixer"], lc, xin, pos, n_new, cfg,
+                                     ko, window=opts.window)
+    x = x + h
+    xin2 = rms_norm(x, lp["norm2"], cfg.rms_eps, ko)
+    if cfg.mixer == "rwkv6":
+        f, lc = rwkv_mod.prefill_rwkv6_channel_mix(lp["mixer"], lc, xin2,
+                                                   n_new, cfg)
+    else:
+        f, counts = _cached_ffn(lp, xin2, cfg, opts, moe, count_rows, counts)
+    return x + f, lc, counts
+
+
+def _prefill_layers(params: dict, cache: dict, tokens: jnp.ndarray,
+                    pos: jnp.ndarray, n_new: jnp.ndarray, cfg: ModelConfig,
+                    opts: RunOptions, counters: bool):
+    """Layer-major chunked prefill: the (B,C) chunk goes through the layer
+    stack once, as (B,C,d); logits are taken at each row's last token."""
+    c = tokens.shape[1]
+    cdt = jnp.dtype(cfg.compute_dtype)
+    x = params["embed"].astype(cdt)[tokens]               # (B,C,d)
+    x = constrain(x, ("batch", None, None))
+    live = jnp.arange(c)[None] < n_new[:, None]           # (B,C)
+
+    def layer(lp, lc, xx, moe, count):
+        return _layer_chunk(lp, lc, xx, pos, n_new, cfg, opts, moe,
+                            live if count else None)
+    x, new_cache, counts = _run_layers(params, cache, x, cfg, opts, layer,
+                                       counters)
+
+    at = jnp.clip(n_new - 1, 0)[:, None, None]
+    x_last = jnp.take_along_axis(x, at, axis=1)           # (B,1,d)
+    xf = rms_norm(x_last, params["final_norm"], cfg.rms_eps, opts.kernels)
+    head = lm_head_weight(params, cfg)
+    logits = (xf[:, 0] @ head).astype(jnp.float32)[:, : cfg.vocab_size]
+    logits = jnp.where((n_new > 0)[:, None], logits, 0.0)
+    return (logits, new_cache, counts) if counters else (logits, new_cache)
+
+
+def _prefill_scan(params: dict, cache: dict, tokens: jnp.ndarray,
                   pos: jnp.ndarray, n_new: jnp.ndarray, cfg: ModelConfig,
-                  opts: RunOptions, counters: bool = False):
-    """Chunked prefill: consume up to C prompt tokens per row.
-
-    ``tokens (B,C)`` int32 (pad with any valid id), ``pos (B,)`` per-row
-    start positions, ``n_new (B,)`` valid token counts (<= C; rows may
-    differ — a short row goes inactive once its tokens are consumed).
-    Returns ``(logits (B,V) at each row's last consumed token, cache)``;
-    rows with ``n_new == 0`` get zero logits.  ``counters``: as
-    :func:`decode_step`'s, over the chunk's consumed tokens.
-
-    Implemented as a ``lax.scan`` of single-token vector-pos decode
-    steps with per-row masking — one compiled program per (bucket, C),
-    correct for every mixer: attention writes land at per-row positions
-    (out-of-range rows write nothing), and recurrent state only advances
-    while a row is active (:func:`_select_rows`).
-    """
+                  opts: RunOptions, counters: bool):
+    """Token-major chunked prefill: a ``lax.scan`` of single-token
+    vector-pos decode steps with per-row masking, correct for every mixer:
+    attention writes land at per-row positions (out-of-range rows write
+    nothing), and recurrent state only advances while a row is active
+    (:func:`_select_rows`)."""
     b, c = tokens.shape
 
     def step(carry, xs):
@@ -487,3 +565,32 @@ def prefill_chunk(params: dict, cache: dict, tokens: jnp.ndarray,
         (tokens.T, jnp.arange(c, dtype=jnp.int32)))
     cache, logits, *counts = out
     return (logits, cache, *counts)
+
+
+def prefill_chunk(params: dict, cache: dict, tokens: jnp.ndarray,
+                  pos: jnp.ndarray, n_new: jnp.ndarray, cfg: ModelConfig,
+                  opts: RunOptions, counters: bool = False):
+    """Chunked prefill: consume up to C prompt tokens per row.
+
+    ``tokens (B,C)`` int32 (pad with any valid id), ``pos (B,)`` per-row
+    start positions, ``n_new (B,)`` valid token counts (<= C; rows may
+    differ — a short row goes inactive once its tokens are consumed).
+    Returns ``(logits (B,V) at each row's last consumed token, cache)``;
+    rows with ``n_new == 0`` get zero logits.  ``counters``: as
+    :func:`decode_step`'s, over the chunk's consumed tokens.
+
+    Two paths, one compiled program per (bucket, C) either way, chosen at
+    trace time from the layer type (:func:`_layer_major`):
+
+    * layer-major (GQA, MLA, RWKV6; MoE on the dropless ``dense`` path):
+      the chunk goes through each layer once, as (B,C,d) — weights applied
+      as matmuls over B*C rows, the chunk's keys/latents written in one
+      scatter per leaf, its C queries scored against the whole cache, the
+      wkv recurrence in its chunked form from the cached state;
+    * token-major (Hymba, and MoE on a capacity path, where a chunk's
+      drops would differ from a token's): :func:`_prefill_scan`, C
+      single-token decode steps — the reference the other is tested
+      against.
+    """
+    run = _prefill_layers if _layer_major(cfg, opts) else _prefill_scan
+    return run(params, cache, tokens, pos, n_new, cfg, opts, counters)
