@@ -104,7 +104,7 @@ from repro.core import (ChangeDetector, Controller, EWMA, ExhaustiveSweep,
                         IridescentRuntime, SafetyController, guards)
 from repro.models import transformer as model
 from repro.models.transformer import RunOptions
-from repro.training import make_decode_builder
+from repro.training import SERVE_SEARCH_POINTS, make_decode_builder
 
 
 def run_serve(steps: int = 120, arch: str = "qwen3-0.6b", batch: int = 4,
@@ -128,10 +128,9 @@ def run_serve(steps: int = 120, arch: str = "qwen3-0.6b", batch: int = 4,
     tokens = jnp.zeros((batch,), jnp.int32)
 
     space = handler.spec_space()
-    labels = ["cache_dtype", "rmsnorm_impl"] + (
-        ["chunk_len"] if cfg.mixer in ("rwkv6", "hymba") else [])
     controller = Controller(
-        handler, lambda: ExhaustiveSweep.from_space(space, labels),
+        handler, lambda: ExhaustiveSweep.from_space(space,
+                                                    SERVE_SEARCH_POINTS),
         dwell=dwell, change_detector=lambda: ChangeDetector(0.3),
         wait_compiles=False, prefetch=prefetch)
 
@@ -688,18 +687,13 @@ def run_disagg(d: int = 512, vocab: int = 32, bucket: int = 8,
     Both runs replay the same prompt-heavy open-loop schedule through the
     *same* machinery — :class:`~repro.serve.executor.PhasedExecutor`
     (chunked prefill interleaved with decode) over a
-    :class:`~repro.serve.kv.PagedKV` manager — and differ only in the two
-    things the tentpole claims matter:
-
-    * **context keying** — the disagg run dispatches through
-      ``(phase, bucket)`` contexts (``phase_context_fn``), so the
-      Controller settles prefill and decode on *different* ``tile``
-      configs; the baseline keys by bucket alone, so one config must
-      serve both phases and compromises one of them
-      (:func:`_disagg_builder` makes both compromises measurably bad),
-    * **KV geometry** — the disagg run stores per-request state in small
-      pages; the baseline uses the contiguous one-slab-per-request
-      layout (the shared-ring descendant).
+    :class:`~repro.serve.kv.PagedKV` manager at the same page size — and
+    differ only in **context keying**: the disagg run dispatches through
+    ``(phase, bucket)`` contexts (``phase_context_fn``), so the
+    Controller settles prefill and decode on *different* ``tile``
+    configs; the baseline keys by bucket alone, so one config must serve
+    both phases and compromises one of them (:func:`_disagg_builder`
+    makes both compromises measurably bad).
 
     The Controller metric is each context's own per-call latency (EWMA,
     as in :func:`run_mixed`) — interleaving makes wall-clock rate
@@ -817,8 +811,7 @@ def run_disagg(d: int = 512, vocab: int = 32, bucket: int = 8,
             wait_compiles=True, prefetch=0)
         kv = PagedKV(template, axes, max_len=max_len,
                      capacity_tokens=2 * bucket * max_len,
-                     page_size=page_size if disagg else max_len,
-                     layout="paged" if disagg else "contig")
+                     page_size=page_size)
         executor = PhasedExecutor(timed_handler, w, kv,
                                   prefill_chunk=chunk, vocab_size=vocab)
         metrics = ServeMetrics()
@@ -847,7 +840,6 @@ def run_disagg(d: int = 512, vocab: int = 32, bucket: int = 8,
             for key, cfg in best.items()}
         row = {
             "mode": "disagg" if disagg else "phase_blind",
-            "kv_layout": list(kv.active_geometry()),
             "wall_s": round(wall, 3),
             "offered": stats["queue"]["submitted"],
             "completed": serve["completed"],
@@ -863,7 +855,7 @@ def run_disagg(d: int = 512, vocab: int = 32, bucket: int = 8,
             "ttft_p50_ms": serve["ttft_p50_ms"],
             "phase_steps": dict(stats.get("phase_steps", {})),
             "contexts": contexts,
-            "kv_pools": kv.stats()["pools"],
+            "kv_pool": kv.stats(),
         }
         if disagg:
             pre = best.get(("prefill", bucket)) or {}
@@ -1138,10 +1130,13 @@ def run_tenants(tight_arch: str = "qwen3-0.6b",
     flooding the queue with long-budget work under an effectively
     infinite deadline.  Each tenant's traffic dispatches through its own
     ``(tenant, phase, bucket)`` contexts, so the shared runtime runs two
-    independent Controller searches over *different* spec spaces (the
-    attention tenant sweeps ``cache_dtype``/``rmsnorm_impl``; the rwkv
-    tenant sweeps its ``chunk_len``) — the settled configs are
-    structurally distinct, the first acceptance criterion.
+    independent Controller searches, each over ``SERVE_SEARCH_POINTS`` of
+    its own handler.  The settled configs are structurally distinct, the
+    first acceptance criterion: each handler declares its mixer's points
+    (``attention_impl`` for the attention tenant, ``chunk_len`` and
+    ``linear_attention_impl`` for the rwkv tenant), so the two tenants'
+    configs differ in their keys whichever ``rmsnorm_impl`` each settles
+    on.
 
     Isolation is a three-run makespan comparison on identical tight
     bursts (the loose flood arrives *before* the tight burst in both
@@ -1265,12 +1260,10 @@ def run_tenants(tight_arch: str = "qwen3-0.6b",
                                              prefill_chunk=chunk,
                                              vocab_size=cfg.vocab_size)
             space = handler.spec_space()
-            labels = (["chunk_len"] if cfg.mixer in ("rwkv6", "hymba")
-                      else ["cache_dtype", "rmsnorm_impl"])
             controller = Controller(
                 handler,
-                (lambda space=space, labels=labels:
-                 ExhaustiveSweep.from_space(space, labels)),
+                (lambda space=space:
+                 ExhaustiveSweep.from_space(space, SERVE_SEARCH_POINTS)),
                 metric=context_latency_rate, dwell=dwell,
                 change_detector=lambda: ChangeDetector(float("inf")),
                 wait_compiles=True, prefetch=0)

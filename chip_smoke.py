@@ -104,7 +104,7 @@ def engine_args(reduced: bool, cache_dir: str, **over):
              "--requests", "16", "--rate", "4", "--dwell", "2",
              "--shadow-frac", "1.0", "--canary-frac", "0.5",
              "--promote-after", "1", "--bucket-dwell", "4",
-             "--kv-dwell", "4", "--cache-dir", cache_dir]
+             "--cache-dir", cache_dir]
     for k, v in over.items():
         flags += [f"--{k.replace('_', '-')}", str(v)]
     return ap.parse_args(flags + (["--reduced"] if reduced else []))

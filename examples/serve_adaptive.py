@@ -13,10 +13,10 @@ flow through the :mod:`repro.serve` engine: admission queue -> scheduler
 -> continuous batcher -> phase-disaggregated execution over the paged
 per-request KV runtime.  Chunked prefill interleaves with decode steps,
 and each phase dispatches through its own ``(phase, bucket)``
-specialization contexts — the Controller tunes decode spec points (cache
-dtype; chunk length for the recurrent archs) separately for prefill and
-decode, while the bucket boundaries and the KV page geometry are tuned
-online against measured goodput by their own plan handlers.
+specialization contexts — the Controller tunes the serve step's spec
+points (the RMSNorm implementation) separately for prefill and decode,
+while the bucket boundaries are tuned online against measured goodput by
+their own plan handler.  ``--kv-page-size`` sets the KV page size.
 """
 import sys
 

@@ -18,11 +18,10 @@ chunked-prefill or a decode batch through one registered serve handler
 whose context key is ``(phase, bucket)``
 (:func:`~repro.training.steps.phase_context_fn`).  The Iridescent
 ``Controller`` therefore tunes prefill and decode *separately* per
-bucket — they are free to settle on different configs.  Two more spec
-points ride the same machinery: the bucket-boundary scheme
-(``BucketTuner``) and the KV page geometry (``KVTuner`` — paged page
-size vs. contiguous-per-request), both searched online against measured
-goodput (in-SLO tokens/s).
+bucket — they are free to settle on different configs.  One more spec
+point rides the same machinery: the bucket-boundary scheme
+(``BucketTuner``), searched online against measured goodput (in-SLO
+tokens/s).  The KV page size is a constant (``--kv-page-size``).
 
 The model is served at its published widths in its own compute dtype
 (``configs.get_config``); ``--reduced`` asks for the reduced same-family
@@ -69,8 +68,6 @@ from types import SimpleNamespace
 from repro.core import telemetry
 from repro.launch.jax_cache import enable_compile_cache
 from repro.serve import Request, pseudo_poisson_times
-
-KV_PAGE_SIZES = (8, 16, 64)
 
 
 def synthetic_workload(n: int, rate: float, seed: int = 0,
@@ -120,8 +117,7 @@ def add_engine_args(ap: argparse.ArgumentParser) -> None:
                          "across fleet replicas (same platform/device "
                          "kind required)")
     ap.add_argument("--kv-page-size", type=int, default=16,
-                    help="initial KV page size (tokens per page); the "
-                         "KVTuner searches the geometry menu online")
+                    help="KV page size (tokens per page)")
     ap.add_argument("--prefill-chunk", type=int, default=16,
                     help="prompt tokens consumed per chunked-prefill step "
                          "(long prompts interleave with decode steps)")
@@ -140,8 +136,6 @@ def add_engine_args(ap: argparse.ArgumentParser) -> None:
                     choices=("fcfs", "sjf", "deadline", "drr"))
     ap.add_argument("--bucket-dwell", type=int, default=25,
                     help="engine steps per bucket-scheme candidate")
-    ap.add_argument("--kv-dwell", type=int, default=25,
-                    help="engine steps per KV-geometry candidate")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--shadow-frac", type=float, default=0.25,
                     help="fraction of live calls mirrored for shadow "
@@ -176,13 +170,12 @@ def build_engine(args, device=None) -> SimpleNamespace:
     from repro.models import transformer as model
     from repro.models.transformer import RunOptions
     from repro.serve import (AdmissionQueue, BucketTuner, ContinuousBatcher,
-                             KVTuner, PagedKV, PhasedExecutor, ServeEngine,
+                             PagedKV, PhasedExecutor, ServeEngine,
                              ServeMetrics, ShadowEvaluator,
-                             bucket_plan_builder, kv_plan_builder,
-                             make_scheduler)
+                             bucket_plan_builder, make_scheduler)
     from repro.serve.batcher import BUCKET_POINT
-    from repro.serve.kv import KV_LAYOUT_POINT, KV_PAGE_POINT
-    from repro.training import make_serve_builder, phase_context_fn
+    from repro.training import (SERVE_SEARCH_POINTS, make_serve_builder,
+                                phase_context_fn)
 
     cfg = configs.select(args.arch, args.reduced)
     device = device if device is not None else jax.devices()[0]
@@ -202,28 +195,18 @@ def build_engine(args, device=None) -> SimpleNamespace:
     plan_handler = rt.register(
         "bucket_plan",
         bucket_plan_builder(list(batcher.schemes), batcher.default_scheme))
-    page_sizes = tuple(sorted({args.kv_page_size, *KV_PAGE_SIZES}))
-    kv_plan_handler = rt.register(
-        "kv_plan",
-        kv_plan_builder(("paged", "contig"), page_sizes, "paged",
-                        args.kv_page_size))
 
     # Restore *before* building the controllers: per-(phase,bucket) configs
     # are seeded onto the handler (the Controller warm-starts each context
-    # as its traffic materializes), and the tuned bucket scheme / KV plan
-    # land on their plan handlers' active configs.
+    # as its traffic materializes), and the tuned bucket scheme lands on
+    # its plan handler's active config.
     spec_state_path = (os.path.join(args.cache_dir, "spec_state.json")
                        if args.cache_dir else None)
     initial_scheme = None
-    initial_plan = None
     restored = False
     if spec_state_path and restore_spec_state(spec_state_path, rt, wait=True):
         restored = True
         initial_scheme = plan_handler.active_config().get(BUCKET_POINT)
-        kv_cfg = kv_plan_handler.active_config()
-        if KV_LAYOUT_POINT in kv_cfg:
-            initial_plan = (kv_cfg[KV_LAYOUT_POINT],
-                            kv_cfg.get(KV_PAGE_POINT, args.kv_page_size))
 
     params = init_serving_params(cfg, SingleDeviceSharding(device))
     run_opts = RunOptions(decode_cache_dtype=cfg.compute_dtype)
@@ -236,9 +219,8 @@ def build_engine(args, device=None) -> SimpleNamespace:
                               vocab_size=cfg.vocab_size)
 
     space = handler.spec_space()
-    labels = ["cache_dtype", "rmsnorm_impl"] + (
-        ["chunk_len"] if cfg.mixer in ("rwkv6", "hymba") else [])
-    policy_factory = lambda: ExhaustiveSweep.from_space(space, labels)
+    policy_factory = lambda: ExhaustiveSweep.from_space(
+        space, SERVE_SEARCH_POINTS)
     controller_kwargs = dict(
         dwell=args.dwell, change_detector=lambda: ChangeDetector(0.3),
         wait_compiles=False, prefetch=args.prefetch, budget=args.budget)
@@ -273,21 +255,17 @@ def build_engine(args, device=None) -> SimpleNamespace:
     tuner = BucketTuner(batcher, metric=metrics.interval_goodput,
                         dwell=args.bucket_dwell, plan_handler=plan_handler,
                         initial_scheme=initial_scheme, device=device)
-    kv_tuner = KVTuner(kv, metric=metrics.interval_goodput,
-                       dwell=args.kv_dwell, page_sizes=page_sizes,
-                       plan_handler=kv_plan_handler,
-                       initial_plan=initial_plan, device=device)
     engine = ServeEngine(
         handler, controller, batcher, make_scheduler(args.scheduler),
         executor=executor,
         queue=AdmissionQueue(depth=args.queue_depth, policy=args.shed_policy),
-        tuner=tuner, kv_tuner=kv_tuner, metrics=metrics, slo_s=slo_s,
+        tuner=tuner, metrics=metrics, slo_s=slo_s,
         shadow=shadow)
     return SimpleNamespace(
         rt=rt, engine=engine, handler=handler, controller=controller,
-        batcher=batcher, tuner=tuner, kv_tuner=kv_tuner, kv=kv,
+        batcher=batcher, tuner=tuner, kv=kv,
         metrics=metrics, restored=restored, initial_scheme=initial_scheme,
-        initial_plan=initial_plan, shadow=shadow, cfg=cfg, params=params,
+        shadow=shadow, cfg=cfg, params=params,
         executor=executor, device=device, policy_factory=policy_factory)
 
 
@@ -315,8 +293,8 @@ def build_tenant_engine(args, tenants) -> SimpleNamespace:
     aggregated behind a :class:`~repro.serve.tenancy.ControllerGroup` and
     a :class:`~repro.serve.tenancy.MultiTenantExecutor`.  Scheduling
     between tenants defaults to weighted-fair DRR (``--scheduler drr``)
-    using each tenant's declared weight.  The bucket/KV plan tuners and
-    the safety plane are single-model machinery and stay off here
+    using each tenant's declared weight.  The bucket tuner and the
+    safety plane are single-model machinery and stay off here
     (tenant engines run plain Controllers with a fixed bucket scheme).
     """
     import jax
@@ -333,7 +311,8 @@ def build_tenant_engine(args, tenants) -> SimpleNamespace:
                              MultiTenantExecutor, PagedKV, PhasedExecutor,
                              ServeEngine, ServeMetrics,
                              make_scheduler, make_tenant_context_fn)
-    from repro.training import make_serve_builder, phase_context_fn
+    from repro.training import (SERVE_SEARCH_POINTS, make_serve_builder,
+                                phase_context_fn)
 
     names = [t.name for t in tenants]
     if len(set(names)) != len(names):
@@ -383,12 +362,10 @@ def build_tenant_engine(args, tenants) -> SimpleNamespace:
             st.handler, params, kv, prefill_chunk=args.prefill_chunk,
             vocab_size=cfg.vocab_size)
         space = st.handler.spec_space()
-        labels = ["cache_dtype", "rmsnorm_impl"] + (
-            ["chunk_len"] if cfg.mixer in ("rwkv6", "hymba") else [])
         st.controller = Controller(
             st.handler,
-            (lambda space=space, labels=labels:
-             ExhaustiveSweep.from_space(space, labels)),
+            (lambda space=space:
+             ExhaustiveSweep.from_space(space, SERVE_SEARCH_POINTS)),
             dwell=args.dwell, change_detector=lambda: ChangeDetector(0.3),
             wait_compiles=False, prefetch=args.prefetch, budget=args.budget)
         pairs.append((st.handler, st.controller))
@@ -510,7 +487,6 @@ def _run_single(args) -> None:
             if args.telemetry_snapshot else None)
     if built.restored:
         print(f"restored spec state: bucket scheme={built.initial_scheme}, "
-              f"kv plan={built.initial_plan}, "
               f"seeded contexts={list(built.handler._seeded)}")
     plane = (SpecPlane(args.plane_dir, replica=args.replica_id,
                        quarantine=getattr(built.controller, "quarantine",
@@ -540,8 +516,7 @@ def _run_single(args) -> None:
           f"phase steps: {stats['phase_steps']}  "
           f"scheme: {built.tuner.active_scheme()} "
           f"(boundaries {built.batcher.schemes[built.tuner.active_scheme()]})")
-    print(f"kv: plan={built.kv_tuner.active_plan()} pools="
-          f"{json.dumps(built.kv.stats()['pools'])}")
+    print(f"kv: {json.dumps(built.kv.stats())}")
     best_cfgs = {str(k): ({kk: repr(vv) for kk, vv in cfg.items()}
                           if cfg is not None else None)
                  for k, cfg in built.controller.best_configs().items()}

@@ -11,8 +11,7 @@ batch through the handler's per-bucket dispatch snapshot and feeds the
 per-context Controller.
 
 Execution is phase-disaggregated: :mod:`repro.serve.kv` keeps every
-request's decode state isolated in block-paged host pools (page geometry
-is itself a tuned spec point), and :mod:`repro.serve.executor` runs
+request's decode state isolated in block-paged device pools, and :mod:`repro.serve.executor` runs
 chunked prefill and decode as separate ``(phase, bucket)`` specialization
 contexts of one serve handler.
 
@@ -28,8 +27,7 @@ from repro.serve.scheduler import (SCHEDULERS, DeadlineAware,
 from repro.serve.metrics import ServeMetrics
 from repro.serve.batcher import (BucketTuner, ContinuousBatcher, PackedBatch,
                                  bucket_plan_builder, default_schemes)
-from repro.serve.kv import (KVTuner, PagedKV, PageError, PagePool, PageTable,
-                            kv_plan_builder)
+from repro.serve.kv import PagedKV, PageError, PagePool, PageTable
 from repro.serve.executor import (DecodeExecutor, PhasedExecutor,
                                   PrefillExecutor)
 from repro.serve.engine import BatchExecutor, ServeEngine
@@ -46,8 +44,7 @@ __all__ = [
     "ShortestJobFirst", "make_scheduler", "ServeMetrics",
     "BucketTuner", "ContinuousBatcher", "PackedBatch",
     "bucket_plan_builder", "default_schemes",
-    "KVTuner", "PagedKV", "PageError", "PagePool", "PageTable",
-    "kv_plan_builder",
+    "PagedKV", "PageError", "PagePool", "PageTable",
     "DecodeExecutor", "PhasedExecutor", "PrefillExecutor",
     "BatchExecutor", "ServeEngine", "ShadowEvaluator",
     "ControllerGroup", "MultiTenantExecutor", "TenantSpec",

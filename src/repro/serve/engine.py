@@ -87,7 +87,6 @@ class ServeEngine:
         executor: BatchExecutor | Callable[[PackedBatch], None] | None = None,
         queue: AdmissionQueue | None = None,
         tuner: BucketTuner | None = None,
-        kv_tuner=None,                       # repro.serve.kv.KVTuner
         metrics: ServeMetrics | None = None,
         slo_s: float | None = None,
         tenant_slos: "dict[str, float] | None" = None,
@@ -106,7 +105,6 @@ class ServeEngine:
         self.scheduler = scheduler if scheduler is not None else FCFS()
         self.queue = queue if queue is not None else AdmissionQueue()
         self.tuner = tuner
-        self.kv_tuner = kv_tuner
         self.slo_s = slo_s
         #: per-tenant default SLOs (a tenant's requests without their own
         #: ``deadline_s`` fall back here before the engine-wide ``slo_s``)
@@ -239,8 +237,6 @@ class ServeEngine:
                 self.controller.step()
             if self.tuner is not None:
                 self.tuner.step()
-            if self.kv_tuner is not None:
-                self.kv_tuner.step()
         return tokens
 
     def _retire(self, req: Request, now: float) -> None:
@@ -376,7 +372,7 @@ class ServeEngine:
         """Every ``(handler_name, controller)`` this engine persists: the
         model controller — or, multi-tenant, every tenant controller a
         :class:`~repro.serve.tenancy.ControllerGroup` aggregates — plus
-        the bucket and KV plan tuners."""
+        the bucket tuner."""
         pairs = []
         sub = getattr(self.controller, "pairs", None)
         if sub:
@@ -385,9 +381,6 @@ class ServeEngine:
             pairs.append((self.handler.name, self.controller))
         if self.tuner is not None:
             pairs.append((self.tuner.handler.name, self.tuner.controller))
-        if self.kv_tuner is not None:
-            pairs.append((self.kv_tuner.handler.name,
-                          self.kv_tuner.controller))
         return pairs
 
     def _safety_state(self) -> dict | None:
@@ -440,8 +433,6 @@ class ServeEngine:
             out["scheduler"] = sched_stats()
         if self.tuner is not None:
             out["buckets"] = self.tuner.status()
-        if self.kv_tuner is not None:
-            out["kv"] = self.kv_tuner.status()
         if self.shadow is not None:
             out["shadow"] = {"pairs": self.shadow_pairs,
                              **self.shadow.stats()}

@@ -18,23 +18,16 @@ The host keeps the allocator's bookkeeping: the free lists
 (:class:`PagePool`), each request's pages and length, and the page-demand
 pre-check that lets a capacity failure raise before anything changes.
 
-**Page geometry is a specialization point.**  The layout — ``paged`` with
-a tunable page size, or ``contig`` (one max-length page per request, the
-contiguous-per-bucket baseline) — is declared as enum spec points on a
-tiny registered ``kv_plan`` handler (:func:`kv_plan_builder`), and
-:class:`KVTuner` drives it with the ordinary
-:class:`~repro.core.controller.Controller` against observed goodput —
-exactly the machinery that tunes kernel implementations and bucket
-schemes, persisting through ``spec_state.json`` like any other tuned
-config.  The tradeoff being searched: small pages waste no capacity on
-short requests (more concurrent requests fit); big pages strand capacity.
-Each geometry has its own pools, but every pool holds the same number of
-token rows when the page sizes divide the capacity, so the gather and
-scatter programs are shared and a re-tune compiles nothing.  A geometry
-re-tune only affects *future* joins — in-flight requests keep the
-geometry they were admitted under, so no live state is ever migrated; a
-geometry's pools are released once no live request is in it and it is no
-longer the active one.
+**The page size is a constant of the manager.**  It sets the allocation
+granularity and nothing else a step can observe: the pool holds
+``ceil(capacity_tokens / page_size) * page_size + 1`` token rows, so for
+every page size that divides the capacity the gather and scatter lower
+to the same programs, and for every page size that divides ``max_len``
+any batch of requests that fits the capacity fits the pages.  A search
+over it would choose between identical behaviours by noise, and would
+keep a pool per candidate alive while requests admitted under each
+drained; a caller that wants one page per request passes
+``page_size=max_len``.
 
 Cache pytree leaves are classified by the model's logical axes
 (``model.cache_axes(cfg)``), so the manager is generic across mixers:
@@ -53,9 +46,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import logging
 import math
-from typing import Any, Callable, Sequence
+from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -64,14 +56,7 @@ from jax import lax
 
 from repro.core import telemetry
 
-logger = logging.getLogger("repro.serve.kv")
-
-__all__ = ["PageError", "PagePool", "PageTable", "PagedKV",
-           "kv_plan_builder", "KVTuner", "KV_LAYOUT_POINT", "KV_PAGE_POINT"]
-
-#: Spec-point labels for the KV plan handler.
-KV_LAYOUT_POINT = "kv_layout"
-KV_PAGE_POINT = "kv_page_size"
+__all__ = ["PageError", "PagePool", "PageTable", "PagedKV"]
 
 #: An index past every pool: a gather reads zeros there, a scatter drops it.
 _NOWHERE = np.iinfo(np.int32).max
@@ -137,18 +122,13 @@ class PageTable:
     """One request's logical KV sequence: its pages and token length."""
 
     rid: str
-    geometry: tuple[str, int]            # (layout, page_size)
     pages: list[int] = dataclasses.field(default_factory=list)
     length: int = 0                      # tokens written so far
     slot: int | None = None              # row-state slot, from 1st harvest
 
-    @property
-    def page_size(self) -> int:
-        return self.geometry[1]
-
-    def pool_rows(self, start: int, n: int) -> np.ndarray:
-        """The pool's token rows behind slots ``[start, start + n)``."""
-        ps = self.page_size
+    def pool_rows(self, ps: int, start: int, n: int) -> np.ndarray:
+        """The pool's token rows behind slots ``[start, start + n)``, for
+        pages of ``ps`` tokens."""
         slots = np.arange(start, start + n)
         return np.asarray(self.pages, np.int32)[slots // ps] * ps + slots % ps
 
@@ -201,26 +181,18 @@ def _from_leaf(x, bat_i: int, seq_i: int):
 def _gather(pools, row_pools, table, *, paged, row):
     """The dense paged and row-state leaves of one step.
 
-    ``table (B, S + 2)``: each row's pool token rows (``_NOWHERE`` past
-    its length), then the index of its geometry in ``pools`` (-1 for
-    padding), then its row-state slot.  ``pools[k][i]`` is geometry k's
-    pool for the i-th paged leaf, whose (batch, seq) axes are
-    ``paged[i]``; ``row`` holds the batch axes of ``row_pools``."""
-    tokens, geo, slot = table[:, :-2], table[:, -2], table[:, -1]
+    ``table (B, S + 1)``: each row's pool token rows (``_NOWHERE`` past
+    its length and on padding rows), then its row-state slot.
+    ``pools[i]`` is the i-th paged leaf's pool, whose (batch, seq) axes
+    are ``paged[i]``; ``row`` holds the batch axes of ``row_pools``."""
+    tokens, slot = table[:, :-1], table[:, -1]
     b, s = tokens.shape
     out = []
-    for i, (bat_i, seq_i) in enumerate(paged):
-        leaf = None
-        for k, geo_pools in enumerate(pools):
-            pool = geo_pools[i]
-            zero = pool.shape[0] - 1
-            mine = (geo == k)[:, None]
-            rows = jnp.where(mine, jnp.minimum(tokens, zero), zero)
-            part = jnp.take(pool, rows.reshape(-1), axis=0, mode="clip")
-            part = part.reshape((b, s) + pool.shape[1:])
-            leaf = part if leaf is None else jnp.where(
-                mine.reshape((b, 1) + (1,) * (part.ndim - 2)), part, leaf)
-        out.append(_to_leaf(leaf, bat_i, seq_i))
+    for pool, (bat_i, seq_i) in zip(pools, paged):
+        # the clip sends _NOWHERE to the pool's last row, which stays zero
+        leaf = jnp.take(pool, tokens.reshape(-1), axis=0, mode="clip")
+        out.append(_to_leaf(leaf.reshape((b, s) + pool.shape[1:]),
+                            bat_i, seq_i))
     for pool, bat_i in zip(row_pools, row):
         out.append(jnp.take(pool, slot, axis=bat_i, mode="clip"))
     return out
@@ -231,16 +203,15 @@ def _gather(pools, row_pools, table, *, paged, row):
 def _scatter(pools, row_pools, new_paged, new_row, table, *, paged, row):
     """Write one step's new slots and row state into the (donated) pools.
 
-    ``table (B, W + 3)``: the pool token rows of the ``W`` slots from
+    ``table (B, W + 2)``: the pool token rows of the ``W`` slots from
     each row's window start (``_NOWHERE`` for a slot it did not write),
-    the window start, the index of its geometry in ``pools``, and its
-    row-state slot (the sink for padding rows)."""
-    w = table.shape[1] - 3
-    dest, start, geo, slot = (table[:, :w], table[:, w], table[:, w + 1],
-                              table[:, w + 2])
+    the window start, and its row-state slot (the sink for padding
+    rows)."""
+    w = table.shape[1] - 2
+    dest, start, slot = table[:, :w], table[:, w], table[:, w + 1]
     b = table.shape[0]
-    out_pools = [[] for _ in pools]
-    for leaf, (bat_i, seq_i), *geo_pools in zip(new_paged, paged, *pools):
+    out_pools = []
+    for pool, leaf, (bat_i, seq_i) in zip(pools, new_paged, paged):
         size = list(leaf.shape)
         size[bat_i], size[seq_i] = 1, w
         window = []
@@ -250,10 +221,8 @@ def _scatter(pools, row_pools, new_paged, new_row, table, *, paged, row):
             window.append(lax.dynamic_slice(leaf, at, size))
         window = _from_leaf(jnp.concatenate(window, axis=bat_i), bat_i, seq_i)
         window = window.reshape((b * w,) + window.shape[2:])
-        for k, pool in enumerate(geo_pools):
-            rows = jnp.where((geo == k)[:, None], dest, _NOWHERE)
-            out_pools[k].append(pool.at[rows.reshape(-1)].set(
-                window.astype(pool.dtype), mode="drop"))
+        out_pools.append(pool.at[dest.reshape(-1)].set(
+            window.astype(pool.dtype), mode="drop"))
     out_rows = []
     for pool, leaf, bat_i in zip(row_pools, new_row, row):
         for r in range(b):
@@ -270,23 +239,24 @@ class PagedKV:
     ``template`` is a cache built for ``batch=1`` at full ``max_len``
     (``model.init_cache(cfg, 1, max_len, opts)``); ``axes`` is the
     matching logical-axes pytree (``model.cache_axes(cfg)``).  The
-    manager owns device pools per *geometry* for the paged leaves and one
-    row-slot pool per row-state leaf, all on ``device`` (``None``: the
-    default device); each step's dense cache is a fresh gathered copy the
-    step program may donate.
+    manager owns one device pool per paged leaf and one row-slot pool per
+    row-state leaf, all built here on ``device`` (``None``: the default
+    device); each step's dense cache is a fresh gathered copy the step
+    program may donate.
 
-    ``capacity_tokens`` bounds each geometry's pool, and
-    ``capacity_tokens // max_len`` requests hold row state at once.
-    ``geometry`` fixes the layout; attach a :class:`KVTuner` to tune it
-    online instead.
+    ``capacity_tokens`` bounds the paged pools, cut into pages of
+    ``page_size`` tokens, and ``capacity_tokens // max_len`` requests
+    hold row state at once.
     """
 
     def __init__(self, template: Any, axes: Any, *, max_len: int,
                  capacity_tokens: int, page_size: int = 16,
-                 layout: str = "paged", device: Any = None):
+                 device: Any = None):
         self.device = device
         if max_len <= 0:
             raise ValueError(f"max_len must be positive, got {max_len}")
+        if page_size <= 0:
+            raise ValueError(f"page_size must be positive, got {page_size}")
         if capacity_tokens < max_len:
             raise ValueError(f"capacity_tokens ({capacity_tokens}) below "
                              f"max_len ({max_len}): one request cannot fit")
@@ -345,91 +315,36 @@ class PagedKV:
         self._row_axes = tuple(self._leaves[i].bat_i for i in self._row_idx)
         self._slots = PagePool(slots, 1) if self._row_idx else None
         self._template_slot, self._sink_slot = slots, slots + 1
-        # geometry -> (PagePool, [device pool per paged leaf])
-        self._pools: dict[tuple[str, int], tuple[PagePool, list]] = {}
+        num_pages = max(1, math.ceil(self.capacity_tokens / page_size))
+        self._pages = PagePool(num_pages, page_size)
+        rows = num_pages * self._pages.page_size + 1     # the last stays zero
+        self._pools = [jnp.zeros((rows,) + self._leaves[i].token_shape(),
+                                 self._leaves[i].dtype, device=device)
+                       for i in self._paged_idx]
         self._tables: dict[str, PageTable] = {}
-        self._tuner: "KVTuner | None" = None
-        self._fixed = self._normalize(layout, page_size)
         self._wide = 1                       # widest multi-token scatter
-
-    # -- geometry ---------------------------------------------------------------
-    def _normalize(self, layout: str, page_size: int | None) -> tuple[str, int]:
-        if layout == "contig":
-            return ("contig", self.max_len)
-        if layout == "paged":
-            if page_size is None or page_size <= 0:
-                raise ValueError(f"paged layout needs a positive page size, "
-                                 f"got {page_size}")
-            return ("paged", int(page_size))
-        raise ValueError(f"unknown layout {layout!r}; "
-                         f"have ['paged', 'contig']")
-
-    def set_geometry(self, layout: str, page_size: int | None = None) -> None:
-        """Pin the geometry for *future* joins (in-flight requests keep
-        the geometry they were admitted under)."""
-        self._fixed = self._normalize(layout, page_size)
-
-    def bind_tuner(self, tuner: "KVTuner") -> None:
-        self._tuner = tuner
-
-    def active_geometry(self) -> tuple[str, int]:
-        if self._tuner is not None:
-            layout, page = self._tuner.active_plan()
-            try:
-                return self._normalize(layout, page)
-            except ValueError:
-                logger.warning("tuned kv plan (%r, %r) invalid; "
-                               "using fixed geometry", layout, page)
-        return self._fixed
-
-    def _geo_pools(self, geo: tuple[str, int]) -> tuple[PagePool, list]:
-        entry = self._pools.get(geo)
-        if entry is None:
-            _, page_size = geo
-            num_pages = max(1, math.ceil(self.capacity_tokens / page_size))
-            rows = num_pages * page_size + 1        # the last stays zero
-            pools = [jnp.zeros((rows,) + self._leaves[i].token_shape(),
-                               self._leaves[i].dtype, device=self.device)
-                     for i in self._paged_idx]
-            entry = (PagePool(num_pages, page_size), pools)
-            self._pools[geo] = entry
-        return entry
-
-    def _release_idle(self) -> None:
-        """Drop the pools of geometries no live request is in, but for the
-        active one (a geometry sweep would otherwise keep one per
-        candidate)."""
-        keep = {t.geometry for t in self._tables.values()}
-        keep.add(self.active_geometry())
-        for geo in [g for g in self._pools if g not in keep]:
-            del self._pools[geo]
 
     # -- request lifecycle ------------------------------------------------------
     def join(self, rid: str) -> PageTable:
-        """Admit a request under the active geometry; pages are allocated
-        lazily as tokens are written, and a row-state slot at its first
-        harvest (until then it reads as the template row)."""
+        """Admit a request; pages are allocated lazily as tokens are
+        written, and a row-state slot at its first harvest (until then it
+        reads as the template row)."""
         if rid in self._tables:
             raise PageError(f"request {rid!r} already live")
-        geo = self.active_geometry()
-        self._release_idle()
-        self._geo_pools(geo)           # materialize the pool up front
-        table = PageTable(rid=rid, geometry=geo)
+        table = PageTable(rid=rid)
         self._tables[rid] = table
         return table
 
     def retire(self, rid: str) -> int:
-        """Free a request's pages back to its geometry's pool.  Returns
-        the number of pages released."""
+        """Free a request's pages back to the pool.  Returns the number of
+        pages released."""
         table = self._tables.pop(rid, None)
         if table is None:
             raise PageError(f"request {rid!r} is not live")
-        pool, _ = self._pools[table.geometry]
         for pid in table.pages:
-            pool.free(pid)
+            self._pages.free(pid)
         if table.slot is not None:
             self._slots.free(table.slot)
-        self._release_idle()
         return len(table.pages)
 
     def length(self, rid: str) -> int:
@@ -441,23 +356,6 @@ class PagedKV:
 
     def live_requests(self) -> list[str]:
         return list(self._tables)
-
-    def can_fit(self, n_tokens: int, rid: str | None = None) -> bool:
-        """Whether ``n_tokens`` more tokens fit — for a live request
-        (``rid``), in its own geometry's pool; otherwise for a fresh
-        request under the active geometry."""
-        if rid is not None and rid in self._tables:
-            table = self._tables[rid]
-            geo = table.geometry
-            have = len(table.pages) * geo[1] - table.length
-        else:
-            geo = self.active_geometry()
-            have = 0
-        if n_tokens <= have:
-            return True
-        pool, _ = self._geo_pools(geo)
-        need = math.ceil((n_tokens - have) / geo[1])
-        return need <= pool.free_pages
 
     # -- step I/O ---------------------------------------------------------------
     def materialize(self, rids: Sequence[str], batch: int) \
@@ -475,27 +373,23 @@ class PagedKV:
             raise ValueError(f"{len(rids)} requests do not fit in "
                              f"batch {batch}")
         tables = [self._tables[r] for r in rids]
+        ps = self._pages.page_size
         with telemetry.span("kv.gather"):
-            geos = list(dict.fromkeys(t.geometry for t in tables)) \
-                or [self.active_geometry()]
             s = self.max_len if self._paged_idx else 0
-            index = np.full((batch, s + 2), _NOWHERE, np.int32)
-            index[:, s] = -1
-            index[:, s + 1] = self._template_slot
+            index = np.full((batch, s + 1), _NOWHERE, np.int32)
+            index[:, s] = self._template_slot
             for r, t in enumerate(tables):
                 if s:
-                    index[r, :t.length] = t.pool_rows(0, t.length)
-                index[r, s] = geos.index(t.geometry)
+                    index[r, :t.length] = t.pool_rows(ps, 0, t.length)
                 if t.slot is not None:
-                    index[r, s + 1] = t.slot
+                    index[r, s] = t.slot
         index = self._upload(index)
         out_leaves = [self._upload(spec.template)
                       if spec.kind == _SHARED else None
                       for spec in self._leaves]
         with telemetry.span("kv.gather"):
-            built = _gather(
-                [self._geo_pools(g)[1] for g in geos], self._rows, index,
-                paged=self._paged_axes, row=self._row_axes)
+            built = _gather(self._pools, self._rows, index,
+                            paged=self._paged_axes, row=self._row_axes)
         for i, leaf in zip(self._paged_idx + self._row_idx, built):
             out_leaves[i] = leaf
         cache = jax.tree_util.tree_unflatten(self._treedef, out_leaves)
@@ -520,8 +414,9 @@ class PagedKV:
             raise ValueError("new_cache structure does not match template")
         tables = [self._tables[r] for r in rids]
         n_new = [int(n) for n in n_new]
-        # pre-check page demand per geometry pool, and row-state slots
-        demand: dict[tuple[str, int], int] = {}
+        ps = self._pages.page_size
+        # pre-check page demand and row-state slots
+        need = 0
         for table, n in zip(tables, n_new):
             if n == 0:
                 continue
@@ -529,15 +424,10 @@ class PagedKV:
             if end > self.max_len:
                 raise PageError(f"request {table.rid!r} would exceed "
                                 f"max_len {self.max_len} ({end} tokens)")
-            need = math.ceil(end / table.page_size) - len(table.pages)
-            if need > 0:
-                demand[table.geometry] = demand.get(table.geometry, 0) + need
-        for geo, need in demand.items():
-            pool, _ = self._geo_pools(geo)
-            if need > pool.free_pages:
-                raise PageError(
-                    f"geometry {geo} needs {need} pages but only "
-                    f"{pool.free_pages} free")
+            need += max(0, math.ceil(end / ps) - len(table.pages))
+        if need > self._pages.free_pages:
+            raise PageError(f"{need} pages needed but only "
+                            f"{self._pages.free_pages} free")
         if self._slots is not None:
             fresh = sum(t.slot is None for t in tables)
             if fresh > self._slots.free_pages:
@@ -546,46 +436,42 @@ class PagedKV:
         # the step program must finish first
         with telemetry.span("kv.wait"):
             jax.block_until_ready(new_cache)
-        writes = [(t, n) for t, n in zip(tables, n_new) if n]
-        if not self._row_idx and not (self._paged_idx and writes):
+        paged = bool(self._paged_idx) and any(n_new)
+        if not self._row_idx and not paged:
             return
         with telemetry.span("kv.scatter"):
-            geos = list(dict.fromkeys(t.geometry for t, _ in writes)) \
-                if self._paged_idx else []
-            w = self._width(max(n_new, default=0)) if geos else 0
+            w = self._width(max(n_new)) if paged else 0
             first = (self._paged_idx + self._row_idx)[0]
             batch = new_leaves[first].shape[self._leaves[first].bat_i]
-            index = np.full((batch, w + 3), _NOWHERE, np.int32)
+            index = np.full((batch, w + 2), _NOWHERE, np.int32)
             index[:, w] = 0
-            index[:, w + 1] = -1
-            index[:, w + 2] = self._sink_slot
+            index[:, w + 1] = self._sink_slot
             for r, (table, n) in enumerate(zip(tables, n_new)):
                 if self._slots is not None:
                     if table.slot is None:
                         table.slot = self._slots.alloc()
-                    index[r, w + 2] = table.slot
+                    index[r, w + 1] = table.slot
                 if n == 0:
                     continue
-                pool, _ = self._pools[table.geometry]
-                while len(table.pages) * table.page_size < table.length + n:
-                    table.pages.append(pool.alloc())
-                if geos:
+                while len(table.pages) * ps < table.length + n:
+                    table.pages.append(self._pages.alloc())
+                if paged:
                     # a window of w slots that holds the written ones and
                     # ends inside the row
                     lo = min(table.length, self.max_len - w)
                     off = table.length - lo
-                    index[r, off:off + n] = table.pool_rows(table.length, n)
+                    index[r, off:off + n] = table.pool_rows(
+                        ps, table.length, n)
                     index[r, w] = lo
-                    index[r, w + 1] = geos.index(table.geometry)
         index = self._upload(index)
         with telemetry.span("kv.scatter"):
             pools, self._rows = _scatter(
-                [self._pools[g][1] for g in geos], self._rows,
-                [new_leaves[i] for i in self._paged_idx] if geos else [],
+                self._pools if paged else [], self._rows,
+                [new_leaves[i] for i in self._paged_idx] if paged else [],
                 [new_leaves[i] for i in self._row_idx], index,
                 paged=self._paged_axes, row=self._row_axes)
-            for g, geo_pools in zip(geos, pools):
-                self._pools[g][1][:] = geo_pools
+            if paged:
+                self._pools = pools
         for table, n in zip(tables, n_new):
             table.length += n
 
@@ -602,21 +488,17 @@ class PagedKV:
 
     # -- reporting --------------------------------------------------------------
     def stats(self) -> dict:
-        geos = {}
-        for geo, (pool, arrays) in self._pools.items():
-            geos[f"{geo[0]}@{geo[1]}"] = {
-                "num_pages": pool.num_pages,
-                "live_pages": pool.live_pages,
-                "free_pages": pool.free_pages,
-                "allocs": pool.allocs,
-                "frees": pool.frees,
-                "high_water": pool.high_water,
-                "pool_bytes": sum(a.nbytes for a in arrays),
-            }
+        pool = self._pages
         return {
             "live_requests": len(self._tables),
-            "active_geometry": list(self.active_geometry()),
-            "pools": geos,
+            "page_size": pool.page_size,
+            "num_pages": pool.num_pages,
+            "live_pages": pool.live_pages,
+            "free_pages": pool.free_pages,
+            "allocs": pool.allocs,
+            "frees": pool.frees,
+            "high_water": pool.high_water,
+            "pool_bytes": sum(a.nbytes for a in self._pools),
             "row_state_bytes": sum(a.nbytes for a in self._rows),
         }
 
@@ -626,135 +508,3 @@ class PagedKV:
         that read it wait for it on the device."""
         with telemetry.span("kv.upload", bytes=host.nbytes):
             return jax.device_put(host, self.device)
-
-
-# -- geometry as a specialization point -----------------------------------------
-
-def kv_plan_builder(layouts: Sequence[str], page_sizes: Sequence[int],
-                    default_layout: str, default_page: int) -> Callable:
-    """Handler builder declaring the KV geometry as enum spec points.
-
-    Like :func:`repro.serve.batcher.bucket_plan_builder`, the traced body
-    is the identity — registering the *choice* as a handler buys the
-    Controller's search, spec_state persistence, and warm restore for
-    free.
-    """
-    layout_choices = tuple(layouts)
-    page_choices = tuple(int(p) for p in page_sizes)
-
-    def builder(spec):
-        spec.enum(KV_LAYOUT_POINT, default_layout, layout_choices,
-                  guarded=False)
-        spec.enum(KV_PAGE_POINT, default_page, page_choices, guarded=False)
-
-        def plan(tick):
-            return tick
-
-        return plan
-
-    return builder
-
-
-class KVTuner:
-    """Tunes the KV geometry online with a Controller.
-
-    Registers a ``kv_plan`` handler on ``runtime`` whose spec points are
-    the layout and page-size enums, and drives it with a
-    :class:`~repro.core.controller.Controller` whose metric is served
-    goodput (the same read-and-reset window the bucket tuner observes).
-    The candidate list enumerates ``contig`` once plus ``paged`` at each
-    page size — the engine calls :meth:`step` once per non-idle
-    iteration, and the manager reads :meth:`active_plan` at each join.
-    """
-
-    def __init__(self, kv: PagedKV, runtime=None,
-                 metric: Callable[[], float] = lambda: 0.0,
-                 dwell: int = 25,
-                 name: str = "kv_plan",
-                 page_sizes: Sequence[int] = (8, 16, 64),
-                 include_contig: bool = True,
-                 policy: "Callable | None" = None,
-                 change_detector=None,
-                 initial_plan: "tuple[str, int] | None" = None,
-                 wait_compiles: bool = False,
-                 plan_handler=None,
-                 device=None):
-        from repro.core.controller import Controller
-        from repro.core.metrics import ChangeDetector
-        from repro.core.policy import ExhaustiveSweep
-        from repro.core.runtime import DEFAULT_CONTEXT
-
-        import jax
-        import jax.numpy as jnp
-
-        self.kv = kv
-        self.metric = metric
-        page_sizes = tuple(sorted({int(p) for p in page_sizes}))
-        if not page_sizes:
-            raise ValueError("page_sizes must be non-empty")
-        layouts = ("paged", "contig") if include_contig else ("paged",)
-        self._default_page = page_sizes[0]
-        if plan_handler is None:
-            if runtime is None:
-                raise ValueError("KVTuner needs a runtime (to register the "
-                                 "plan handler) or a plan_handler")
-            plan_handler = runtime.register(
-                name, kv_plan_builder(layouts, page_sizes, layouts[0],
-                                      self._default_page))
-        self.handler = plan_handler
-        candidates = [{KV_LAYOUT_POINT: "paged", KV_PAGE_POINT: p}
-                      for p in page_sizes]
-        if include_contig:
-            candidates.append({KV_LAYOUT_POINT: "contig"})
-        initial_configs = None
-        if initial_plan is not None:
-            layout, page = initial_plan
-            if layout not in layouts or (layout == "paged"
-                                         and page not in page_sizes):
-                logger.warning("restored kv plan %r unknown; "
-                               "exploring fresh", initial_plan)
-            else:
-                cfg = {KV_LAYOUT_POINT: layout}
-                if layout == "paged":
-                    cfg[KV_PAGE_POINT] = int(page)
-                initial_configs = {DEFAULT_CONTEXT: cfg}
-        self.controller = Controller(
-            self.handler,
-            policy if policy is not None
-            else (lambda: ExhaustiveSweep(candidates)),
-            metric=lambda view: self.metric(),
-            dwell=dwell,
-            change_detector=(change_detector if change_detector is not None
-                             else (lambda: ChangeDetector(0.5))),
-            wait_compiles=wait_compiles,
-            prefetch=0,
-            initial_configs=initial_configs)
-        # the plan handler's dwell-clock input, on the replica's device
-        self._tick = jax.device_put(jnp.int32(0), device)
-        kv.bind_tuner(self)
-
-    def active_plan(self) -> tuple[str, int]:
-        cfg = self.handler.active_config()
-        layout = cfg.get(KV_LAYOUT_POINT, "paged")
-        page = cfg.get(KV_PAGE_POINT, self._default_page)
-        return layout, page
-
-    def step(self) -> None:
-        self.handler(self._tick)
-        self.controller.step()
-
-    def settled(self) -> bool:
-        return self.controller.settled()
-
-    def best_plan(self) -> "tuple[str, int] | None":
-        cfg, _ = self.controller.best()
-        if cfg is None:
-            return None
-        return (cfg.get(KV_LAYOUT_POINT, "paged"),
-                cfg.get(KV_PAGE_POINT, self._default_page))
-
-    def status(self) -> dict:
-        return {"active": list(self.active_plan()),
-                "best": list(self.best_plan() or ()),
-                "settled": self.settled(),
-                "stats": self.kv.stats()}

@@ -28,9 +28,10 @@ from repro.models import (KernelOptions, ModelConfig, MoEOptions, RunOptions)
 from repro.models import transformer as model
 from repro.optim import OptConfig, apply_updates, init_opt_state
 
-__all__ = ["SHARDING_PROFILES", "make_train_builder", "make_prefill_builder",
-           "make_decode_builder", "make_serve_builder", "phase_context_fn",
-           "run_options_from_spec", "cross_entropy", "chunked_cross_entropy"]
+__all__ = ["SHARDING_PROFILES", "SERVE_SEARCH_POINTS", "make_train_builder",
+           "make_prefill_builder", "make_decode_builder", "make_serve_builder",
+           "phase_context_fn", "run_options_from_spec", "cross_entropy",
+           "chunked_cross_entropy"]
 
 
 # -- sharding profiles (layout specialization points) ---------------------------
@@ -378,6 +379,13 @@ def phase_context_fn(args, kwargs) -> tuple[str, int]:
     return (phase, int(tokens.shape[0]))
 
 
+#: The serve step's searched spec points: each one changes the program the
+#: step lowers to.  The step declares no cache dtype (its cache's is the
+#: template's), and ``chunk_len`` is not searched: at the default prefill
+#: chunk of 16 every choice lowers to the same program.
+SERVE_SEARCH_POINTS = ("rmsnorm_impl",)
+
+
 def make_serve_builder(
     cfg: ModelConfig,
     mesh=None,
@@ -414,9 +422,6 @@ def make_serve_builder(
         opts = run_options_from_spec(spec, cfg, kernel_impl=kernel_impl,
                                      scan_layers=scan_layers, window=window,
                                      for_decode=True, dropless=True)
-        opts = RunOptions(**{**opts.__dict__, "decode_cache_dtype": spec.enum(
-            "cache_dtype", "bfloat16", ("bfloat16", "float32"),
-            guarded=False)})
         rules = _rules_from_spec(spec)
         cache_layout = spec.enum("cache_layout", "seq", ("seq", "batch"),
                                  guarded=False)

@@ -237,6 +237,27 @@ def test_spec_state_stale_config_degrades_to_generic(tmp_path):
     rt.shutdown()
 
 
+def test_spec_state_of_a_dropped_handler_and_point_degrades(tmp_path):
+    """A serving state from when the KV page geometry was a searched plan
+    handler and the step declared a cache dtype: the plan handler's entry
+    is ignored, and the undeclared point degrades to generic."""
+    path = str(tmp_path / "spec_state.json")
+    default = encode_context_key(DEFAULT_CONTEXT)
+    with open(path, "w") as f:
+        json.dump({"version": 3, "handlers": {
+            "m": {"contexts": {default: {"cache_dtype": "float32"}}},
+            "kv_plan": {"contexts": {default: {"kv_layout": "contig",
+                                               "kv_page_size": 16}}}}}, f)
+    rt = make_rt()
+    h = rt.register("m", _mm_builder)
+    restore_spec_state(path, rt, wait=True)           # must not raise
+    assert set(rt.handlers) == {"m"}
+    out = h(jnp.ones((4, 4)), jnp.eye(4))
+    np.testing.assert_allclose(out, np.ones((4, 4)))
+    assert h.active_config() == {}
+    rt.shutdown()
+
+
 def test_spec_state_malformed_v2_degrades_to_generic(tmp_path):
     """A truncated / hand-edited v2 file must never crash startup."""
     path = str(tmp_path / "spec_state.json")

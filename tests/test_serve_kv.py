@@ -7,7 +7,7 @@ Covers the PR's serve-path invariants:
 * materialize/harvest round trips keep per-request state isolated,
 * **determinism**: requests decoding interleaved through the phased
   executor produce exactly the tokens they produce when served alone
-  (and the same under paged vs contiguous KV geometry),
+  (and the same at a small page size and at one page per request),
 * tuple context keys (``(phase, bucket)``) survive spec_state.json
   save -> restore losslessly, and a warm restart resumes distinct
   per-phase configs with zero XLA recompiles,
@@ -44,10 +44,9 @@ AXES = {"k": ("batch", "seq_kv", "model"),
         "tick": ()}
 
 
-def make_kv(page_size=4, layout="paged", capacity=8 * MAX_LEN, width=3):
+def make_kv(page_size=4, capacity=8 * MAX_LEN, width=3):
     return PagedKV(_template(width), AXES, max_len=MAX_LEN,
-                   capacity_tokens=capacity, page_size=page_size,
-                   layout=layout)
+                   capacity_tokens=capacity, page_size=page_size)
 
 
 # -- page allocator properties --------------------------------------------------
@@ -110,9 +109,9 @@ def test_no_page_shared_between_live_requests(ops):
         assert len(owned) == len(set(owned)), "page shared across requests"
     for rid in list(live):
         kv.retire(rid)
-    for pool in kv.stats()["pools"].values():
-        assert pool["live_pages"] == 0
-        assert pool["allocs"] == pool["frees"]
+    stats = kv.stats()
+    assert stats["live_pages"] == 0
+    assert stats["allocs"] == stats["frees"]
 
 
 def test_retired_pages_are_reused_by_next_join():
@@ -130,9 +129,10 @@ def test_retired_pages_are_reused_by_next_join():
 
 def test_harvest_roundtrip_isolates_rows():
     """Distinct values written for interleaved requests come back on the
-    right rows at the right slots — under both geometries."""
-    for layout, page in (("paged", 4), ("contig", MAX_LEN)):
-        kv = make_kv(page_size=page, layout=layout)
+    right rows at the right slots — at a small page size and at one page
+    per request."""
+    for page in (4, MAX_LEN):
+        kv = make_kv(page_size=page)
         kv.join("a")
         kv.join("b")
         for step in range(3):
@@ -235,9 +235,9 @@ def _assert_same_bits(got, want):
                                       err_msg=name)
 
 
-@pytest.mark.parametrize("layout,page", [("paged", 4), ("paged", 8),
-                                         ("contig", MAX_LEN)])
-def test_device_pools_match_host_staging_bit_for_bit(layout, page):
+@pytest.mark.parametrize("page", [4, 8, MAX_LEN],
+                         ids=["paged-4", "paged-8", "contig-16"])
+def test_device_pools_match_host_staging_bit_for_bit(page):
     """Random join/harvest/retire interleavings: every dense cache equals
     the host reference bit for bit — padding rows, slots past each row's
     length after LIFO page reuse, rows in any order — and harvest takes
@@ -245,7 +245,7 @@ def test_device_pools_match_host_staging_bit_for_bit(layout, page):
     rng = np.random.default_rng(page)
     template = _layered_template()
     kv = PagedKV(template, LAYERED_AXES, max_len=MAX_LEN,
-                 capacity_tokens=4 * MAX_LEN, page_size=page, layout=layout)
+                 capacity_tokens=4 * MAX_LEN, page_size=page)
     ref = _HostReference(template)
     live: dict[str, int] = {}
     for step in range(60):
@@ -280,8 +280,7 @@ def test_device_pools_match_host_staging_bit_for_bit(layout, page):
 
 
 def _pool_arrays(kv):
-    return ({geo: [np.asarray(a) for a in arrays]
-             for geo, (_, arrays) in kv._pools.items()},
+    return ([np.asarray(a) for a in kv._pools],
             [np.asarray(a) for a in kv._rows])
 
 
@@ -297,11 +296,10 @@ def test_harvest_changes_only_the_written_slots_and_real_rows():
     step = {k: (v + 1 if k != "tick" else v) for k, v in cache.items()}
     kv.harvest(["c", "a"], step, [2, 1])
     after_pools, after_rows = _pool_arrays(kv)
-    (geo,) = after_pools
     # token rows of the written slots: c's 0-1 (its first page) and a's 6
     pages = {r: kv.table(r).pages for r in ("a", "c")}
     written = {pages["c"][0] * 4, pages["c"][0] * 4 + 1, pages["a"][1] * 4 + 2}
-    for before, after in zip(before_pools[geo], after_pools[geo]):
+    for before, after in zip(before_pools, after_pools):
         changed = {int(t) for t in np.nonzero(
             (before != after).reshape(len(before), -1).any(axis=1))[0]}
         assert changed == written
@@ -332,29 +330,39 @@ def test_page_error_leaves_the_device_pools_untouched():
     assert kv.length("a") == 10 and kv.length("b") == 0
     assert kv.table("b").slot is None and not kv.table("b").pages
     after_pools, after_rows = _pool_arrays(kv)
-    for geo in before_pools:
-        for before, after in zip(before_pools[geo], after_pools[geo]):
-            np.testing.assert_array_equal(before, after)
-    for before, after in zip(before_rows, after_rows):
+    for before, after in zip(before_pools + before_rows,
+                             after_pools + after_rows):
         np.testing.assert_array_equal(before, after)
 
 
-def test_idle_inactive_geometry_pool_is_released():
+def test_pools_are_fixed_at_construction():
+    """The pools are built once, before the first join, and joins,
+    harvests and retires move pages without growing or dropping them."""
     kv = make_kv(page_size=4)
+    # 128 token rows and the zero row, 3 float32 lanes each; 8 row-state
+    # slots, the template row and the sink
+    want = {"page_size": 4, "num_pages": 32,
+            "pool_bytes": (8 * MAX_LEN + 1) * 3 * 4,
+            "row_state_bytes": (8 + 2) * 3 * 4}
+    shapes = [a.shape for a in kv._pools]
+
+    def check():
+        assert {k: kv.stats()[k] for k in want} == want
+        assert [a.shape for a in kv._pools] == shapes
+
+    check()
     kv.join("a")
-    cache, _ = kv.materialize(["a"], 1)
-    kv.harvest(["a"], cache, [5])
-    kv.set_geometry("paged", 8)
-    kv.join("b")                                   # a keeps paged@4
-    pools = kv.stats()["pools"]
-    assert set(pools) == {"paged@4", "paged@8"}
-    # 128 token rows and the zero row, 3 float32 lanes each
-    assert pools["paged@4"]["pool_bytes"] == (8 * MAX_LEN + 1) * 3 * 4
-    kv.retire("a")                                 # paged@4 idle, inactive
-    assert set(kv.stats()["pools"]) == {"paged@8"}
-    kv.retire("b")                                 # the active one stays
-    assert set(kv.stats()["pools"]) == {"paged@8"}
-    assert kv.stats()["row_state_bytes"] == (8 + 2) * 3 * 4
+    kv.join("b")
+    check()
+    cache, _ = kv.materialize(["a", "b"], 2)
+    kv.harvest(["a", "b"], cache, [5, 1])
+    check()
+    assert kv.stats()["live_pages"] == 3
+    kv.retire("a")
+    check()
+    kv.retire("b")
+    check()
+    assert kv.stats()["live_pages"] == 0 and kv.stats()["high_water"] == 3
 
 
 def test_decode_step_uploads_index_tables_and_shared_leaves_only():
@@ -385,10 +393,10 @@ def test_decode_step_uploads_index_tables_and_shared_leaves_only():
     assert [n for n, *_ in spans].count("serve.exec.decode") == 1
     assert not any(n == "kv.download" for n, *_ in spans)
     up = sum(a["bytes"] for n, _, _, a in spans if n == "kv.upload")
-    # int32 tables of the bucket's 2 rows: materialize's 16 slots, its
-    # geometry and row-state slot; harvest's 1 slot, window start,
-    # geometry and row-state slot; and the shared tick
-    assert 0 < up <= 2 * (MAX_LEN + 2) * 4 + 2 * (1 + 3) * 4 + 4
+    # int32 tables of the bucket's 2 rows: materialize's 16 slots and
+    # row-state slot; harvest's 1 slot, window start and row-state slot;
+    # and the shared tick
+    assert up == 2 * (MAX_LEN + 1) * 4 + 2 * (1 + 2) * 4 + 4
 
 
 # -- phased executor determinism ------------------------------------------------
@@ -425,12 +433,11 @@ def _prompt_fn(req):
     return (np.arange(req.prompt_tokens, dtype=np.int32) * 3 + 1) % VOCAB
 
 
-def _serve(reqs, layout="paged", bucket=2):
+def _serve(reqs, page_size=4, bucket=2):
     rt = IridescentRuntime(async_compile=False)
     handler = rt.register("hist", _history_builder,
                           context_fn=phase_context_fn)
-    kv = make_kv(page_size=4 if layout == "paged" else MAX_LEN,
-                 layout=layout)
+    kv = make_kv(page_size=page_size)
     executor = PhasedExecutor(handler, None, kv, prefill_chunk=2,
                               prompt_fn=_prompt_fn)
     engine = ServeEngine(handler, None,
@@ -458,12 +465,14 @@ def test_interleaved_decode_matches_sequential():
 
 
 def test_paged_and_contig_geometries_decode_identically():
+    """Page sizes 4 and MAX_LEN (one page per request) decode
+    identically."""
     specs = [(5, 4), (3, 6)]
-    paged = _serve([Request(prompt_tokens=p, max_new_tokens=g)
-                    for p, g in specs], layout="paged")
-    contig = _serve([Request(prompt_tokens=p, max_new_tokens=g)
-                     for p, g in specs], layout="contig")
-    assert paged == contig
+    small = _serve([Request(prompt_tokens=p, max_new_tokens=g)
+                    for p, g in specs], page_size=4)
+    whole = _serve([Request(prompt_tokens=p, max_new_tokens=g)
+                    for p, g in specs], page_size=MAX_LEN)
+    assert small == whole
 
 
 def test_executor_rejects_requests_that_cannot_fit():

@@ -156,21 +156,21 @@ def test_paged_kv_byte_counters_match_the_leaves():
     dense = sum(int(np.asarray(x).nbytes) for x in leaves)
     # k (4, 16, 3) + state (4, 2) in float32, tick int32
     assert dense == 4 * 16 * 3 * 4 + 4 * 2 * 4 + 4
-    # an int32 table of each row's 16 slots, geometry and row-state slot,
-    # and the shared tick
-    assert moved(t0) == {"kv.upload": 4 * (16 + 2) * 4 + 4, "kv.download": 0}
+    # an int32 table of each row's 16 slots and row-state slot, and the
+    # shared tick
+    assert moved(t0) == {"kv.upload": 4 * (16 + 1) * 4 + 4, "kv.download": 0}
     # the table's build and the gather's dispatch
     assert [n for n, *_ in _since(t0)].count("kv.gather") == 2
     t0 = time.perf_counter()
     kv.harvest(["a", "b"], cache, [1, 1])
-    # the scatter's table: each row's written slot, length, geometry and
-    # row-state slot
-    assert moved(t0) == {"kv.upload": 4 * (1 + 3) * 4, "kv.download": 0}
+    # the scatter's table: each row's written slot, length and row-state
+    # slot
+    assert moved(t0) == {"kv.upload": 4 * (1 + 2) * 4, "kv.download": 0}
     # a harvest with no token written writes the row state alone
     cache, _ = kv.materialize(["a"], 1)
     t0 = time.perf_counter()
     kv.harvest(["a"], cache, [0])
-    assert moved(t0) == {"kv.upload": 1 * 3 * 4, "kv.download": 0}
+    assert moved(t0) == {"kv.upload": 1 * 2 * 4, "kv.download": 0}
 
 
 def test_bus_span_is_the_span_bound_to_that_bus(tmp_path):

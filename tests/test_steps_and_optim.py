@@ -8,7 +8,9 @@ from repro import configs
 from repro.core.specializer import discover_space, specialize_builder
 from repro.models import transformer as model
 from repro.optim import OptConfig, apply_updates, cosine_lr, init_opt_state
-from repro.training import cross_entropy, make_train_builder
+from repro.models.transformer import RunOptions
+from repro.training import (SERVE_SEARCH_POINTS, cross_entropy,
+                            make_serve_builder, make_train_builder)
 
 CFG = configs.get_reduced("yi-6b").replace(compute_dtype="float32")
 OPT = OptConfig(lr=1e-2, warmup_steps=1, total_steps=100)
@@ -121,3 +123,29 @@ def test_chunked_ce_equals_full():
                     np.asarray(jax.tree_util.tree_leaves(s2["params"])[0]))
     assert abs(outs[0][0] - outs[16][0]) < 1e-5
     np.testing.assert_allclose(outs[0][1], outs[16][1], rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "rwkv6-1.6b",
+                                  "deepseek-v2-236b-ep16"])
+def test_every_searched_serve_point_changes_the_program(arch):
+    """Each point the serve step's search sweeps lowers two of its choices
+    available here to different programs, in both phases: a point that
+    changes nothing would only spend the search's dwells."""
+    cfg = configs.select(arch, reduced=True)
+    b, max_len = 2, 32
+    params = jax.eval_shape(lambda k: model.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(
+        cfg, b, max_len, RunOptions(decode_cache_dtype=cfg.compute_dtype)))
+    rows = jax.ShapeDtypeStruct((b,), jnp.int32)
+    builder = make_serve_builder(cfg)
+    space = discover_space(builder)
+    for label in SERVE_SEARCH_POINTS:
+        choices = space[label].choices[:2]      # xla_ref, pallas_interpret
+        assert len(choices) == 2, (label, choices)
+        for shape in ((b,), (b, 16)):
+            tokens = jax.ShapeDtypeStruct(shape, jnp.int32)
+            texts = [jax.jit(specialize_builder(builder, {label: c}).fn)
+                     .lower(params, cache, tokens, rows, rows).as_text()
+                     for c in choices]
+            assert texts[0] != texts[1], (label, choices, shape)

@@ -191,7 +191,7 @@ def test_paged_kv_programs_compile_in_place(one_chip, program):
 
     b, max_len, tokens = 8, 1024, 8192
     dense = on_chip((28, b, 8, max_len, 128), BF16)
-    pools = [[on_chip((tokens + 1, 28, 8, 128), BF16)] * 2]
+    pools = [on_chip((tokens + 1, 28, 8, 128), BF16)] * 2
     rows = [on_chip((24, 16 + 2, 32, 64, 64), F32),
             on_chip((24, 16 + 2, 2048), BF16),
             on_chip((24, 16 + 2, 2048), BF16)]
@@ -200,17 +200,17 @@ def test_paged_kv_programs_compile_in_place(one_chip, program):
     paged, row = ((1, 3), (1, 3)), (1, 1, 1)
     mib = 1 << 20
     if program == "gather":
-        compiled = kv._gather.lower(pools, rows, on_chip((b, max_len + 2), I32),
+        compiled = kv._gather.lower(pools, rows, on_chip((b, max_len + 1), I32),
                                     paged=paged, row=row).compile()
         leaf = 28 * b * 8 * max_len * 128 * 2
         assert compiled.memory_analysis().temp_size_in_bytes < leaf + 16 * mib
     else:
         for width in (1, 16):
-            table = on_chip((b, width + 3), I32)
+            table = on_chip((b, width + 2), I32)
             compiled = kv._scatter.lower(pools, [], [dense] * 2, [], table,
                                          paged=paged, row=()).compile()
             assert compiled.memory_analysis().temp_size_in_bytes < 16 * mib
         compiled = kv._scatter.lower([], rows, [], state,
-                                     on_chip((16, 3), I32),
+                                     on_chip((16, 2), I32),
                                      paged=(), row=row).compile()
         assert compiled.memory_analysis().temp_size_in_bytes < 16 * mib
